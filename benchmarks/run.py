@@ -151,6 +151,8 @@ def main(argv=None) -> None:
                     help="run the full CSV sweep ('off' keeps --json cheap "
                          "on a warm plan cache)")
     args = ap.parse_args(argv)
+    from repro.kernels.runtime import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
     if args.sweep == "on":

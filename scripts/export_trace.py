@@ -140,7 +140,16 @@ def _launch_names(order) -> list:
 
 def trace_pallas_events(cp, route: str) -> list:
     """Chrome-tracing events for one launch-by-launch pallas execution of
-    ``cp`` — each span is one ``pallas_call`` (a fused chain = one span)."""
+    ``cp`` — each span is one ``pallas_call`` (a fused chain = one span).
+    The kernels run in the Pallas interpreter on the host CPU, so the
+    spans are host-clock spans and no TPU is touched."""
+    import jax
+    from repro.kernels import runtime
+    with jax.default_device(runtime.interpret_device()):
+        return _interpreted_pallas_events(cp, route)
+
+
+def _interpreted_pallas_events(cp, route: str) -> list:
     import jax.numpy as jnp
     import numpy as np
     from repro.core import exec as X

@@ -20,10 +20,11 @@ into a pluggable runtime layer:
   :meth:`~repro.core.planner.BlockPlan.window_schedule`, VMEM-gated on
   the window instead of the whole arena), and the **flat** byte arena
   (interpret-only fallback for mixed-dtype plans, and the cross-check
-  reference). ``mode="interpret"`` runs any of them on CPU CI;
-  ``mode="compiled"`` (or ``REPRO_DMO_INTERPRET=0``) lowers the blocked
-  program with ``interpret=False`` — the TPU analogue of the paper's SRAM
-  arena being VMEM. Select per instance via
+  reference). ``mode="interpret"`` runs any of them in the Pallas
+  interpreter on the host CPU; ``mode="compiled"`` lowers the blocked
+  program through Mosaic on a TPU — the TPU analogue of the paper's SRAM
+  arena being VMEM. Unpinned, the mode follows the platform. Select per
+  instance via
   ``get_backend("pallas", mode=..., layout=...)``.
 
 Every backend implements the :class:`ArenaExecutor` protocol::

@@ -11,7 +11,7 @@ Three arena programs share the backend (see :mod:`repro.kernels.arena_ops`):
   compiled-mode path, the TPU-VMEM realisation of the paper's SRAM arena.
   The whole arena is VMEM-resident, so VMEM caps ``total_rows``.
 - **streaming** (``mode="streaming"``): the same row-blocked layouts, but
-  the arena lives in ``pltpu.ANY`` (HBM) and each op DMAs only its *live
+  the arena lives in ``pl.ANY`` (HBM) and each op DMAs only its *live
   window* (:meth:`repro.core.planner.BlockPlan.window_schedule`) into VMEM
   scratch with double-buffered ``make_async_copy``. The VMEM gate becomes
   the schedule's ``max_resident_bytes`` instead of the whole arena — the
@@ -22,15 +22,14 @@ Three arena programs share the backend (see :mod:`repro.kernels.arena_ops`):
   plans execute in one buffer. Byte-granular dynamic slices fight the VMEM
   tilings, so this program is interpret-mode only.
 
-Execution mode is ``mode="interpret"`` (CPU CI), ``mode="compiled"``
-(``interpret=False`` lowering; requires row-blocked layouts and a backend
-with a real Pallas lowering), or ``mode="streaming"`` (whose interpret-ness
-follows the stack-wide switch unless ``interpret=`` is passed explicitly).
-The default follows the stack-wide ``REPRO_DMO_INTERPRET`` switch
-(:mod:`repro.kernels.runtime`), so one env var retargets the executor and
-every standalone kernel together. The VMEM budget the compiled and
-streaming gates check against is ``vmem_budget`` bytes (default: the
-``REPRO_DMO_VMEM_BUDGET`` env var, else 16 MiB).
+Execution mode is ``mode="interpret"`` (the Pallas interpreter, run on
+the host CPU), ``mode="compiled"`` (Mosaic lowering on a TPU; requires
+row-blocked layouts), or ``mode="streaming"`` (interpreted or compiled).
+Unpinned, the mode follows the platform (:mod:`repro.kernels.runtime`):
+interpret on the CPU backend, compiled on a TPU. The VMEM budget the
+compiled and streaming gates check against — and the scoped-VMEM limit
+handed to Mosaic — is ``vmem_budget`` bytes, by default the device kind's
+row of :data:`repro.kernels.runtime.VMEM_LIMIT_BYTES`.
 
 Split row bands lower like any conv/pool: ``_canon_meta`` takes the op's
 geometry from the band-aware :func:`repro.core.exec.ops.pads`, so a band's
@@ -60,6 +59,7 @@ from repro.core.graph import Op
 from repro.core.planner import (BlockPlan, Plan, chain_addr_of,
                                 chain_image_rows_of, fused_slots,
                                 legalise_for_blocks, tile_rows)
+from repro.kernels import runtime
 
 
 def _fused_chains(order: Sequence[Op]) -> Dict[str, List[Op]]:
@@ -144,19 +144,15 @@ def _canon_qmeta(op: Op, q: Optional[X.OpQuant]) -> Tuple:
     return ()
 
 
-#: VMEM budget assumed when neither the constructor nor the
-#: REPRO_DMO_VMEM_BUDGET env var names one (bytes; ~a TPU core's VMEM).
-DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
-
-
 class PallasExecutor:
     """The ``pallas`` :class:`~repro.core.exec.ArenaExecutor` backend.
 
-    ``mode``: ``"interpret"`` (CPU-runnable, the default), ``"compiled"``
-    (``interpret=False`` lowering), or ``"streaming"`` (ANY-space arena,
-    live windows DMA'd into VMEM scratch; runs interpreted or compiled —
-    pass ``interpret=`` to pin it, else the shared switch decides). ``None``
-    defers to the shared ``REPRO_DMO_INTERPRET`` switch. ``layout``:
+    ``mode``: ``"interpret"`` (the Pallas interpreter on the host CPU),
+    ``"compiled"`` (Mosaic lowering on a TPU), or ``"streaming"``
+    (ANY-space arena, live windows DMA'd into VMEM scratch; runs
+    interpreted or compiled — pass ``interpret=`` to pin it, else the
+    platform decides). ``None`` follows the platform: interpret on the CPU
+    backend, compiled on a TPU. ``layout``:
     ``"auto"`` runs the row-blocked program whenever the plan legalises
     (uniform dtype, no aggregated views) and falls back to the flat byte
     program otherwise; ``"blocks"`` / ``"flat"`` force one program. The
@@ -164,9 +160,11 @@ class PallasExecutor:
     ``packing="auto"``) and reverts to the legacy one-image-row-per-arena-
     row layout whenever packing fails to reduce the padded peak.
     Compiled and streaming modes require the row-blocked program — a flat
-    byte arena cannot meet the VMEM tilings. ``vmem_budget`` (bytes) gates
-    execution: compiled mode refuses arenas larger than it, streaming mode
-    refuses only schedules whose ``max_resident_bytes`` exceeds it."""
+    byte arena cannot meet the VMEM tilings. ``vmem_budget`` (bytes, by
+    default the device kind's VMEM limit) gates execution: compiled mode
+    refuses a launch whose arena, chain scratch and weights exceed it,
+    streaming mode refuses only schedules whose ``max_resident_bytes``
+    exceeds it."""
 
     name = "pallas"
 
@@ -182,9 +180,7 @@ class PallasExecutor:
                              "(expected 'auto', 'blocks' or 'flat')")
         if mode is None and interpret is not None:
             mode = "interpret" if interpret else "compiled"
-        #: None = follow the REPRO_DMO_INTERPRET env *per call*, so the
-        #: default-constructed (registry-cached) instance retargets when
-        #: the switch flips mid-process
+        #: None = follow the platform JAX runs on
         self._mode = mode
         self._interpret = interpret     # explicit pin (streaming mode only)
         self.layout = layout
@@ -212,17 +208,13 @@ class PallasExecutor:
     def mode(self) -> str:
         if self._mode is not None:
             return self._mode
-        from repro.kernels.runtime import default_interpret
-        return "interpret" if default_interpret() else "compiled"
+        return "interpret" if runtime.default_interpret() else "compiled"
 
     @property
     def interpret(self) -> bool:
         mode = self.mode
         if mode == "streaming":
-            if self._interpret is not None:
-                return self._interpret
-            from repro.kernels.runtime import default_interpret
-            return default_interpret()
+            return runtime.resolve_interpret(self._interpret)
         return mode == "interpret"
 
     def _check_mode_layout(self) -> None:
@@ -235,9 +227,7 @@ class PallasExecutor:
     def _resolve_budget(self) -> int:
         if self.vmem_budget is not None:
             return int(self.vmem_budget)
-        import os
-        env = os.environ.get("REPRO_DMO_VMEM_BUDGET", "").strip()
-        return int(env) if env else DEFAULT_VMEM_BUDGET
+        return runtime.vmem_limit()
 
     # -- lowering -----------------------------------------------------------
 
@@ -538,8 +528,9 @@ class PallasExecutor:
             scratch_rows=total)
         if streaming:
             import dataclasses
-            assert window.win_rows == total, \
-                f"fused window/slot mismatch: {window.win_rows} vs {total}"
+            assert window.resident_rows == total, \
+                f"fused window/slot mismatch: {window.resident_rows} vs " \
+                f"{total}"
             spec = dataclasses.replace(
                 spec, win_lo=window.lo, win_rows=window.win_rows,
                 in_slots=tuple(slots[t.storage()] for t in ext),
@@ -597,6 +588,9 @@ class PallasExecutor:
 
     def execute(self, plan_or_compiled, inputs=None, weights=None, *,
                 seed: int = 0, quant=None) -> Dict[str, np.ndarray]:
+        import contextlib
+
+        import jax
         import jax.numpy as jnp
         from repro.kernels import arena_ops
 
@@ -626,12 +620,11 @@ class PallasExecutor:
 
         def w_of(op):
             if quant is not None and id(op) in quant.weights_q:
-                return jnp.asarray(quant.weights_q[id(op)]["filter"],
-                                   jnp.int8)
-            return jnp.asarray(weights[id(op)]["filter"], jnp.float32)
+                return np.asarray(quant.weights_q[id(op)]["filter"], np.int8)
+            return np.asarray(weights[id(op)]["filter"], np.float32)
 
         # weight order mirrors the per-image spec/stage expansion exactly:
-        # a batched op repeats its filter per image (same jnp buffer, no
+        # a batched op repeats its filter per image (one device buffer, no
         # copies); a batched fused chain's stages run op-major so each
         # weighted member's filter repeats per image consecutively
         wflat = []
@@ -675,9 +668,9 @@ class PallasExecutor:
             while len(self._lowered) > 32:
                 self._lowered.popitem(last=False)
 
+        budget = self._resolve_budget()
         if bplan is not None:
             if self.mode == "streaming":
-                budget = self._resolve_budget()
                 ws = bplan.window_schedule()
                 if ws.max_resident_bytes > budget:
                     raise ValueError(
@@ -686,21 +679,24 @@ class PallasExecutor:
                         f"({ws.max_window_rows} live rows) exceeds the "
                         f"{budget}-byte budget")
             elif self.mode == "compiled":
-                budget = self._resolve_budget()
-                # a fused chain's scratch is VMEM-resident alongside the
-                # whole arena while its super-kernel runs
-                scratch = max((s.scratch_rows for s in specs
-                               if s.kind == "fused"), default=0)
-                arena_bytes = (bplan.total_rows + scratch) * bplan.row_bytes
-                if arena_bytes > budget:
+                # each launch holds the whole arena in VMEM, plus a fused
+                # chain's scratch and the weights the call stages there
+                need, rows, wbytes = 0, 0, 0
+                i = 0
+                for s in specs:
+                    nw = arena_ops.spec_weight_count(s)
+                    w = sum(int(x.nbytes) for x in wflat[i:i + nw])
+                    i += nw
+                    r = bplan.total_rows + s.scratch_rows
+                    if r * bplan.row_bytes + w > need:
+                        need, rows, wbytes = r * bplan.row_bytes + w, r, w
+                if need > budget:
                     raise ValueError(
-                        f"arena of {graph.name!r} does not fit VMEM: "
-                        f"{arena_bytes} bytes ({bplan.total_rows} rows"
-                        + (f" + {scratch} fused-scratch rows" if scratch
-                           else "")
-                        + f") exceeds the {budget}-byte budget — "
-                        "mode='streaming' keeps only the live window "
-                        "resident")
+                        f"arena of {graph.name!r} does not fit VMEM: a "
+                        f"launch needs {need} bytes ({rows} arena + scratch "
+                        f"rows, {wbytes} weight bytes), over the "
+                        f"{budget}-byte budget — mode='streaming' keeps "
+                        "only the live window resident")
             arena = self._seed_block_arena(bplan, graph, inputs)
         else:
             arena = np.zeros(plan.peak_bytes, np.uint8)
@@ -711,12 +707,27 @@ class PallasExecutor:
                                    X.arena_dtype(s.dtype_bytes)).reshape(-1)
                     arena[off:off + s.nbytes] = v.view(np.uint8)
 
-        fn = arena_ops.lower_program(specs, self.interpret)
-        with warnings.catch_warnings():
+        interpret = self.interpret
+        if not interpret and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"{self.mode} Pallas kernels need a TPU, and JAX runs on "
+                f"{jax.default_backend()!r}: pass interpret=True (or leave "
+                "the mode to the platform) to interpret them")
+        fn = arena_ops.lower_program(specs, interpret,
+                                     None if interpret else budget)
+        # interpreted kernels run on the host CPU, never on a TPU
+        place = (jax.default_device(runtime.interpret_device()) if interpret
+                 else contextlib.nullcontext())
+        with warnings.catch_warnings(), place:
             # CPU jit can't honour the donation and warns; the in-kernel
             # aliasing is what carries the single-buffer semantics there
             warnings.filterwarnings("ignore", message=".*donated.*")
-            out_arena = np.asarray(fn(jnp.asarray(arena), *wflat))
+            on_device: Dict[int, object] = {}
+            for w in wflat:
+                if id(w) not in on_device:
+                    on_device[id(w)] = jnp.asarray(w)
+            out_arena = np.asarray(fn(jnp.asarray(arena),
+                                      *(on_device[id(w)] for w in wflat)))
 
         if bplan is not None:
             return self._gather_block_outputs(bplan, graph, out_arena)

@@ -94,6 +94,12 @@ def graph_signature(graph: Graph) -> str:
 # ---------------------------------------------------------------------------
 
 
+#: Scratch bytes the FusePass lets one fused chain hold (Mosaic's default
+#: scoped-VMEM limit on TPU v5e). A planning constant: plans do not depend
+#: on the device they are compiled on.
+FUSE_VMEM_BUDGET = 16 * 2**20
+
+
 @dataclasses.dataclass(frozen=True)
 class CompileOptions:
     profile: str = "paper"        # overlap profile: "paper" | "extended"
@@ -112,8 +118,7 @@ class CompileOptions:
     split_ops_limit: int = 150    # "auto": skip auto_split on larger graphs
     fuse: str = "auto"            # band-chain fusion: "auto" | "on" | "off"
     #: VMEM budget (bytes) the FusePass gates per-chain scratch estimates
-    #: against; None = the REPRO_DMO_VMEM_BUDGET env var, else the pallas
-    #: backend default (16 MiB).
+    #: against; None = :data:`FUSE_VMEM_BUDGET`.
     fuse_vmem_budget: Optional[int] = None
     verify: str = "auto"          # "auto" | "constraints" | "numeric" | "off"
     backend: str = "numpy"        # executor backend a plan is compiled for
@@ -479,11 +484,7 @@ class FusePass(Pass):
     def _budget(opt: CompileOptions) -> int:
         if opt.fuse_vmem_budget is not None:
             return int(opt.fuse_vmem_budget)
-        env = os.environ.get("REPRO_DMO_VMEM_BUDGET", "").strip()
-        if env:
-            return int(env)
-        from repro.core.exec.pallas_backend import DEFAULT_VMEM_BUDGET
-        return DEFAULT_VMEM_BUDGET
+        return FUSE_VMEM_BUDGET
 
 
 @register_pass
@@ -712,10 +713,12 @@ class VerifyPass(Pass):
                 "verify: split-band execution matches the unsplit "
                 "reference" + (" (<= 1 LSB)" if quant else " (bit-exact)"))
         if opt.backend == "pallas":
-            # the flat byte program is the lowering reference; the
-            # row-blocked program is what compiled mode executes — verify
-            # both against the numpy arena semantics
-            got_fl = X.get_backend("pallas", layout="flat").execute(
+            # the flat byte program is the lowering reference (interpreted
+            # on the host CPU wherever this runs); the row-blocked and
+            # streaming programs follow the device — compiled on a TPU —
+            # and all three are verified against the numpy arena semantics
+            got_fl = X.get_backend("pallas", layout="flat",
+                                   mode="interpret").execute(
                 state.plan, inputs, weights, quant=quant)
             X.compare_outputs(got_np, got_fl, exact=False,
                               label="pallas flat vs numpy")
@@ -736,7 +739,7 @@ class VerifyPass(Pass):
                 # program bit-for-bit — and with numpy to fp32 tolerance
                 try:
                     got_st = X.get_backend(
-                        "pallas", mode="streaming", interpret=True).execute(
+                        "pallas", mode="streaming").execute(
                         state.plan, inputs, weights, quant=quant)
                 except ValueError as e:
                     # live window over the VMEM budget — a real refusal,
@@ -896,8 +899,8 @@ def compile(graph: Graph, *, profile: str = "paper",
             split region's band chain into one fused super-kernel whose
             intermediates live in VMEM scratch instead of the arena.
             ``fuse_vmem_budget`` (bytes) overrides the per-chain scratch
-            gate (default: ``REPRO_DMO_VMEM_BUDGET`` env, else 16 MiB);
-            over-budget chains are left unfused.
+            gate (default :data:`FUSE_VMEM_BUDGET`); over-budget chains
+            are left unfused.
         verify: verification mode (``auto``/``constraints``/``numeric``/``off``).
         batch: leading batch axis to compile the plan for (default 1). The
             graph is rewritten through :func:`repro.core.graph.with_batch`
@@ -911,7 +914,7 @@ def compile(graph: Graph, *, profile: str = "paper",
             :func:`repro.core.planner.legalise_for_blocks`, the program
             compiled mode executes — against the numpy backend, and
             ``CompiledPlan.execute()`` runs on this backend by default
-            (interpret vs compiled mode follows ``REPRO_DMO_INTERPRET``).
+            (interpreted on the CPU, compiled on a TPU).
         cache: look up / populate the content-addressed plan cache.
         disk_cache: persist/look up plans on disk under
             ``$REPRO_DMO_CACHE_DIR`` (default ``~/.cache/repro-dmo``) so
@@ -1101,5 +1104,12 @@ def compile_many(graphs: Sequence[Graph], batches: Sequence[int] = (1,),
         return [_compile_many_worker(j) for j in jobs]
     import multiprocessing as mp
     ctx = mp.get_context("spawn")
-    with ctx.Pool(processes=min(workers, len(jobs) or 1)) as pool:
+    with ctx.Pool(processes=min(workers, len(jobs) or 1),
+                  initializer=_pin_worker_to_cpu) as pool:
         return pool.map(_compile_many_worker, jobs)
+
+
+def _pin_worker_to_cpu() -> None:
+    """``compile_many`` workers only plan: pin them to the CPU before they
+    import JAX, so a parent that holds the chip never starves them."""
+    os.environ["JAX_PLATFORMS"] = "cpu"

@@ -224,6 +224,19 @@ def os_bytes(plan: Plan, inp: Tensor, outp: Tensor) -> int:
 TPU_TILES: Dict[int, Tuple[int, int]] = {4: (8, 128), 2: (16, 128),
                                          1: (32, 128)}
 
+#: Row granularity of an HBM <-> VMEM copy of a (rows, lanes) arena: Mosaic
+#: takes DMA row slices whose start and length are multiples of 8 (f32 and
+#: int8 alike). Streaming slots keep each block at its arena row modulo 8,
+#: so one aligned copy moves it.
+DMA_ROWS = 8
+
+
+def dma_span(off: int, rows: int) -> Tuple[int, int]:
+    """``(start, rows)`` of the DMA-aligned row span covering arena rows
+    ``[off, off + rows)``."""
+    lo = off // DMA_ROWS * DMA_ROWS
+    return lo, _round_up(off + rows, DMA_ROWS) - lo
+
 #: Op kinds whose kernels stream output rows (and therefore read/write the
 #: arena one whole row at a time — the shapes the row-granular O_s covers).
 _ROW_STREAMING_KINDS = frozenset({"conv2d", "depthwise_conv2d", "pool"})
@@ -774,31 +787,31 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def staged_slots(in_rows: Sequence[int], out_rows: int, sub: int,
+def staged_slots(in_blocks: Sequence[Tuple[int, int]],
+                 out_block: Tuple[int, int], sub: int,
                  ) -> Tuple[Tuple[int, ...], int, int]:
-    """Scratch packing for a staged (whole-tensor) streaming op: operand
-    blocks packed back-to-back, output last, total rounded up to the
-    sublane tile. Returns ``(input slot row offsets, output slot row
-    offset, total scratch rows)``. Blocks pack *tight* — the arena-side DMA
-    offsets stay tile-aligned (placement guarantees it) and that is the
-    side alignment matters on — so a staged op costs the sum of its block
-    heights, not the span between scattered placements. The kernel layer
-    and the planner both derive the packing from this one function, so the
-    scratch a kernel allocates always matches the resident rows the
+    """Scratch packing for a staged (whole-tensor) streaming op, from the
+    ``(arena row offset, rows)`` of each input block and of the output
+    block: each block gets a slot holding its :func:`dma_span`, slots
+    packed back-to-back (output last), total rounded up to the sublane
+    tile. Returns ``(input block row offsets, output block row offset,
+    total scratch rows)`` — each block sits at its arena row modulo
+    :data:`DMA_ROWS` inside its slot. A staged op costs the sum of its
+    block spans, not the span between scattered placements. The kernel
+    layer and the planner both derive the packing from this one function,
+    so the scratch a kernel allocates always matches the resident rows the
     schedule reports."""
     offs: List[int] = []
     cur = 0
-    for r in in_rows:
-        offs.append(cur)
-        cur += int(r)
-    out_slot = cur
-    cur += int(out_rows)
-    return tuple(offs), out_slot, _round_up(cur, sub)
+    for off, rows in list(in_blocks) + [out_block]:
+        offs.append(cur + off % DMA_ROWS)
+        cur += dma_span(off, rows)[1]
+    return tuple(offs[:-1]), offs[-1], _round_up(cur, sub)
 
 
 def fused_slots(members: Sequence[Op], size_of, align: int = 1,
                 round_to: int = 1, include_io: bool = False,
-                ) -> Tuple[Dict[Tensor, int], int]:
+                dma_io: bool = True) -> Tuple[Dict[Tensor, int], int]:
     """Scratch-slot packing for one fused band chain.
 
     The chain's internal tensors (every member output except the last
@@ -814,11 +827,14 @@ def fused_slots(members: Sequence[Op], size_of, align: int = 1,
     DMA'd back at the end) — and since an external input dies at its last
     in-chain read, the output slot can reuse its space.
 
-    Slots pack tight (like :func:`staged_slots` — the arena-side DMA
-    offsets are the aligned side); only the total is rounded up to
-    ``round_to``. Returns ``(slot offset per tensor, total scratch
-    units)``. The kernel layer, the window schedule and the FusePass budget
-    estimate all derive the packing from this one function."""
+    Internal slots pack tight; with ``include_io`` the staged I/O blocks
+    (whole tensors at DMA-aligned arena rows) take :data:`DMA_ROWS`-aligned
+    slots of whole DMA rows, so one aligned copy moves each
+    (``dma_io=False`` packs them tight too: the chain's live rows, without
+    the copy slack). Only the total is rounded up to ``round_to``. Returns
+    ``(slot offset per tensor, total scratch units)``. The kernel layer,
+    the window schedule and the FusePass budget estimate all derive the
+    packing from this one function."""
     n = len(members)
     internal = {op.output.storage() for op in members[:-1]}
     first: Dict[Tensor, int] = {}
@@ -848,11 +864,14 @@ def fused_slots(members: Sequence[Op], size_of, align: int = 1,
             touch(s, i)
             last[s] = n - 1        # held until the write-back DMA
     scopes = {s: (first[s], last[s]) for s in tensors}
-    sizes = {s: int(size_of(s)) for s in tensors}
+    io = {s for s in tensors if s not in internal} if dma_io else set()
+    sizes = {s: (_round_up(int(size_of(s)), DMA_ROWS) if s in io
+                 else int(size_of(s))) for s in tensors}
     placed: Dict[Tensor, int] = {}
     for s in tensors:              # first-touch (production) order
-        placed[s] = _lowest_feasible(s, placed, scopes, list(members), {},
-                                     sizes=sizes, align=align)
+        placed[s] = _lowest_feasible(
+            s, placed, scopes, list(members), {}, sizes=sizes,
+            align=max(align, DMA_ROWS) if s in io else align)
     total = max((placed[s] + sizes[s] for s in tensors), default=0)
     return placed, _round_up(total, max(1, round_to))
 
@@ -903,10 +922,10 @@ def rolling_starts(op: Op, xi: int, xo: int, ih: int, oh: int, sub: int,
     output were placed.
 
     Fetches are fixed-size (``win_in`` rows, sublane-rounded) starting at
-    ``starts[t]`` (arena rows, aligned), clamped so the fetch never runs
-    past the arena; over-fetched rows are never read unmasked (reads
-    outside the valid input rows are the kernels' clamped+masked taps) and
-    never written back (write-back covers exactly the computed rows).
+    ``starts[t]`` (arena rows, :data:`DMA_ROWS`-aligned), clamped so the
+    fetch never runs past the arena; over-fetched rows are never read
+    unmasked (reads outside the valid input rows are the kernels'
+    clamped+masked taps).
 
     The O_s row invariant makes split input/output staging exact: an op's
     write to output row ``oy`` only ever clobbers arena input rows no
@@ -929,13 +948,37 @@ def rolling_starts(op: Op, xi: int, xo: int, ih: int, oh: int, sub: int,
         b = min(a + tr, oh)
         iy_lo = min(max(a * sh - ph, 0), ih - 1)
         iy_hi = min(max((b - 1) * sh - ph + (kh - 1) * dh, 0), ih - 1)
-        s_t = (_ar_of(iy_lo, ci, ki) // sub) * sub
+        s_t = (xi + (_ar_of(iy_lo, ci, ki) // sub) * sub) \
+            // DMA_ROWS * DMA_ROWS
         tiles.append(s_t)
-        need = max(need, _ar_top(iy_hi, ci, ki) - s_t + 1)
-    win_in = min(_round_up(need, sub), _round_up(in_arena_rows, sub))
-    starts = tuple(max(0, min(xi + s_t, total_rows - win_in))
-                   for s_t in tiles)
+        need = max(need, xi + _ar_top(iy_hi, ci, ki) - s_t + 1)
+    win_in = min(_round_up(need, sub),
+                 _round_up(xi % DMA_ROWS + in_arena_rows, sub))
+    starts = tuple(max(0, min(s_t, total_rows - win_in)) for s_t in tiles)
     return starts, win_in
+
+
+def tile_writeback(out_off: int, oh: int, co: int, ko: int, sub: int,
+                   total_rows: int) -> Tuple[Tuple[int, ...], int]:
+    """Write-back spans of a row-streaming op's output tiles. Tile ``t``
+    computes output image rows ``[t*tr, (t+1)*tr)`` into a VMEM slot of
+    ``slot_rows`` rows that mirrors arena rows ``[starts[t], starts[t] +
+    slot_rows)``: the slot is filled from the arena, the tile's rows are
+    written into it, and it is copied back whole — one DMA-aligned copy
+    each way, however the tile's rows sit against the DMA row grid; the
+    rows the tile does not compute go back unchanged. Returns ``(starts
+    per tile, slot_rows)``, ``slot_rows`` at least
+    :func:`tile_arena_rows`."""
+    tr = tile_rows(co, ko, sub)
+    spans = []
+    for a in range(0, oh, tr):
+        b = min(a + tr, oh)
+        spans.append(dma_span(out_off + _ar_of(a, co, ko),
+                              _ar_top(b - 1, co, ko) - _ar_of(a, co, ko)
+                              + 1))
+    rows = min(max([tile_arena_rows(co, ko, sub)] + [n for _, n in spans]),
+               total_rows)
+    return tuple(min(lo, total_rows - rows) for lo, _ in spans), rows
 
 
 @dataclasses.dataclass(frozen=True)
@@ -943,8 +986,9 @@ class OpWindow:
     """One op's live window in the streaming schedule: the contiguous
     arena-row extent ``[lo, hi)`` it may touch, the live-window rows
     (``win_rows``) and the scratch rows its streaming program allocates
-    (``resident_rows`` — the rolling input window is double-buffered, so
-    resident exceeds the live window by one input-window slot).
+    (``resident_rows`` — the rolling input window is double-buffered, and
+    every slot holds whole :data:`DMA_ROWS` groups, so resident exceeds
+    the live window).
     ``starts`` is the per-output-tile fetch start table for rolling
     (conv / depthwise / pool) ops; empty for staged whole-tensor ops."""
 
@@ -1083,6 +1127,8 @@ def _fused_window(bplan: BlockPlan, members: Sequence[Op],
     def rows_of(s: Tensor) -> int:
         return irows_of(s) * (s.batch if s.batch > 1 else 1)
 
+    _, live = fused_slots(members, rows_of, round_to=sub, include_io=True,
+                          dma_io=False)
     _, total = fused_slots(members, rows_of, round_to=sub, include_io=True)
     ext: List[BlockLayout] = []
     for op in members:
@@ -1095,7 +1141,7 @@ def _fused_window(bplan: BlockPlan, members: Sequence[Op],
     hi = max(l.row_offset + l.rows for l in ext)
     return OpWindow(members[-1].params["fuse_chain"], "fused",
                     (lo // sub) * sub, _round_up(hi, sub),
-                    win_rows=total, resident_rows=total)
+                    win_rows=live, resident_rows=total)
 
 
 def window_schedule(bplan: BlockPlan) -> "WindowSchedule":
@@ -1148,20 +1194,24 @@ def window_schedule(bplan: BlockPlan) -> "WindowSchedule":
                     int(op.inputs[0].shape[-3]), int(op.output.shape[-3]),
                     sub, bplan.total_rows, in_addr=in_addr,
                     out_addr=out_addr)
-                out_ar = tile_arena_rows(*out_addr, sub)
-                lo = (min(min(starts), lo_e) // sub) * sub
-                hi = _round_up(max(max(s + win_in for s in starts), hi_e),
-                               sub)
-                windows.append(OpWindow(op.name, op.kind, lo, hi,
-                                        win_rows=win_in + out_ar,
-                                        resident_rows=2 * win_in + out_ar,
-                                        starts=starts))
+                wb, slot_rows = tile_writeback(
+                    out_off, int(op.output.shape[-3]), *out_addr, sub,
+                    bplan.total_rows)
+                lo = (min(min(starts), min(wb), lo_e) // sub) * sub
+                hi = _round_up(max(max(s + win_in for s in starts),
+                                   max(wb) + slot_rows, hi_e), sub)
+                windows.append(OpWindow(
+                    op.name, op.kind, lo, hi,
+                    win_rows=win_in + tile_arena_rows(*out_addr, sub),
+                    resident_rows=2 * win_in + slot_rows, starts=starts))
             else:
-                _, _, total = staged_slots([l.image_rows for l in lays],
-                                           out.image_rows, sub)
+                _, _, total = staged_slots(
+                    [(o, l.image_rows) for o, l in zip(offs, lays)],
+                    (out_off, out.image_rows), sub)
+                live = sum(l.image_rows for l in lays) + out.image_rows
                 windows.append(OpWindow(
                     op.name, op.kind, (lo_e // sub) * sub,
-                    _round_up(hi_e, sub), win_rows=total,
+                    _round_up(hi_e, sub), win_rows=_round_up(live, sub),
                     resident_rows=total))
     return WindowSchedule(tuple(windows), bplan.total_rows,
                           bplan.arena_rowlen, bplan.dtype_bytes)

@@ -26,20 +26,20 @@ that the streaming one):
   ``(rows, rowlen)`` buffer *typed* to the plan's dtype, laid out by
   :func:`repro.core.planner.legalise_for_blocks`: operands occupy whole
   arena rows at row-aligned offsets, conv/pool walk image rows via
-  ``pl.dslice`` on the row axis, and no bitcasts are needed — the same
-  program lowers under ``interpret=False``. Packed layouts (spec
-  ``in_addr``/``out_addr`` triples) put ``cols_per_row`` narrow image rows
-  in each arena row — reads dynamic-slice the lane phase, writes RMW the
-  whole arena row — or span one wide image row over ``row_span``
-  consecutive arena rows. The whole arena is VMEM-resident, so the VMEM
-  capacity caps ``total_rows``.
+  ``pl.ds`` on the row axis, and no bitcasts are needed — the program
+  compiles through Mosaic. Packed layouts (spec ``in_addr``/``out_addr``
+  triples) put ``cols_per_row`` narrow image rows in each arena row —
+  reads pick the lane phase from static slices, writes merge it into the
+  loaded row — or span one wide image row over ``row_span`` consecutive
+  arena rows. Each launch copies the arena from HBM into its VMEM block,
+  so the VMEM capacity caps ``total_rows``.
 - **streaming** (:class:`_StreamRollMem` / :class:`_StreamStageMem`) — the
-  arena stays in ``pltpu.ANY`` (HBM) and each op DMAs only its *live
+  arena stays in ``pl.ANY`` (HBM) and each op DMAs only its *live
   window* (:class:`repro.core.planner.WindowSchedule`) into VMEM scratch
   with ``pltpu.make_async_copy``. Row-streaming ops (conv / depthwise /
   pool) run a row-tile grid: a double-buffered rolling input window (the
-  tile-``t+1`` fetch is issued before the tile-``t`` wait) plus a one-tile
-  output slot whose rows DMA back as they are produced. Every other kind
+  tile-``t+1`` fetch is issued before the tile-``t`` wait) plus an output
+  slot mirroring the tile's DMA-aligned arena span. Every other kind
   stages whole operand blocks into packed scratch slots
   (:func:`repro.core.planner.staged_slots`, fetches pipelined over two
   rotating DMA semaphores), computes, and copies the output block back.
@@ -50,6 +50,10 @@ spec carries its band shapes and its explicit band-local pads (a producer
 band's leading row pad is *negative* — ``iy = oy*sh - ph + fy*dh`` simply
 starts deeper in the full input), so the ordinary row kernels index exactly
 the band's rows in both the flat and the row-blocked program.
+
+Mosaic takes what the memory-access layer emits: values stay 2-D, taps are
+static sublane shifts, int8 rows are addressed through aligned 8-row
+windows, and every HBM <-> VMEM copy moves whole 8-row groups.
 
 Safety contract (paper §III.A): kernels read *and* write through the aliased
 output ref, and conv/pool walk output rows in ascending index order inside a
@@ -66,12 +70,15 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.planner import (DMA_ROWS, dma_span, staged_slots,
+                                tile_writeback)
 
 #: jnp mirrors of repro.core.exec.ops.ELEMENTWISE (same names, same maths).
 _ELEMENTWISE = {
@@ -103,7 +110,8 @@ class OpSpec:
     ``(rows, used-elements-per-row)`` block shape from its
     :class:`~repro.core.planner.BlockLayout`. ``win_rows > 0`` (on top of
     ``rowlen > 0``) selects the streaming grid program: the arena lives in
-    ``pltpu.ANY`` and only ``win_rows`` rows are VMEM-resident —
+    ``pl.ANY`` and only the op's live window (``win_rows`` rows, plus
+    double-buffering and DMA row-group slack) is VMEM-resident —
     ``win_starts`` is the planner's per-output-tile fetch start table for
     rolling conv/pool windows (empty = staged whole-block op), ``win_lo``
     the low edge of the op's live-window extent (reporting only)."""
@@ -120,7 +128,7 @@ class OpSpec:
     in_rows: Tuple[Tuple[int, int], ...] = ()  # (rows, used) per input
     out_rows: Tuple[int, int] = ()             # (rows, used) of the output
     win_lo: int = 0                    # live-window extent low edge (rows)
-    win_rows: int = 0                  # VMEM-resident rows (0 = non-streaming)
+    win_rows: int = 0                  # live-window rows (0 = non-streaming)
     win_starts: Tuple[int, ...] = ()   # rolling-window fetch starts per tile
     #: Packed row addressing (blocked/streaming programs only): per-operand
     #: ``(cols_per_row, row_span, image_rowlen)`` triples from the packed
@@ -188,9 +196,223 @@ def _tile_geom(spec: OpSpec) -> Tuple[int, int]:
 
 
 # ---------------------------------------------------------------------------
-# Memory access layer: the one place the two arena addressings differ.
-# Kernel bodies below are written once against this API.
+# Value relayouts. Kernel bodies see 2-D values only — an image row as a
+# (1, W*C) lane vector, a whole tensor as an (N, C) matrix over its last
+# axis — and these helpers move between the two with static lane/sublane
+# slices and concatenates, the relayouts Mosaic lowers (it refuses lane ->
+# sublane reshapes of int8-tier values, and dynamic slices and gathers of
+# loaded values outright).
 # ---------------------------------------------------------------------------
+
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _cdt(dtype: str):
+    """Compute dtype of an arena tier: int8 values travel as int32."""
+    return jnp.int32 if dtype == "i8" else jnp.float32
+
+
+def _mat_shape(shape: Tuple[int, ...]) -> Tuple[int, int]:
+    """(N, C) matrix view of a tensor over its last axis."""
+    c = int(shape[-1]) if shape else 1
+    return _elems(shape) // c, c
+
+
+def _cat(parts, axis: int):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+def _widen(v, width: int):
+    """A (1, w) lane vector cut or zero-filled to (1, width)."""
+    w = v.shape[1]
+    if w >= width:
+        return v[:, :width]
+    return jnp.concatenate([v, jnp.zeros((1, width - w), v.dtype)], axis=1)
+
+
+def _unflatten(v, n: int, c: int):
+    """The first ``n*c`` lanes of a (1, >= n*c) vector as an (n, c)
+    matrix, row ``j`` = lanes ``[j*c, (j+1)*c)``."""
+    return _cat([v[:, j * c:(j + 1) * c] for j in range(n)], 0)
+
+
+def _flatten(x):
+    """Inverse of :func:`_unflatten`: an (n, c) matrix as (1, n*c)."""
+    return _cat([x[j:j + 1, :] for j in range(x.shape[0])], 1)
+
+
+def _pixel_phases(row, w: int, c: int, s: int):
+    """A (1, w*c) image row split into ``s`` stride phases: phase ``q`` is
+    the (ceil((w-q)/s), c) matrix of pixels ``q, q+s, q+2s, ...``."""
+    if s == 1:
+        return [_unflatten(row, w, c)]
+    return [_cat([row[:, x * c:(x + 1) * c] for x in range(q, w, s)], 0)
+            for q in range(min(s, w))]
+
+
+def _taps(phases, s: int, off: int, n: int, c: int, dt):
+    """The (n, c) tap matrix whose row ``o`` is pixel ``o*s + off`` —
+    zero where that pixel lies outside the row. One static sublane shift of
+    the matching stride phase, in place of a per-tap gather."""
+    q, m = off % s, off // s
+    if q >= len(phases):
+        return jnp.zeros((n, c), dt)
+    p = phases[q]
+    lo, hi = max(0, -m), min(n, p.shape[0] - m)
+    if hi <= lo:
+        return jnp.zeros((n, c), dt)
+    parts = [p[lo + m:hi + m]]
+    if lo:
+        parts.insert(0, jnp.zeros((lo, c), dt))
+    if hi < n:
+        parts.append(jnp.zeros((n - hi, c), dt))
+    return _cat(parts, 0)
+
+
+def _pick(sel, options):
+    """``options[sel]`` for a static or traced index (select chain)."""
+    if isinstance(sel, int):
+        return options[sel]
+    out = options[0]
+    for p in range(1, len(options)):
+        out = jnp.where(sel == p, options[p], out)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Memory access layer: the one place the arena addressings differ. Kernel
+# bodies below are written once against this API.
+# ---------------------------------------------------------------------------
+
+
+def _aligned8(r):
+    if isinstance(r, int):
+        return r // 8 * 8
+    return pl.multiple_of(r // 8 * 8, 8)
+
+
+def _row_load(ref, r):
+    """Arena row ``r`` (static or traced) of a (rows, L) ref as a (1, L)
+    value in the compute dtype. Int8 rows pack four to a 32-bit sublane, so
+    Mosaic addresses them in aligned 8-row windows: load the window holding
+    ``r`` and keep its row."""
+    if ref.dtype == jnp.int8:
+        base = _aligned8(r)
+        win = ref[pl.ds(base, 8), :].astype(jnp.int32)
+        sel = jax.lax.broadcasted_iota(jnp.int32, win.shape, 0) == r - base
+        return jnp.sum(jnp.where(sel, win, 0), axis=0, keepdims=True)
+    return ref[pl.ds(r, 1), :]
+
+
+def _row_store(ref, r, row) -> None:
+    """Overwrite arena row ``r`` with a (1, L) compute-dtype value (int8:
+    read-modify-write of the aligned 8-row window; the other rows are
+    written back unchanged, and the row loops are sequential)."""
+    if ref.dtype == jnp.int8:
+        base = _aligned8(r)
+        win = ref[pl.ds(base, 8), :].astype(jnp.int32)
+        sel = jax.lax.broadcasted_iota(jnp.int32, win.shape, 0) == r - base
+        ref[pl.ds(base, 8), :] = jnp.where(sel, row, win).astype(jnp.int8)
+    else:
+        ref[pl.ds(r, 1), :] = row.astype(ref.dtype)
+
+
+def _image_row(ref, row0, iy, used: int, addr: Tuple[int, int, int]):
+    """One image row (``used`` elements, as (1, used)) of an operand whose
+    image row 0 starts at arena row ``row0``, at row index ``iy``. Packed
+    rows live at lane phase ``(iy % c) * rl`` of arena row ``iy // c``; a
+    spanning image row occupies ``k`` consecutive arena rows."""
+    c, k, rl = addr
+    if c > 1:
+        row = _row_load(ref, row0 + iy // c)
+        return _pick(iy % c, [row[:, p * rl:(p + 1) * rl] for p in range(c)])
+    if k > 1:
+        return _cat([_row_load(ref, row0 + iy * k + j) for j in range(k)],
+                    1)[:, :used]
+    return _row_load(ref, row0 + iy)[:, :used]
+
+
+def _put_image_row(ref, row0, oy, L: int, addr: Tuple[int, int, int],
+                   val) -> None:
+    """Store a (1, rl) image row at row index ``oy``. A packed row store is
+    a read-modify-write of its arena row (the other lane phases must
+    survive); safe because the row loop is sequential and the planner's
+    O_s is derived at whole-arena-row granularity, phases included. Legacy
+    and spanning rows clobber their whole arena rows, tile padding
+    zero-filled."""
+    c, k, rl = addr
+    if c > 1:
+        r = row0 + oy // c
+        ph = oy % c
+        old = _row_load(ref, r)
+        lane = jax.lax.broadcasted_iota(jnp.int32, old.shape, 1)
+        new = old
+        for p in range(c):
+            if isinstance(ph, int) and ph != p:
+                continue
+            placed = _widen(_cat([jnp.zeros((1, p * rl), val.dtype), val]
+                                 if p else [val], 1), L)
+            hit = (lane >= p * rl) & (lane < (p + 1) * rl)
+            if not isinstance(ph, int):
+                hit = hit & (ph == p)
+            new = jnp.where(hit, placed, new)
+        _row_store(ref, r, new)
+    elif k > 1:
+        wide = _widen(val, k * L)
+        for j in range(k):
+            _row_store(ref, row0 + oy * k + j, wide[:, j * L:(j + 1) * L])
+    else:
+        _row_store(ref, row0 + oy, _widen(val, L))
+
+
+def _is_image(shape, rows: int, used: int, addr: Tuple[int, int, int]):
+    """Does an operand block keep image-row structure (so its rows can be
+    addressed one image row at a time)? Dense blocks pack the flat tensor
+    over full arena rows."""
+    if len(shape) < 3:
+        return False
+    h, rl = int(shape[-3]), _elems(shape[-2:])
+    c, k, _ = addr
+    if c > 1:
+        return addr[2] == rl and rows == -(-h // c)
+    if k > 1:
+        return addr[2] == rl and rows == h * k
+    return used == rl and rows == h
+
+
+def _block_matrix(ref, off, rows: int, used: int,
+                  addr: Tuple[int, int, int], shape):
+    """A whole operand block as its (N, C) matrix: the used prefix of each
+    arena row (packing is row-major in image order; spanning rows carry
+    per-image-row padding that is stripped) joined into the flat element
+    stream, then cut into rows of the last axis."""
+    n, c = _mat_shape(shape)
+    _, k, rl = addr
+    if k > 1:
+        parts = [_cat([_row_load(ref, off + y * k + j) for j in range(k)],
+                      1)[:, :rl] for y in range(rows // k)]
+    else:
+        parts = [_row_load(ref, off + r)[:, :used] for r in range(rows)]
+    return _unflatten(_cat(parts, 1), n, c)
+
+
+def _put_block_matrix(ref, off, rows: int, used: int, L: int,
+                      addr: Tuple[int, int, int], val) -> None:
+    """Inverse of :func:`_block_matrix`: write an (N, C) matrix as a padded
+    (rows, L) block (dense tail and per-row tile padding zero-filled)."""
+    stream = _flatten(val)
+    _, k, rl = addr
+    if k > 1:
+        for y in range(rows // k):
+            seg = _widen(stream[:, y * rl:(y + 1) * rl], k * L)
+            for j in range(k):
+                _row_store(ref, off + y * k + j, seg[:, j * L:(j + 1) * L])
+        return
+    for r in range(rows):
+        seg = stream[:, r * used:(r + 1) * used]
+        _row_store(ref, off + r, _widen(seg, L) if seg.shape[1]
+                   else jnp.zeros((1, L), stream.dtype))
 
 
 class _FlatMem:
@@ -203,6 +425,7 @@ class _FlatMem:
     def __init__(self, ref, spec: OpSpec):
         self.ref, self.spec = ref, spec
         self.isz = _isz(spec.dtype)
+        self.cdt = _cdt(spec.dtype)
 
     def _in_ref(self, i: int):
         return self.ref
@@ -213,25 +436,33 @@ class _FlatMem:
     def _read(self, ref, byte_off, elems: int):
         if self.spec.dtype == "i8":
             raw = ref[pl.dslice(byte_off, elems)]
-            return jax.lax.bitcast_convert_type(raw, jnp.int8)
-        raw = ref[pl.dslice(byte_off, 4 * elems)].reshape(elems, 4)
-        return jax.lax.bitcast_convert_type(raw, jnp.float32)
+            v = jax.lax.bitcast_convert_type(raw, jnp.int8)
+        else:
+            raw = ref[pl.dslice(byte_off, 4 * elems)].reshape(elems, 4)
+            v = jax.lax.bitcast_convert_type(raw, jnp.float32)
+        return v.astype(self.cdt).reshape(1, elems)
+
+    def is_image(self, i: int) -> bool:
+        return len(self.spec.in_shape[i]) >= 3
+
+    def out_is_image(self) -> bool:
+        return len(self.spec.out_shape) >= 3
 
     def read_t(self, i: int):
-        """Input ``i`` as a typed tensor in its view shape."""
+        """Input ``i`` as its (N, C) matrix."""
         shape = self.spec.in_shape[i]
+        n, c = _mat_shape(shape)
         return self._read(self._in_ref(i), self.spec.in_off[i],
-                          _elems(shape)).reshape(shape)
+                          n * c).reshape(n, c)
 
     def read_row(self, i: int, iy):
-        """One image row (W*C elements) of input ``i`` at a traced row
-        index."""
+        """One image row (1, W*C) of input ``i`` at a row index."""
         row = _elems(self.spec.in_shape[i][-2:])
         return self._read(self._in_ref(i),
                           self.spec.in_off[i] + iy * row * self.isz, row)
 
     def _write(self, ref, byte_off, value):
-        flat = value.reshape(-1)
+        flat = value.reshape(-1).astype(_jnp_dtype(self.spec.dtype))
         raw = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
         ref[pl.dslice(byte_off, raw.size)] = raw
 
@@ -248,76 +479,14 @@ class _FlatMem:
         jax.lax.fori_loop(0, oh, body, 0)
 
 
-def _pad_cols(block, rows: int, used: int, L: int, dt):
-    """Zero-fill each row's tile-padding tail out to the arena row."""
-    if used == L:
-        return block
-    return jnp.concatenate(
-        [block, jnp.zeros((rows, L - used), dt)], axis=1)
-
-
-def _out_block(value, rows: int, used: int, L: int, dt):
-    """An output tensor as a padded (rows, L) arena block (dense tail and
-    per-row tile padding zero-filled)."""
-    flat = value.reshape(-1).astype(dt)
-    if flat.size < rows * used:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros(rows * used - flat.size, dt)])
-    return _pad_cols(flat.reshape(rows, used), rows, used, L, dt)
-
-
-def _dec_row(ref, row0, iy, L: int, used: int, addr: Tuple[int, int, int]):
-    """One image row (``used`` elements) of an operand whose image row 0
-    starts at arena row ``row0`` of a (rows, L) ref, at a traced row index
-    ``iy``. Packed rows live at lane phase ``(iy % c) * rl`` of arena row
-    ``iy // c``; a spanning image row occupies ``k`` consecutive arena
-    rows."""
-    c, k, rl = addr
-    if c > 1:
-        row = ref[pl.dslice(row0 + iy // c, 1), :].reshape(L)
-        return jax.lax.dynamic_slice(row, ((iy % c) * rl,), (rl,))
-    if k > 1:
-        return ref[pl.dslice(row0 + iy * k, k), :].reshape(k * L)[:used]
-    return ref[pl.dslice(row0 + iy, 1), :].reshape(L)[:used]
-
-
-def _dec_block(block, rows: int, used: int, L: int,
-               addr: Tuple[int, int, int], n: int):
-    """A whole (rows, L) operand block flattened to its first ``n``
-    elements. Packed/legacy rows are contiguous over the used prefix
-    (packing is row-major in image order); spanning rows carry per-image-row
-    column padding that must be stripped."""
-    _, k, rl = addr
-    if k > 1:
-        h = rows // k
-        flat = block.reshape(h, k * L)[:, :rl].reshape(h * rl)
-    else:
-        flat = block[:, :used].reshape(rows * used)
-    return flat[:n]
-
-
-def _enc_block(value, rows: int, used: int, L: int, dt,
-               addr: Tuple[int, int, int]):
-    """Inverse of :func:`_dec_block`: an output tensor as a padded
-    (rows, L) arena block under the given addressing."""
-    _, k, rl = addr
-    if k > 1:
-        h = rows // k
-        flat = value.reshape(-1).astype(dt)
-        if flat.size < h * rl:
-            flat = jnp.concatenate([flat, jnp.zeros(h * rl - flat.size, dt)])
-        return _pad_cols(flat.reshape(h, rl), h, rl, k * L,
-                         dt).reshape(rows, L)
-    return _out_block(value, rows, used, L, dt)
-
-
 class _BlockMem:
-    """Row-blocked accessor: whole arena rows of a typed (R, L) buffer via
-    ``pl.dslice`` on the row axis — no bitcasts, compiled-mode lowerable."""
+    """Row-blocked accessor over a typed (R, L) ref: image rows by arena
+    row (static or traced) and lane phase, whole blocks as matrices — no
+    bitcasts, compiled-mode lowerable."""
 
     def __init__(self, ref, spec: OpSpec):
         self.ref, self.spec = ref, spec
-        self.dt = _jnp_dtype(spec.dtype)
+        self.cdt = _cdt(spec.dtype)
         self.L = spec.rowlen
 
     def _in_ref(self, i: int):
@@ -326,45 +495,43 @@ class _BlockMem:
     def _out_ref(self):
         return self.ref
 
+    def _in_off(self, i: int):
+        return self.spec.in_off[i]
+
+    def _out_off(self):
+        return self.spec.out_off
+
+    def is_image(self, i: int) -> bool:
+        rows, used = self.spec.in_rows[i]
+        return _is_image(self.spec.in_shape[i], rows, used,
+                         _addr_in(self.spec, i))
+
+    def out_is_image(self) -> bool:
+        rows, used = self.spec.out_rows
+        return _is_image(self.spec.out_shape, rows, used,
+                         _addr_out(self.spec))
+
     def read_t(self, i: int):
         rows, used = self.spec.in_rows[i]
-        shape = self.spec.in_shape[i]
-        block = self._in_ref(i)[pl.dslice(self.spec.in_off[i], rows), :]
-        return _dec_block(block, rows, used, self.L, _addr_in(self.spec, i),
-                          _elems(shape)).reshape(shape)
+        return _block_matrix(self._in_ref(i), self._in_off(i), rows, used,
+                             _addr_in(self.spec, i), self.spec.in_shape[i])
 
     def read_row(self, i: int, iy):
         used = _elems(self.spec.in_shape[i][-2:])
-        return _dec_row(self._in_ref(i), self.spec.in_off[i], iy, self.L,
-                        used, _addr_in(self.spec, i))
+        return _image_row(self._in_ref(i), self._in_off(i), iy, used,
+                          _addr_in(self.spec, i))
 
     def write(self, value):
         rows, used = self.spec.out_rows
-        self._out_ref()[pl.dslice(self.spec.out_off, rows), :] = \
-            _enc_block(value, rows, used, self.L, self.dt,
-                       _addr_out(self.spec))
+        n, c = _mat_shape(self.spec.out_shape)
+        _put_block_matrix(self._out_ref(), self._out_off(), rows, used,
+                          self.L, _addr_out(self.spec),
+                          value.astype(self.cdt).reshape(n, c))
 
     def write_row(self, oy, value):
-        # A packed row store is a read-modify-write of the whole arena row
-        # (the other lane phases must survive); safe because the row loop is
-        # sequential and the planner's O_s is derived at whole-arena-row
-        # granularity, phases included.
-        used = _elems(self.spec.out_shape[-2:])
-        c, k, rl = _addr_out(self.spec)
-        ref, off = self._out_ref(), self.spec.out_off
-        val = value.reshape(-1).astype(self.dt)
-        if c > 1:
-            ar = off + oy // c
-            row = ref[pl.dslice(ar, 1), :].reshape(self.L)
-            row = jax.lax.dynamic_update_slice(row, val, ((oy % c) * rl,))
-            ref[pl.dslice(ar, 1), :] = row.reshape(1, self.L)
-        elif k > 1:
-            ref[pl.dslice(off + oy * k, k), :] = _pad_cols(
-                val.reshape(1, used), 1, used, k * self.L,
-                self.dt).reshape(k, self.L)
-        else:
-            ref[pl.dslice(off + oy, 1), :] = \
-                _pad_cols(val.reshape(1, used), 1, used, self.L, self.dt)
+        _put_image_row(self._out_ref(), self._out_off(), oy, self.L,
+                       _addr_out(self.spec),
+                       _flatten(value.astype(self.cdt)))
 
     def fori_rows(self, oh: int, body) -> None:
         jax.lax.fori_loop(0, oh, body, 0)
@@ -398,111 +565,73 @@ class _RoutedBlockMem(_RoutedMem, _BlockMem):
     pass
 
 
-class _StreamRollMem:
+class _StreamRollMem(_BlockMem):
     """Streaming accessor for one output-row tile of a rolling-window
     conv/pool: reads index the double-buffered VMEM input-window slot
     (arena row ``r`` lives at scratch row ``r - base``; reads that fall
-    outside the window are the kernels' clamped+masked taps, which the
-    dynamic slice clamps in-bounds and the mask discards), writes land in
-    the one-tile output slot and DMA straight back to the arena row they
-    belong to. ``fori_rows`` restricts the shared kernel bodies to this
-    tile's output rows — the bodies themselves stay written-once."""
+    outside the window are the kernels' clamped+masked taps, clamped
+    in-bounds here and discarded by the mask), writes land in the output
+    slot, which mirrors arena rows from ``out_base`` on and is copied back
+    whole after the tile. ``fori_rows`` restricts the shared kernel bodies
+    to this tile's output rows — the bodies themselves stay written-once."""
 
-    def __init__(self, in_ref, out_ref, arena_ref, sem, spec: OpSpec,
-                 base, row_lo, row_hi):
-        self.in_ref, self.out_ref = in_ref, out_ref
-        self.arena_ref, self.sem, self.spec = arena_ref, sem, spec
+    def __init__(self, in_ref, out_ref, spec: OpSpec, base, out_base,
+                 row_lo, row_hi):
+        super().__init__(in_ref, spec)
+        self.out_ref, self.out_base = out_ref, out_base
         self.base, self.row_lo, self.row_hi = base, row_lo, row_hi
-        self.dt = _jnp_dtype(spec.dtype)
-        self.L = spec.rowlen
 
     def read_row(self, i: int, iy):
         used = _elems(self.spec.in_shape[i][-2:])
-        return _dec_row(self.in_ref, self.spec.in_off[i] - self.base, iy,
-                        self.L, used, _addr_in(self.spec, i))
+        c, k, _ = _addr_in(self.spec, i)
+        # clamp the window-relative image row so every arena row it touches
+        # lies inside the window slot
+        last = self.ref.shape[0] - 1
+        row0 = self.spec.in_off[i] - self.base
+        if c > 1:
+            lo, hi = -row0 * c, (last - row0) * c + c - 1
+        else:
+            lo, hi = -(row0 // k), (last - row0 - k + 1) // k
+        return _image_row(self.ref, row0, jnp.clip(iy, lo, hi), used,
+                          _addr_in(self.spec, i))
 
     def write_row(self, oy, value):
-        # Packed output rows RMW their slot row (phases accumulate — the
-        # tile covers whole arena rows, ``out_tile = sub*c`` image rows) and
-        # DMA the whole arena row back per phase; the redundant copies are
-        # idempotent and the final one carries every phase. Spanning rows
-        # write and copy ``k`` arena rows at once.
-        used = _elems(self.spec.out_shape[-2:])
-        c, k, rl = _addr_out(self.spec)
-        val = value.reshape(-1).astype(self.dt)
-        if c > 1:
-            ar = oy // c                    # operand-relative arena row
-            j = ar - self.row_lo // c       # slot row (row_lo % c == 0)
-            row = self.out_ref[pl.dslice(j, 1), :].reshape(self.L)
-            row = jax.lax.dynamic_update_slice(row, val, ((oy % c) * rl,))
-            self.out_ref[pl.dslice(j, 1), :] = row.reshape(1, self.L)
-            n = 1
-        elif k > 1:
-            ar = oy * k
-            j = (oy - self.row_lo) * k
-            self.out_ref[pl.dslice(j, k), :] = _pad_cols(
-                val.reshape(1, used), 1, used, k * self.L,
-                self.dt).reshape(k, self.L)
-            n = k
-        else:
-            ar = oy
-            j = oy - self.row_lo
-            self.out_ref[pl.dslice(j, 1), :] = \
-                _pad_cols(val.reshape(1, used), 1, used, self.L, self.dt)
-            n = 1
-        cp = pltpu.make_async_copy(
-            self.out_ref.at[pl.dslice(j, n), :],
-            self.arena_ref.at[pl.dslice(self.spec.out_off + ar, n), :],
-            self.sem)
-        cp.start()
-        cp.wait()
+        # packed rows read-modify-write their slot row, so the lane phases
+        # of one arena row accumulate across the tile's image rows
+        _put_image_row(self.out_ref, self.spec.out_off - self.out_base, oy,
+                       self.L, _addr_out(self.spec),
+                       _flatten(value.astype(self.cdt)))
 
     def fori_rows(self, oh: int, body) -> None:
         jax.lax.fori_loop(self.row_lo, self.row_hi, body, 0)
 
 
-class _StreamStageMem:
+class _StreamStageMem(_BlockMem):
     """Streaming accessor for a staged whole-block op: operand blocks were
     DMA'd into packed scratch slots before the body runs (read-all before
-    write-all — exactly the blocked kernels' order), the output block is
-    staged in its slot and copied back in one DMA."""
+    write-all — exactly the blocked kernels' order); the output block is
+    staged in its slot, which the kernel copies back after the body."""
 
-    def __init__(self, ref, arena_ref, sem, spec: OpSpec,
-                 offs: Tuple[int, ...], out_slot: int):
-        self.ref, self.arena_ref, self.sem, self.spec = \
-            ref, arena_ref, sem, spec
+    def __init__(self, ref, spec: OpSpec, offs: Tuple[int, ...],
+                 out_slot: int):
+        super().__init__(ref, spec)
         self.offs, self.out_slot = offs, out_slot
-        self.dt = _jnp_dtype(spec.dtype)
-        self.L = spec.rowlen
 
-    def read_t(self, i: int):
-        rows, used = self.spec.in_rows[i]
-        shape = self.spec.in_shape[i]
-        block = self.ref[pl.dslice(self.offs[i], rows), :]
-        return _dec_block(block, rows, used, self.L, _addr_in(self.spec, i),
-                          _elems(shape)).reshape(shape)
+    def _in_off(self, i: int):
+        return self.offs[i]
 
-    def write(self, value):
-        rows, used = self.spec.out_rows
-        self.ref[pl.dslice(self.out_slot, rows), :] = \
-            _enc_block(value, rows, used, self.L, self.dt,
-                       _addr_out(self.spec))
-        cp = pltpu.make_async_copy(
-            self.ref.at[pl.dslice(self.out_slot, rows), :],
-            self.arena_ref.at[pl.dslice(self.spec.out_off, rows), :],
-            self.sem)
-        cp.start()
-        cp.wait()
+    def _out_off(self):
+        return self.out_slot
 
-
-def _mem(ref, spec: OpSpec):
-    return _BlockMem(ref, spec) if spec.rowlen else _FlatMem(ref, spec)
+    def out_is_image(self) -> bool:
+        return False    # the output block is staged whole, then DMA'd
 
 
 def _requant(acc, mult: float, zp: int):
-    """jnp mirror of repro.core.exec.ops.requantise (same f32 arithmetic)."""
+    """jnp mirror of repro.core.exec.ops.requantise (same f32 arithmetic);
+    the int8-range result stays in the int32 compute dtype."""
     q = jnp.round(acc.astype(jnp.float32) * jnp.float32(mult)) + zp
-    return jnp.clip(q, -128, 127).astype(jnp.int8)
+    return jnp.clip(q, -128, 127).astype(jnp.int32)
 
 
 def _dequant(x, scale: float, zp: int):
@@ -511,7 +640,30 @@ def _dequant(x, scale: float, zp: int):
 
 def _quant(v, scale: float, zp: int):
     q = jnp.round(v / jnp.float32(scale)) + zp
-    return jnp.clip(q, -128, 127).astype(jnp.int8)
+    return jnp.clip(q, -128, 127).astype(jnp.int32)
+
+
+def _dot(a, b):
+    """f32 matrix product at full f32 precision (the MXU's default would
+    round the operands to bf16). Float sums go through it too: a dot's
+    summation order does not depend on how the compiler fuses its operands'
+    producers, so every arena program rounds alike."""
+    return jnp.dot(a, b, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _qdot(a, b, a_zp: int, b_zp: int):
+    """Exact ``(a - a_zp) @ (b - b_zp)`` over int8-range int32 operands, as
+    one int8 x int8 -> int32 product plus zero-point corrections (the MXU
+    multiplies int8, not int32)."""
+    acc = jnp.dot(a.astype(jnp.int8), b.astype(jnp.int8),
+                  preferred_element_type=jnp.int32)
+    if a_zp:
+        acc = acc - a_zp * jnp.sum(b, axis=0, keepdims=True)
+    if b_zp:
+        acc = acc - b_zp * jnp.sum(a, axis=1, keepdims=True)
+        acc = acc + a.shape[1] * a_zp * b_zp
+    return acc
 
 
 # ---------------------------------------------------------------------------
@@ -528,39 +680,45 @@ def _conv_kernel(mem, w_ref, *, spec: OpSpec):
     kh, kw, sh, sw, dh, dw, ph, pw, mult = spec.meta
     depthwise = spec.kind == "depthwise_conv2d"
     quant = spec.dtype == "i8"
+    cdt = _cdt(spec.dtype)
+    x_zp, amult, y_zp = spec.qmeta if quant else (0, 1.0, 0)
+    if quant and not depthwise:
+        # int8 MXU products of the raw taps; padding taps carry x_zp, so
+        # one correction per output channel removes every zero point
+        wsum = sum(jnp.sum(w_ref[fy, fx].astype(jnp.int32), axis=0,
+                           keepdims=True)
+                   for fy in range(kh) for fx in range(kw))
 
     def body(oy, _):
-        if quant:
-            x_zp, amult, y_zp = spec.qmeta
-            acc = jnp.zeros((ow, oc), jnp.int32)
-        else:
-            acc = jnp.zeros((ow, oc), jnp.float32)
+        acc = jnp.zeros((ow, oc), cdt)
         for fy in range(kh):                    # static unroll (kh small)
             iy = oy * sh - ph + fy * dh
             row_ok = (iy >= 0) & (iy < ih)
             iy_c = jnp.clip(iy, 0, ih - 1)
-            row = mem.read_row(0, iy_c).reshape(iw, ic)
-            if quant:
-                row = row.astype(jnp.int32) - x_zp
+            phases = _pixel_phases(mem.read_row(0, iy_c), iw, ic, sw)
             for fx in range(kw):
                 ix = jax.lax.broadcasted_iota(jnp.int32, (ow, 1), 0)
                 ix = ix * sw - pw + fx * dw
                 valid = (ix >= 0) & (ix < iw) & row_ok
-                taps = jnp.take_along_axis(row, jnp.clip(ix, 0, iw - 1),
-                                           axis=0)          # (ow, ic)
-                taps = jnp.where(valid, taps, 0 if quant else 0.0)
+                taps = _taps(phases, sw, fx * dw - pw, ow, ic, cdt)
                 w = w_ref[fy, fx]
-                if quant:
-                    w = w.astype(jnp.int32)
                 if depthwise:
-                    acc += (taps[:, :, None]
-                            * w[None, :, :]).reshape(ow, ic * mult)
+                    if mult > 1:
+                        taps = jnp.repeat(taps, mult, axis=1)
+                    # the select sits between product and sum, so no
+                    # compiler fuses them into one FMA: every program
+                    # rounds each tap alike
+                    acc += jnp.where(valid, (taps - x_zp) * w.astype(cdt), 0)
+                    continue
+                taps = jnp.where(valid, taps, x_zp)    # (ow, ic)
+                if quant:
+                    acc += jnp.dot(taps.astype(jnp.int8), w,
+                                   preferred_element_type=jnp.int32)
                 else:
-                    acc += jnp.dot(
-                        taps, w, preferred_element_type=(
-                            jnp.int32 if quant else jnp.float32))
-        out = _requant(acc, amult, y_zp) if quant else acc
-        mem.write_row(oy, out)
+                    acc += _dot(taps, w)
+        if quant and not depthwise:
+            acc = acc - x_zp * wsum
+        mem.write_row(oy, _requant(acc, amult, y_zp) if quant else acc)
         return 0
 
     mem.fori_rows(oh, body)
@@ -571,6 +729,7 @@ def _pool_kernel(mem, *, spec: OpSpec):
     oh, ow, _ = spec.out_shape[-3:]
     kh, kw, sh, sw, ph, pw, mode = spec.meta
     quant = spec.dtype == "i8"
+    cdt = _cdt(spec.dtype)
 
     def body(oy, _):
         if quant:
@@ -584,15 +743,12 @@ def _pool_kernel(mem, *, spec: OpSpec):
             iy = oy * sh - ph + fy
             row_ok = (iy >= 0) & (iy < ih)
             iy_c = jnp.clip(iy, 0, ih - 1)
-            row = mem.read_row(0, iy_c).reshape(iw, c)
-            if quant:
-                row = row.astype(jnp.int32)
+            phases = _pixel_phases(mem.read_row(0, iy_c), iw, c, sw)
             for fx in range(kw):
                 ix = jax.lax.broadcasted_iota(jnp.int32, (ow, 1), 0)
                 ix = ix * sw - pw + fx
                 valid = (ix >= 0) & (ix < iw) & row_ok
-                taps = jnp.take_along_axis(row, jnp.clip(ix, 0, iw - 1),
-                                           axis=0)
+                taps = _taps(phases, sw, fx - pw, ow, c, cdt)
                 if mode == "max":
                     acc = jnp.where(valid, jnp.maximum(acc, taps), acc)
                 else:
@@ -619,8 +775,11 @@ def _elementwise_kernel(mem, *, spec: OpSpec):
     if spec.dtype == "i8":
         in_q, (ys, yzp) = spec.qmeta
         xs = [_dequant(x, s, zp) for x, (s, zp) in zip(xs, in_q)]
-    if len(xs) == 2 and _elems(spec.in_shape[1]) != _elems(spec.in_shape[0]):
-        xs[1] = jnp.broadcast_to(xs[1], xs[0].shape)
+    if len(xs) == 2 and xs[1].shape != xs[0].shape:
+        # trailing-axis broadcast: the smaller operand's rows repeat
+        reps = xs[0].shape[0] // xs[1].shape[0]
+        xs[1] = (jnp.broadcast_to(xs[1], xs[0].shape) if xs[1].shape[0] == 1
+                 else jnp.tile(xs[1], (reps, 1)))
     v = fn(*xs).astype(jnp.float32)
     mem.write(_quant(v, ys, yzp) if spec.dtype == "i8" else v)
 
@@ -631,7 +790,7 @@ def _softmax_kernel(mem, *, spec: OpSpec):
         (xs, xzp), (ys, yzp) = spec.qmeta
         x = _dequant(x, xs, xzp)
     e = jnp.exp(x - jnp.max(x, axis=-1, keepdims=True))
-    y = e / jnp.sum(e, axis=-1, keepdims=True)
+    y = e / _dot(e, jnp.ones((e.shape[1], 1), jnp.float32))
     mem.write(_quant(y, ys, yzp) if spec.dtype == "i8" else y)
 
 
@@ -640,13 +799,11 @@ def _fully_connected_kernel(mem, w_ref, *, spec: OpSpec):
     x = mem.read_t(0).reshape(-1, idim)
     if spec.dtype == "i8":
         x_zp, amult, y_zp = spec.qmeta
-        acc = jnp.dot(x.astype(jnp.int32) - x_zp,
-                      w_ref[...].astype(jnp.int32),
-                      preferred_element_type=jnp.int32)
+        acc = _qdot(x, w_ref[...].astype(jnp.int32), x_zp, 0)
         y = _requant(acc, amult, y_zp)
     else:
-        y = jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
-    mem.write(y.reshape(spec.out_shape))
+        y = _dot(x, w_ref[...])
+    mem.write(y)
 
 
 def _matmul_kernel(mem, *, spec: OpSpec):
@@ -654,13 +811,10 @@ def _matmul_kernel(mem, *, spec: OpSpec):
     b = mem.read_t(1)
     if spec.dtype == "i8":
         a_zp, b_zp, amult, y_zp = spec.qmeta
-        acc = jnp.dot(a.astype(jnp.int32) - a_zp,
-                      b.astype(jnp.int32) - b_zp,
-                      preferred_element_type=jnp.int32)
-        y = _requant(acc, amult, y_zp)
+        y = _requant(_qdot(a, b, a_zp, b_zp), amult, y_zp)
     else:
-        y = jnp.dot(a, b, preferred_element_type=jnp.float32)
-    mem.write(y.reshape(spec.out_shape))
+        y = _dot(a, b)
+    mem.write(y)
 
 
 def _rescale(x, src, dst):
@@ -671,16 +825,35 @@ def _rescale(x, src, dst):
 
 
 def _concat_kernel(mem, *, spec: OpSpec):
-    axis = spec.meta[0]
-    xs = [mem.read_t(i) for i in range(len(spec.in_shape))]
+    n_in = len(spec.in_shape)
+    rank = len(spec.out_shape)
+    axis = spec.meta[0] % rank
     if spec.dtype == "i8":
         in_q, (yzp,) = spec.qmeta
-        xs = [_rescale(x, q, (yzp,)) for x, q in zip(xs, in_q)]
+        fix = [functools.partial(_rescale, src=q, dst=(yzp,)) for q in in_q]
+    else:
+        fix = [lambda x: x] * n_in
+    if rank >= 3 and axis == rank - 3 and mem.out_is_image() and all(
+            mem.is_image(i) for i in range(n_in)):
+        # image-row concat (split-band reassembly): copy rows in place
+        y0 = 0
+        for i in range(n_in):
+            for y in range(spec.in_shape[i][-3]):
+                mem.write_row(y0 + y, fix[i](mem.read_row(i, y)))
+            y0 += spec.in_shape[i][-3]
+        return
+    xs = [fix[i](mem.read_t(i)) for i in range(n_in)]
+    if axis in (0, rank - 1):
+        # the leading axis joins flat streams, the last axis the matrices'
+        # columns
+        mem.write(jnp.concatenate(xs, axis=0 if axis == 0 else 1))
+        return
+    xs = [x.reshape(s) for x, s in zip(xs, spec.in_shape)]
     mem.write(jnp.concatenate(xs, axis=axis))
 
 
 def _pad_kernel(mem, *, spec: OpSpec):
-    x = mem.read_t(0)
+    x = mem.read_t(0).reshape(spec.in_shape[0])
     if spec.dtype == "i8":
         (x_zp, mult), (y_zp,) = spec.qmeta
         padded = jnp.pad(x, spec.meta[0], constant_values=x_zp)
@@ -691,18 +864,25 @@ def _pad_kernel(mem, *, spec: OpSpec):
 
 def _mean_kernel(mem, *, spec: OpSpec):
     x = mem.read_t(0)
-    axes = spec.meta[0]
+    shape = spec.in_shape[0]
+    axes = tuple(a % len(shape) for a in spec.meta[0])
+    lead = axes == tuple(range(len(shape) - 1))
+    if not lead:
+        x = x.reshape(shape)
+    cnt = 1
+    for ax in axes:
+        cnt *= shape[ax]
     if spec.dtype == "i8":
         x_zp, amult, y_zp = spec.qmeta
-        cnt = 1
-        for ax in axes:
-            cnt *= x.shape[ax]
-        acc = jnp.sum(x.astype(jnp.int32), axis=axes)
+        acc = (jnp.sum(x, axis=0, keepdims=True) if lead
+               else jnp.sum(x, axis=axes))
         val = acc.astype(jnp.float32) / jnp.float32(cnt) - x_zp
         y = _requant(val, amult, y_zp)
+    elif lead:
+        y = _dot(jnp.ones((1, x.shape[0]), jnp.float32), x) / jnp.float32(cnt)
     else:
         y = jnp.mean(x, axis=axes)
-    mem.write(y.reshape(spec.out_shape))
+    mem.write(y)
 
 
 _BODIES = {
@@ -719,12 +899,6 @@ _BODIES = {
 }
 
 
-def _plain_kernel(*refs, spec: OpSpec):
-    """Flat/row-blocked kernel: refs are (arena_in, *weights, arena_out);
-    the body reads and writes through the aliased output ref."""
-    _BODIES[spec.kind](_mem(refs[-1], spec), *refs[1:-1], spec=spec)
-
-
 def spec_weight_count(spec: OpSpec) -> int:
     """Weight operands a lowered spec consumes (a fused chain consumes all
     of its stages' weights, in stage order)."""
@@ -733,30 +907,123 @@ def spec_weight_count(spec: OpSpec) -> int:
     return 1 if spec.kind in WEIGHTED_KINDS else 0
 
 
-def _fused_kernel(*refs, spec: OpSpec):
-    """Fused band-chain super-kernel (flat or row-blocked program): refs are
-    (arena_in, *stage_weights, arena_out, scratch). Stages run in graph
-    order against the aliased arena-out ref; chain-internal operands route
-    to the VMEM scratch ref per their stage flags, so intermediate bands and
-    their halo rows never touch the arena — only the terminal stage (the
-    reassembling concat) writes back at the planned offset. Stage order is
-    the graph order, so every read of the chain input precedes the terminal
-    write: the planner may overlap the chain's input and output via plain
-    disjoint liveness."""
-    o_ref, scratch = refs[-2], refs[-1]
-    w_refs = refs[1:-2]
+def _run_stages(spec: OpSpec, mem_of, w_refs) -> None:
+    """Fused band chain: run the stages in graph order, each against the
+    accessor ``mem_of(stage)``. Chain-internal operands route to the VMEM
+    scratch per the stage flags, so intermediate bands and their halo rows
+    never touch the arena — only the terminal stage (the reassembling
+    concat) writes at the planned offset. Stage order is the graph order,
+    so every read of the chain input precedes the terminal write: the
+    planner may overlap the chain's input and output via plain disjoint
+    liveness."""
     wi = 0
     for st in spec.stages:
-        nw = 1 if st.kind in WEIGHTED_KINDS else 0
-        cls = _RoutedBlockMem if st.rowlen else _RoutedFlatMem
-        _BODIES[st.kind](cls(o_ref, scratch, st), *w_refs[wi:wi + nw],
-                         spec=st)
+        nw = spec_weight_count(st)
+        _BODIES[st.kind](mem_of(st), *w_refs[wi:wi + nw], spec=st)
         wi += nw
 
 
+def _flat_kernel(*refs, spec: OpSpec):
+    """Flat byte program (interpret mode only): refs are (arena_in,
+    *weights, arena_out[, chain scratch]); the body reads and writes
+    through the aliased output ref, which the interpreter hands the
+    arena's contents."""
+    nw = spec_weight_count(spec)
+    w_refs, o_ref = refs[1:1 + nw], refs[1 + nw]
+    if spec.kind == "fused":
+        scratch = refs[2 + nw]
+        _run_stages(spec, lambda st: _RoutedFlatMem(o_ref, scratch, st),
+                    w_refs)
+    else:
+        _BODIES[spec.kind](_FlatMem(o_ref, spec), *w_refs, spec=spec)
+
+
+def _block_kernel(a_ref, *rest, spec: OpSpec):
+    """Row-blocked VMEM-resident program: refs are (arena in HBM,
+    *weights, arena block in VMEM[, chain scratch], DMA semaphore). A
+    kernel's VMEM output block starts undefined, so the arena is first
+    copied into it; the body then reads and writes it in place, and the
+    whole block goes back to the aliased HBM arena."""
+    nw = spec_weight_count(spec)
+    w_refs, o_ref = rest[:nw], rest[nw]
+    sem = rest[-1]
+    cp = pltpu.make_async_copy(a_ref, o_ref, sem)
+    cp.start()
+    cp.wait()
+    if spec.kind == "fused":
+        scratch = rest[nw + 1]
+        _run_stages(spec, lambda st: _RoutedBlockMem(o_ref, scratch, st),
+                    w_refs)
+    else:
+        _BODIES[spec.kind](_BlockMem(o_ref, spec), *w_refs, spec=spec)
+
+
 # ---------------------------------------------------------------------------
-# Streaming grid programs: arena in pltpu.ANY (HBM), live window in VMEM.
+# Streaming grid programs: arena in pl.ANY (HBM), live window in VMEM.
+# Every HBM <-> VMEM copy moves whole DMA row groups (planner.DMA_ROWS):
+# scratch slots hold each block at its arena row modulo DMA_ROWS.
 # ---------------------------------------------------------------------------
+
+
+def _table(vals: Tuple[int, ...], t):
+    """``vals[t]`` for the grid index ``t``: a static select chain over a
+    planner table of DMA-aligned rows (a captured jnp constant is not a
+    legal kernel operand; tables are short)."""
+    s = jnp.int32(vals[0])
+    for i in range(1, len(vals)):
+        s = jnp.where(t >= i, jnp.int32(vals[i]), s)
+    return pl.multiple_of(s, DMA_ROWS)
+
+
+def _fetch_blocks(arena_ref, buf, blocks, sems) -> None:
+    """DMA each ``(arena row, rows, slot row)`` block's aligned span into
+    the scratch slot (the slot row sits at the arena row modulo DMA_ROWS),
+    pipelined over two rotating semaphores; returns once all landed."""
+    cps = []
+    for i, (off, rows, slot) in enumerate(blocks):
+        lo, n = dma_span(off, rows)
+        cps.append(pltpu.make_async_copy(
+            arena_ref.at[pl.dslice(lo, n), :],
+            buf.at[pl.dslice(slot - (off - lo), n), :],
+            sems.at[i % 2]))
+    for cp in cps[:2]:
+        cp.start()
+    for i, cp in enumerate(cps):
+        cp.wait()
+        if i + 2 < len(cps):
+            cps[i + 2].start()
+
+
+def _copy_back(buf, slot: int, arena_ref, off: int, rows: int, edge,
+               sem) -> None:
+    """Copy the block staged at scratch row ``slot`` back to arena rows
+    ``[off, off + rows)`` (``slot`` is ``off`` modulo DMA_ROWS). Whole DMA
+    row groups go straight back; a group the block only partly covers is
+    merged into the arena's current rows in the ``edge`` buffer first, so
+    rows outside the block keep their values."""
+    D = DMA_ROWS
+    hi = off + rows
+    a, b = -(-off // D) * D, hi // D * D       # wholly covered groups
+    if a < b:
+        cp = pltpu.make_async_copy(buf.at[pl.dslice(slot + a - off, b - a), :],
+                                   arena_ref.at[pl.dslice(a, b - a), :], sem)
+        cp.start()
+        cp.wait()
+    for g in sorted({off // D * D, (hi - 1) // D * D} - set(range(a, b, D))):
+        cp = pltpu.make_async_copy(arena_ref.at[pl.dslice(g, D), :], edge,
+                                   sem)
+        cp.start()
+        cp.wait()
+        cdt = jnp.int32 if edge.dtype == jnp.int8 else edge.dtype
+        ours = buf[pl.dslice(slot + g - off, D), :].astype(cdt)
+        row = jax.lax.broadcasted_iota(jnp.int32, ours.shape, 0) + g
+        keep = (row >= off) & (row < hi)
+        edge[...] = jnp.where(keep, ours, edge[...].astype(cdt)
+                              ).astype(edge.dtype)
+        cp = pltpu.make_async_copy(edge, arena_ref.at[pl.dslice(g, D), :],
+                                   sem)
+        cp.start()
+        cp.wait()
 
 
 def _stream_roll_kernel(a_ref, *rest, spec: OpSpec):
@@ -771,29 +1038,26 @@ def _stream_roll_kernel(a_ref, *rest, spec: OpSpec):
     clamped+masked taps (the O_s row invariant keeps every *live* read at
     arena rows >= the write frontier), so the overlap is benign. Fetches
     source the aliased *output* ref so the window observes all previous
-    write-backs."""
-    nw = 1 if spec.kind in WEIGHTED_KINDS else 0
+    write-backs. The output slot mirrors the tile's DMA-aligned arena span
+    (:func:`repro.core.planner.tile_writeback`): it is filled from the
+    arena, the tile's rows land in it, and it goes back whole."""
+    nw = spec_weight_count(spec)
     w_refs, o_ref = rest[:nw], rest[nw]
     in_win, out_buf, in_sems, out_sem = rest[nw + 1:]
 
     oh = spec.out_shape[-3]
     T = len(spec.win_starts)
     tr, tile_ar = _tile_geom(spec)
+    c, k, _ = _addr_out(spec)
+    wb, slot_rows = tile_writeback(spec.out_off, oh, c, k, _sub(spec.dtype),
+                                   o_ref.shape[0])
     win_in = spec.win_rows - tile_ar
     t = pl.program_id(0)
-
-    def start_of(tt):
-        # static select chain over the planner's table (a captured jnp
-        # constant is not a legal kernel operand; T is small)
-        s = jnp.int32(spec.win_starts[0])
-        for i in range(1, T):
-            s = jnp.where(tt >= i, jnp.int32(spec.win_starts[i]), s)
-        return s
 
     def fetch(tt):
         slot = jax.lax.rem(tt, 2)
         return pltpu.make_async_copy(
-            o_ref.at[pl.dslice(start_of(tt), win_in), :],
+            o_ref.at[pl.dslice(_table(spec.win_starts, tt), win_in), :],
             in_win.at[slot],
             in_sems.at[slot])
 
@@ -805,178 +1069,182 @@ def _stream_roll_kernel(a_ref, *rest, spec: OpSpec):
     def _():
         fetch(t + 1).start()
 
+    out_base = _table(wb, t)
+    span = o_ref.at[pl.dslice(out_base, slot_rows), :]
+    cp = pltpu.make_async_copy(span, out_buf, out_sem)
+    cp.start()
+    cp.wait()
     fetch(t).wait()
 
     row_lo = t * tr
     row_hi = jnp.minimum(row_lo + tr, oh)
-    mem = _StreamRollMem(in_win.at[jax.lax.rem(t, 2)], out_buf, o_ref,
-                         out_sem, spec, start_of(t), row_lo, row_hi)
+    mem = _StreamRollMem(in_win.at[jax.lax.rem(t, 2)], out_buf, spec,
+                         _table(spec.win_starts, t), out_base, row_lo, row_hi)
     _BODIES[spec.kind](mem, *w_refs, spec=spec)
-
-
-def _stream_stage_kernel(a_ref, *rest, spec: OpSpec, offs, out_slot):
-    """Staged whole-block op: DMA every operand block from the ANY arena
-    into its packed VMEM slot (fetches pipelined over two rotating
-    semaphores), run the written-once body against the staged window, then
-    copy the output block back in one DMA. Read-all-before-write-all — the
-    exact element order of the blocked program, so in-place overlaps are
-    handled identically."""
-    nw = 1 if spec.kind in WEIGHTED_KINDS else 0
-    w_refs, o_ref = rest[:nw], rest[nw]
-    buf, in_sems, out_sem = rest[nw + 1:]
-
-    cps = [pltpu.make_async_copy(
-        o_ref.at[pl.dslice(spec.in_off[i], rows), :],
-        buf.at[pl.dslice(offs[i], rows), :],
-        in_sems.at[i % 2])
-        for i, (rows, _) in enumerate(spec.in_rows)]
-    for cp in cps[:2]:
-        cp.start()
-    for i, cp in enumerate(cps):
-        cp.wait()
-        if i + 2 < len(cps):
-            cps[i + 2].start()
-
-    mem = _StreamStageMem(buf, o_ref, out_sem, spec, offs, out_slot)
-    _BODIES[spec.kind](mem, *w_refs, spec=spec)
-
-
-def _stream_fused_kernel(a_ref, *rest, spec: OpSpec):
-    """Streaming fused band chain: stage every *external* input block from
-    the ANY arena into its packed VMEM slot (fetches pipelined over two
-    rotating semaphores, exactly the staged program), run ALL chain stages
-    entirely inside the scratch buffer (stage specs carry scratch-local slot
-    offsets for every operand — internals, externals and the terminal
-    output alike), then copy the terminal output block back in one DMA. The
-    chain's VMEM residency is the :func:`repro.core.planner.fused_slots`
-    ``include_io`` packing = the window schedule's ``win_rows``."""
-    nw = spec_weight_count(spec)
-    w_refs, o_ref = rest[:nw], rest[nw]
-    buf, in_sems, out_sem = rest[nw + 1:]
-
-    cps = [pltpu.make_async_copy(
-        o_ref.at[pl.dslice(spec.in_off[i], rows), :],
-        buf.at[pl.dslice(spec.in_slots[i], rows), :],
-        in_sems.at[i % 2])
-        for i, (rows, _) in enumerate(spec.in_rows)]
-    for cp in cps[:2]:
-        cp.start()
-    for i, cp in enumerate(cps):
-        cp.wait()
-        if i + 2 < len(cps):
-            cps[i + 2].start()
-
-    wi = 0
-    for st in spec.stages:
-        snw = 1 if st.kind in WEIGHTED_KINDS else 0
-        _BODIES[st.kind](_BlockMem(buf, st), *w_refs[wi:wi + snw], spec=st)
-        wi += snw
-
-    rows, _ = spec.out_rows
-    cp = pltpu.make_async_copy(
-        buf.at[pl.dslice(spec.out_slot, rows), :],
-        o_ref.at[pl.dslice(spec.out_off, rows), :],
-        out_sem)
+    cp = pltpu.make_async_copy(out_buf, span, out_sem)
     cp.start()
     cp.wait()
 
 
+def _stream_stage_kernel(a_ref, *rest, spec: OpSpec, offs, out_slot):
+    """Staged whole-block op: DMA every operand block from the ANY arena
+    into its packed VMEM slot, run the written-once body against the
+    staged window, then copy the output block back. Read-all-before-
+    write-all — the exact element order of the blocked program, so
+    in-place overlaps are handled identically."""
+    nw = spec_weight_count(spec)
+    w_refs, o_ref = rest[:nw], rest[nw]
+    buf, edge, in_sems, out_sem = rest[nw + 1:]
+    _fetch_blocks(o_ref, buf, [(off, rows, slot) for off, (rows, _), slot
+                               in zip(spec.in_off, spec.in_rows, offs)],
+                  in_sems)
+    _BODIES[spec.kind](_StreamStageMem(buf, spec, offs, out_slot), *w_refs,
+                       spec=spec)
+    _copy_back(buf, out_slot, o_ref, spec.out_off, spec.out_rows[0], edge,
+               out_sem)
+
+
+def _stream_fused_kernel(a_ref, *rest, spec: OpSpec):
+    """Streaming fused band chain: stage every *external* input block from
+    the ANY arena into its packed VMEM slot (exactly the staged program),
+    run ALL chain stages entirely inside the scratch buffer (stage specs
+    carry scratch-local slot offsets for every operand — internals,
+    externals and the terminal output alike), then copy the terminal output
+    block back. The chain's VMEM residency is the
+    :func:`repro.core.planner.fused_slots` ``include_io`` packing = the
+    window schedule's ``win_rows``."""
+    nw = spec_weight_count(spec)
+    w_refs, o_ref = rest[:nw], rest[nw]
+    buf, edge, in_sems, out_sem = rest[nw + 1:]
+    _fetch_blocks(o_ref, buf, [(off, rows, slot) for off, (rows, _), slot
+                               in zip(spec.in_off, spec.in_rows,
+                                      spec.in_slots)], in_sems)
+    _run_stages(spec, lambda st: _BlockMem(buf, st), w_refs)
+    _copy_back(buf, spec.out_slot, o_ref, spec.out_off, spec.out_rows[0],
+               edge, out_sem)
+
+
+def _kernel_weights(spec: OpSpec, weights: Tuple[jax.Array, ...]):
+    """Weights as the kernel bodies index them: a depthwise filter
+    ``(kh, kw, ic, mult)`` becomes ``(kh, kw, 1, ic*mult)`` so each tap's
+    weights load as one lane row (reshaped outside the kernel)."""
+    kinds = ([st.kind for st in spec.stages if st.kind in WEIGHTED_KINDS]
+             if spec.kind == "fused" else [spec.kind])
+    return tuple(
+        w.reshape(w.shape[0], w.shape[1], 1, -1)
+        if kind == "depthwise_conv2d" else w
+        for kind, w in zip(kinds, weights))
+
+
 def _apply_stream(arena: jax.Array, spec: OpSpec,
-                  weights: Tuple[jax.Array, ...], interpret: bool):
+                  weights: Tuple[jax.Array, ...], interpret: bool,
+                  params: dict):
     dt = _jnp_dtype(spec.dtype)
     L = spec.rowlen
     sub = _sub(spec.dtype)
     io_specs = dict(
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)]
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(weights),
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
+        **params,
     )
+    sems = [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(())]
+    edge = pltpu.VMEM((DMA_ROWS, L), dt)
     if spec.kind == "fused":                   # band-chain super-kernel
         fn = pl.pallas_call(
             functools.partial(_stream_fused_kernel, spec=spec),
-            scratch_shapes=[
-                pltpu.VMEM((max(spec.scratch_rows, spec.win_rows), L), dt),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA(()),
-            ],
+            scratch_shapes=[pltpu.VMEM((spec.scratch_rows, L), dt),
+                            edge] + sems,
             **io_specs,
         )
     elif spec.win_starts:                      # rolling conv/dw/pool window
-        _, tile_ar = _tile_geom(spec)
+        c, k, _ = _addr_out(spec)
+        _, slot_rows = tile_writeback(spec.out_off, spec.out_shape[-3], c, k,
+                                      sub, arena.shape[0])
         fn = pl.pallas_call(
             functools.partial(_stream_roll_kernel, spec=spec),
             grid=(len(spec.win_starts),),
             scratch_shapes=[
-                pltpu.VMEM((2, spec.win_rows - tile_ar, L), dt),
-                pltpu.VMEM((tile_ar, L), dt),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA(()),
-            ],
+                pltpu.VMEM((2, spec.win_rows - _tile_geom(spec)[1], L), dt),
+                pltpu.VMEM((slot_rows, L), dt)] + sems,
             **io_specs,
         )
     else:                                      # staged whole-block op
-        from repro.core.planner import staged_slots  # no import cycle
         offs, out_slot, total = staged_slots(
-            [r for r, _ in spec.in_rows], spec.out_rows[0], sub)
+            [(o, r) for o, (r, _) in zip(spec.in_off, spec.in_rows)],
+            (spec.out_off, spec.out_rows[0]), sub)
         fn = pl.pallas_call(
-            functools.partial(_stream_stage_kernel, spec=spec,
-                              offs=offs, out_slot=out_slot),
-            scratch_shapes=[
-                pltpu.VMEM((max(total, spec.win_rows), L), dt),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA(()),
-            ],
+            functools.partial(_stream_stage_kernel, spec=spec, offs=offs,
+                              out_slot=out_slot),
+            scratch_shapes=[pltpu.VMEM((total, L), dt), edge] + sems,
             **io_specs,
         )
     return fn(arena, *weights)
 
 
 def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
-             interpret: bool = True) -> jax.Array:
+             interpret: bool, vmem_limit: Optional[int] = None) -> jax.Array:
     """Run one op in-place on the shared arena (flat 1-D byte buffer,
     row-blocked 2-D typed buffer, or ANY-space streamed buffer, per the
-    spec); returns the (aliased) arena."""
+    spec); returns the (aliased) arena. ``vmem_limit`` (bytes) is handed to
+    Mosaic as the kernel's scoped-VMEM limit when compiling."""
+    weights = _kernel_weights(spec, weights)
+    params = ({} if interpret or vmem_limit is None else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)))
     if spec.win_rows:
-        return _apply_stream(arena, spec, weights, interpret)
-    if spec.kind == "fused":
+        return _apply_stream(arena, spec, weights, interpret, params)
+    fused = spec.kind == "fused"
+    if spec.rowlen:
         # one launch for the whole chain; intermediates live in the VMEM
-        # scratch (typed rows for the blocked program, raw bytes for flat)
-        scratch = [pltpu.VMEM((spec.scratch_rows, spec.rowlen),
-                              _jnp_dtype(spec.dtype)) if spec.rowlen
-                   else pltpu.VMEM((spec.scratch_rows,), jnp.uint8)]
-        kernel = functools.partial(_fused_kernel, spec=spec)
-    else:
-        scratch = []
-        kernel = functools.partial(_plain_kernel, spec=spec)
+        # scratch (typed rows)
+        dt = _jnp_dtype(spec.dtype)
+        scratch = ([pltpu.VMEM((spec.scratch_rows, spec.rowlen), dt)]
+                   if fused else []) + [pltpu.SemaphoreType.DMA(())]
+        fn = pl.pallas_call(
+            functools.partial(_block_kernel, spec=spec),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+            + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(weights),
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+            input_output_aliases={0: 0},        # the arena is donated through
+            scratch_shapes=scratch,
+            interpret=interpret,
+            **params,
+        )
+        return fn(arena, *weights)
     fn = pl.pallas_call(
-        kernel,
+        functools.partial(_flat_kernel, spec=spec),
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={0: 0},            # the arena is donated through
-        scratch_shapes=scratch,
+        input_output_aliases={0: 0},
+        scratch_shapes=([pltpu.VMEM((spec.scratch_rows,), jnp.uint8)]
+                        if fused else []),
         interpret=interpret,
     )
     return fn(arena, *weights)
 
 
-def lower_program(specs: Tuple[OpSpec, ...], interpret: bool = True):
+def lower_program(specs: Tuple[OpSpec, ...], interpret: bool,
+                  vmem_limit: Optional[int] = None):
     """Jit-compiled executor for a spec sequence: ``fn(arena, *weights) ->
     arena``. The arena argument is donated, so together with the per-op
     aliasing the whole network runs in one shared buffer. Cached on the spec
     content — structurally identical plans share the compiled program."""
-    return _lower_program_cached(tuple(specs), bool(interpret))
+    return _lower_program_cached(tuple(specs), bool(interpret), vmem_limit)
 
 
 @functools.lru_cache(maxsize=128)
-def _lower_program_cached(specs: Tuple[OpSpec, ...], interpret: bool):
+def _lower_program_cached(specs: Tuple[OpSpec, ...], interpret: bool,
+                          vmem_limit: Optional[int]):
     weight_counts = tuple(spec_weight_count(s) for s in specs)
 
     def run(arena, *wflat):
         i = 0
         for spec, nw in zip(specs, weight_counts):
-            arena = apply_op(arena, spec, wflat[i:i + nw], interpret)
+            arena = apply_op(arena, spec, wflat[i:i + nw], interpret,
+                             vmem_limit)
             i += nw
         return arena
 

@@ -60,8 +60,8 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
                            causal: bool = True, block_q: int = 128,
                            block_k: int = 128,
                            interpret: Optional[bool] = None) -> jax.Array:
-    """q: (S,H,D); k,v: (T,H,D) -> (S,H,D). ``interpret=None`` defers to the
-    shared ``REPRO_DMO_INTERPRET`` switch (default: interpret mode)."""
+    """q: (S,H,D); k,v: (T,H,D) -> (S,H,D). ``interpret=None`` follows the
+    platform (interpret on the CPU, compiled on a TPU)."""
     interpret = resolve_interpret(interpret)
     s, h, d = q.shape
     t = k.shape[0]

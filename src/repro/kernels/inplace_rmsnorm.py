@@ -29,8 +29,8 @@ def rmsnorm_scale_residual_inplace(x: jax.Array, g: jax.Array, r: jax.Array,
                                    eps: float = 1e-6, block: int = 128,
                                    interpret: Optional[bool] = None
                                    ) -> jax.Array:
-    """x, r: (N, d); g: (d,). Output aliases x. ``interpret=None`` defers to
-    the shared ``REPRO_DMO_INTERPRET`` switch."""
+    """x, r: (N, d); g: (d,). Output aliases x. ``interpret=None`` follows
+    the platform (interpret on the CPU, compiled on a TPU)."""
     interpret = resolve_interpret(interpret)
     n, d = x.shape
     b = min(block, n)

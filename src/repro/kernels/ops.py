@@ -61,10 +61,8 @@ def dmo_dwconv2d(x: jax.Array, w: jax.Array, stride: int = 1, pad: int = 0,
                  interpret: Optional[bool] = None) -> jax.Array:
     """Depthwise conv through the shared VMEM arena. x: (IH,IW,C) f32.
 
-    The ``REPRO_DMO_INTERPRET`` default is resolved *before* the jit
-    boundary: the concrete bool is the static cache key, so flipping the
-    env between calls retraces instead of silently reusing the previous
-    lowering."""
+    The platform's interpret default is resolved *before* the jit
+    boundary: the concrete bool is the static cache key."""
     return _dmo_dwconv2d_jit(x, w, stride=stride, pad=pad,
                              interpret=resolve_interpret(interpret))
 

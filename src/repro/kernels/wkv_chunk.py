@@ -69,8 +69,8 @@ def wkv_chunk_kernel(r: jax.Array, k: jax.Array, v: jax.Array,
                      interpret: Optional[bool] = None):
     """r,k,v,logw: (B,S,H,D) (logw = log decay, <= 0); u: (H,D).
     Returns (y (B,S,H,D) f32, final state (B,H,D,D) f32).
-    ``interpret=None`` defers to the shared ``REPRO_DMO_INTERPRET``
-    switch."""
+    ``interpret=None`` follows the platform (interpret on the CPU,
+    compiled on a TPU)."""
     interpret = resolve_interpret(interpret)
     b, s, h, d = r.shape
     assert s % q == 0

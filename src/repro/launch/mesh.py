@@ -13,12 +13,14 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:
@@ -30,4 +32,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = 1, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

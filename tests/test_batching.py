@@ -264,6 +264,20 @@ def test_compile_many_shares_disk_cache(tmp_path, monkeypatch):
             == (b["graph"], b["batch"], b["peak_bytes"])
 
 
+def test_compile_many_workers_pinned_to_cpu(monkeypatch):
+    """compile_many workers only plan: each starts on the CPU platform
+    whatever the parent's, so a parent holding the chip never starves
+    them."""
+    import multiprocessing as mp
+    import os
+
+    from repro.core.pipeline import _pin_worker_to_cpu
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with mp.get_context("spawn").Pool(
+            1, initializer=_pin_worker_to_cpu) as pool:
+        assert pool.apply(os.getenv, ("JAX_PLATFORMS",)) == "cpu"
+
+
 def test_disk_store_same_key_race(tmp_path, monkeypatch):
     """Satellite (a): concurrent same-key writers race benignly through
     the tmp-file + atomic-replace protocol — two workers compiling the

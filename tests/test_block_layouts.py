@@ -290,21 +290,29 @@ def test_blocked_specs_lowering():
 
 
 # ---------------------------------------------------------------------------
-# Mode plumbing: interpret vs compiled, REPRO_DMO_INTERPRET
+# Mode plumbing: interpret vs compiled follows the platform
 # ---------------------------------------------------------------------------
 
 
-def test_default_interpret_env_switch(monkeypatch):
+def _fake_platform(monkeypatch, platform: str, kind: str = "cpu"):
+    """Make JAX report ``platform`` (and a first device of ``kind``)."""
+    import types
+
+    import jax
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+
+
+def test_default_interpret_follows_platform(monkeypatch):
     from repro.kernels.runtime import default_interpret, resolve_interpret
-    monkeypatch.delenv("REPRO_DMO_INTERPRET", raising=False)
-    assert default_interpret() is True
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "0")
+    assert default_interpret() is True          # the CPU backend
+    _fake_platform(monkeypatch, "tpu", "TPU v5 lite")
     assert default_interpret() is False
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "compiled")
-    assert default_interpret() is False
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "1")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True      # an explicit pin wins
+    _fake_platform(monkeypatch, "gpu")
     assert default_interpret() is True
-    assert resolve_interpret(False) is False  # explicit beats env
 
 
 def test_pallas_mode_plumbing(monkeypatch):
@@ -318,10 +326,14 @@ def test_pallas_mode_plumbing(monkeypatch):
     # compiled mode cannot address a flat byte arena
     with pytest.raises(ValueError, match="row-blocked"):
         PallasExecutor(mode="compiled", layout="flat")
-    # the env switch retargets the default-constructed backend
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "0")
-    assert PallasExecutor().mode == "compiled"
-    monkeypatch.delenv("REPRO_DMO_INTERPRET")
+    # compiled kernels need a TPU: no silent fallback to the interpreter
+    with pytest.raises(RuntimeError, match="need a TPU"):
+        PallasExecutor(mode="compiled").execute(plan_dmo(small_conv_graph()))
+    # the platform retargets the default-constructed backend
+    with monkeypatch.context() as m:
+        _fake_platform(m, "tpu", "TPU v5 lite")
+        assert PallasExecutor().mode == "compiled"
+        assert PallasExecutor().interpret is False
     assert PallasExecutor().mode == "interpret"
     # compiled + a non-legalisable plan must refuse rather than fall back
     g = Graph("mixed")

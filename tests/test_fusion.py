@@ -159,7 +159,10 @@ def test_fused_streaming_window_containment():
     # chain-scratch tensors exactly as the planner's _fused_window does
     _, total = P.fused_slots(members, P.chain_rows_of(bp),
                              round_to=bp.tiling[0], include_io=True)
-    assert w.win_rows == w.resident_rows == total
+    _, live = P.fused_slots(members, P.chain_rows_of(bp),
+                            round_to=bp.tiling[0], include_io=True,
+                            dma_io=False)
+    assert w.resident_rows == total and w.win_rows == live <= total
     for op in members:
         for t in list(op.inputs) + [op.output]:
             s = t.storage()

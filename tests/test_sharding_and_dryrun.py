@@ -61,7 +61,9 @@ _SUBPROC = textwrap.dedent("""
     from repro.models import transformer as T
 
     cfg = dataclasses.replace(registry()["{arch}"].reduced(), dtype="float32")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with mesh, SH.axis_env(mesh, batch=("data",)):
         st_sh = SP.state_shardings(cfg, mesh)
         state = jax.eval_shape(lambda: TS.init_state(cfg, jax.random.PRNGKey(0)))
@@ -74,8 +76,6 @@ _SUBPROC = textwrap.dedent("""
                          in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None))
         compiled = jitted.lower(state, batch).compile()
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else {{}}
         print(json.dumps({{"ok": True, "flops": ca.get("flops", 0)}}))
 """)
 
@@ -86,8 +86,7 @@ def test_subprocess_tiny_mesh_train_lowers(arch):
     """Real SPMD compile of a reduced config on an 8-device virtual mesh."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    # the forced 8-device host platform only exists on the CPU backend; an
-    # accelerator plugin on the machine would otherwise win auto-selection
+    # the forced 8-device host platform only exists on the CPU backend
     env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _SUBPROC.format(arch=arch)],
                        capture_output=True, text=True, env=env, timeout=600)

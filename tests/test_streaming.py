@@ -133,12 +133,24 @@ def test_window_containment(name):
         oh = int(op.output.shape[-3])
         ci, ki = lays[0].cols_per_row, lays[0].row_span
         tr = P.tile_rows(out.cols_per_row, out.row_span, sub)
+        wb, slot_rows = P.tile_writeback(out.row_offset, oh,
+                                         out.cols_per_row, out.row_span,
+                                         sub, bp.total_rows)
         win_in = w.win_rows - P.tile_arena_rows(
             out.cols_per_row, out.row_span, sub)
         assert len(w.starts) == -(-oh // tr)
         for s in w.starts:
+            assert s % P.DMA_ROWS == 0
             assert w.lo <= s and s + win_in <= w.hi
             assert 0 <= s and s + win_in <= bp.total_rows
+        # every output tile's rows sit inside its write-back span
+        co, ko = out.cols_per_row, out.row_span
+        for t, s in enumerate(wb):
+            assert s % P.DMA_ROWS == 0 and w.lo <= s
+            a, b = t * tr, min((t + 1) * tr, oh)
+            assert s <= out.row_offset + P._ar_of(a, co, ko)
+            assert out.row_offset + P._ar_top(b - 1, co, ko) < s + slot_rows
+            assert s + slot_rows <= w.hi
         # ... and every valid tap of every output row of tile t is
         # resident in tile t's fetched window
         kh, sh, dh, ph = P._roll_geometry(op)
@@ -156,8 +168,10 @@ def test_window_containment(name):
 
 @pytest.mark.parametrize("name", list(_MODELS))
 def test_staged_slots_match_schedule(name):
-    """Staged ops: the packed scratch slots are disjoint, ordered, and the
-    total the kernel allocates equals the schedule's resident rows."""
+    """Staged ops: the packed scratch slots are disjoint, ordered, each
+    block sits at its arena row modulo DMA_ROWS, and the total the kernel
+    allocates equals the schedule's resident rows; the live rows are the
+    blocks' own rows."""
     _, bp = _bplan(_MODELS[name])
     ws = bp.window_schedule()
     by_name = {op.name: op for op in bp.order}
@@ -169,9 +183,14 @@ def test_staged_slots_match_schedule(name):
             chains.setdefault(cname, []).append(op)
     for w in ws.windows:
         if w.rolling:
-            out = bp.layout_of(by_name[w.op_name].output)
+            op = by_name[w.op_name]
+            out = bp.layout_of(op.output)
+            _, slot_rows = P.tile_writeback(
+                out.row_offset, int(op.output.shape[-3]), out.cols_per_row,
+                out.row_span, sub, bp.total_rows)
             tile_ar = P.tile_arena_rows(out.cols_per_row, out.row_span, sub)
-            assert w.resident_rows == 2 * (w.win_rows - tile_ar) + tile_ar
+            assert slot_rows >= tile_ar
+            assert w.resident_rows == 2 * (w.win_rows - tile_ar) + slot_rows
             continue
         if w.kind == "fused":
             # fused chains stage the ext inputs + terminal output alongside
@@ -181,19 +200,28 @@ def test_staged_slots_match_schedule(name):
             members = chains[w.op_name]
             _, total = P.fused_slots(members, P.chain_rows_of(bp),
                                      round_to=sub, include_io=True)
-            assert total == w.win_rows == w.resident_rows
+            _, live = P.fused_slots(members, P.chain_rows_of(bp),
+                                    round_to=sub, include_io=True,
+                                    dma_io=False)
+            assert total == w.resident_rows and live == w.win_rows <= total
             continue
         op = by_name[w.op_name]
         ins = [t for t in op.inputs if t.storage().kind != "weight"]
-        rows = [bp.layout_of(t).rows for t in ins]
-        out_rows = bp.layout_of(op.output).rows
-        offs, out_slot, total = P.staged_slots(rows, out_rows, sub)
-        assert total == w.win_rows == w.resident_rows
+        blocks = [(bp.layout_of(t).row_offset, bp.layout_of(t).rows)
+                  for t in ins]
+        out_lay = bp.layout_of(op.output)
+        out_block = (out_lay.row_offset, out_lay.rows)
+        offs, out_slot, total = P.staged_slots(blocks, out_block, sub)
+        assert total == w.resident_rows
+        assert w.win_rows == -(-sum(r for _, r in blocks + [out_block])
+                               // sub) * sub <= total
         cur = 0
-        for o, r in zip(offs, rows):
-            assert o == cur
-            cur += r
-        assert out_slot == cur and cur + out_rows <= total
+        for o, (off, r) in zip(list(offs) + [out_slot],
+                               blocks + [out_block]):
+            lo, n = P.dma_span(off, r)
+            assert o == cur + off - lo
+            cur += n
+        assert cur <= total
 
 
 def test_flagship_window_strictly_below_arena():
@@ -279,13 +307,13 @@ def test_streaming_mode_plumbing(monkeypatch):
         PallasExecutor(mode="stream")
     with pytest.raises(ValueError, match="row-blocked"):
         PallasExecutor(mode="streaming", layout="flat")
-    # interpret-ness: pinned beats the env switch, else the switch decides
-    assert PallasExecutor(mode="streaming", interpret=True).interpret
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "0")
-    assert not PallasExecutor(mode="streaming").interpret
+    # interpret-ness: a pin beats the platform, else the platform decides
+    assert PallasExecutor(mode="streaming").interpret          # CPU
     assert not PallasExecutor(mode="streaming", interpret=False).interpret
-    monkeypatch.setenv("REPRO_DMO_INTERPRET", "1")
-    assert PallasExecutor(mode="streaming").interpret
+    with monkeypatch.context() as m:
+        _fake_tpu(m, "TPU v5 lite")
+        assert not PallasExecutor(mode="streaming").interpret
+        assert PallasExecutor(mode="streaming", interpret=True).interpret
 
 
 def test_streaming_refuses_over_budget_window():
@@ -319,13 +347,41 @@ def test_streaming_refuses_over_budget_window():
     X.compare_outputs(ref, out, exact=False, label="budget-admitted stream")
 
 
-def test_budget_env_knob(monkeypatch):
+def _fake_tpu(monkeypatch, kind: str):
+    """Make JAX report one TPU device of ``kind``."""
+    import types
+
+    import jax
+    dev = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+
+
+def test_vmem_budget_from_device_kind_table(monkeypatch):
+    """The VMEM budget is the device kind's row of the one table; the CPU
+    (interpret mode) gates on the chip it stands in for; an explicit
+    budget wins."""
     from repro.core.exec import pallas_backend as PB
+    from repro.kernels import runtime
     be = PB.PallasExecutor(mode="streaming", interpret=True)
-    assert be._resolve_budget() == PB.DEFAULT_VMEM_BUDGET
-    monkeypatch.setenv("REPRO_DMO_VMEM_BUDGET", "4096")
-    assert be._resolve_budget() == 4096
+    v5e = runtime.VMEM_LIMIT_BYTES["TPU v5 lite"]
+    assert be._resolve_budget() == v5e
+    assert runtime.vmem_limit(runtime.INTERPRET_TARGET) == v5e
+    with monkeypatch.context() as m:
+        _fake_tpu(m, "TPU v5 lite")
+        assert be._resolve_budget() == v5e
     assert PB.PallasExecutor(vmem_budget=99)._resolve_budget() == 99
+
+
+def test_unknown_tpu_kind_raises(monkeypatch):
+    """A TPU the VMEM table does not name is an error, not a default."""
+    from repro.core.exec import pallas_backend as PB
+    from repro.kernels import runtime
+    with pytest.raises(ValueError, match="no VMEM limit"):
+        runtime.vmem_limit("TPU v99")
+    _fake_tpu(monkeypatch, "TPU v99")
+    with pytest.raises(ValueError, match="no VMEM limit"):
+        PB.PallasExecutor(mode="compiled")._resolve_budget()
 
 
 def test_verify_pass_covers_streaming_tier():
