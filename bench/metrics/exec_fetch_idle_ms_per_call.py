@@ -1,0 +1,16 @@
+"""Device-idle milliseconds per traced call that the trace files under the
+executor's ``dmo.fetch`` span: the wait for the program, less the device
+time inside it, the arena's copy back to the host and the release of the
+call's device buffers. None where the program writes no such span."""
+
+PHASES = ("dmo.fetch",)
+
+
+def read(run: dict):
+    t = run["trace"]
+    if t is None:
+        return None
+    idle = dict(t.idle_gaps)
+    if not any(p in idle for p in PHASES):
+        return None
+    return 1e3 * sum(idle.get(p, 0.0) for p in PHASES) / t.calls

@@ -291,6 +291,8 @@ def main(argv=None) -> None:
         events = trace_events(cp)
     elif args.route == "serve":
         events = trace_serve_events(cp.original, args.requests)
+    else:
+        events = trace_pallas_events(cp, args.route)
     spans = sum(1 for e in events if e["ph"] == "X")
     with open(args.out, "w") as f:
         json.dump({"traceEvents": events, "displayTimeUnit": "ms",

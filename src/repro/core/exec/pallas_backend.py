@@ -44,11 +44,26 @@ buffer — the planner's peak (padded to whole rows in blocked mode) *is* the
 runtime footprint, overlaps included. Row loops are sequential
 ``fori_loop``s — see the §III.F multi-threading caveat in
 :mod:`repro.kernels.arena_ops`.
+
+:meth:`PallasExecutor.execute` runs in seven phases, each wrapped in a
+``jax.profiler.TraceAnnotation`` that a profiler session records on the
+host plane, on the same clock as the device's ops: ``dmo.resolve``
+(plan, parameters, the weight list), ``dmo.legalise`` (row-blocked
+layouts, lowered specs, the VMEM gate), ``dmo.seed_arena`` (inputs into
+the arena), ``dmo.upload`` (weights and arena to the device),
+``dmo.launch`` (the program lookup and its dispatch), ``dmo.fetch`` (the
+wait for the device, the copy back, the call's device buffers freed) and
+``dmo.gather`` (outputs out of the arena). Each carries the executor's
+call number as ``call``; ``dmo.upload`` and ``dmo.fetch`` carry their
+``bytes``. With no profiler running a span costs about a microsecond.
+:meth:`PallasExecutor.stats` counts the same calls on the host.
 """
 from __future__ import annotations
 
 import collections
+import time
 import warnings
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -194,15 +209,27 @@ class PallasExecutor:
         #: synth_weights/calibrate results per (plan identity, seed) — both
         #: are deterministic, so repeat executions skip calibration too.
         self._autoparams: "collections.OrderedDict" = collections.OrderedDict()
-        self._cache_hits = 0
-        self._cache_misses = 0
+        #: jitted programs this executor has run (a call that runs one not
+        #: in here traces, lowers and compiles it, or loads it from JAX's
+        #: compilation cache)
+        self._programs: "weakref.WeakSet" = weakref.WeakSet()
+        self._stats: Dict[str, float] = dict.fromkeys(
+            ("calls", "images", "h2d_bytes", "d2h_bytes", "uploads",
+             "lowering_hits", "lowering_misses", "programs_built"), 0)
+        self._stats["first_call_s"] = 0.0
         self._check_mode_layout()
 
-    def lowering_cache_info(self) -> Dict[str, int]:
-        """Hit/miss counters of the per-executor lowering cache (tests and
-        the trace exporter read this)."""
-        return {"hits": self._cache_hits, "misses": self._cache_misses,
-                "size": len(self._lowered)}
+    def stats(self) -> Dict[str, float]:
+        """Counters over this executor's :meth:`execute` calls, kept on the
+        host with no device sync: ``calls`` (entered) and ``images``
+        (returned); ``h2d_bytes`` (the distinct weight buffers and the
+        arena, every call) and ``d2h_bytes`` (the arena fetched back);
+        ``uploads`` (device buffers created); ``lowering_hits`` and
+        ``lowering_misses`` of the lowered-spec cache; ``programs_built``,
+        the calls that ran a program this executor had not run before,
+        and ``first_call_s``, their host seconds. Read it before and after
+        a window: a ``programs_built`` that moved names a recompile."""
+        return dict(self._stats)
 
     @property
     def mode(self) -> str:
@@ -588,46 +615,130 @@ class PallasExecutor:
 
     def execute(self, plan_or_compiled, inputs=None, weights=None, *,
                 seed: int = 0, quant=None) -> Dict[str, np.ndarray]:
+        t0 = time.perf_counter()
         import contextlib
 
         import jax
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
+
         from repro.kernels import arena_ops
 
-        plan, graph = unwrap_plan(plan_or_compiled)
-        reason = X.executability(graph)
-        if reason is not None:
-            raise ValueError(
-                f"pallas backend cannot lower {graph.name!r}: {reason}")
-        if weights is None and quant is None:
-            cached = self._autoparams.get((id(plan), seed))
-            if cached is not None and cached[0] is plan:
-                weights, quant = cached[1], cached[2]
-            else:
+        st = self._stats
+        call = st["calls"]
+        st["calls"] += 1
+        with TraceAnnotation("dmo.resolve", call=call):
+            plan, graph = unwrap_plan(plan_or_compiled)
+            reason = X.executability(graph)
+            if reason is not None:
+                raise ValueError(
+                    f"pallas backend cannot lower {graph.name!r}: {reason}")
+            if weights is None and quant is None:
+                cached = self._autoparams.get((id(plan), seed))
+                if cached is not None and cached[0] is plan:
+                    weights, quant = cached[1], cached[2]
+                else:
+                    weights = X.synth_weights(graph, seed)
+                    if X.needs_quant(graph):
+                        quant = X.calibrate(graph, seed, weights)
+                    self._autoparams[(id(plan), seed)] = (plan, weights,
+                                                          quant)
+                    while len(self._autoparams) > 32:
+                        self._autoparams.popitem(last=False)
+            if weights is None:
                 weights = X.synth_weights(graph, seed)
-                if X.needs_quant(graph):
-                    quant = X.calibrate(graph, seed, weights)
-                self._autoparams[(id(plan), seed)] = (plan, weights, quant)
-                while len(self._autoparams) > 32:
-                    self._autoparams.popitem(last=False)
-        if weights is None:
-            weights = X.synth_weights(graph, seed)
-        if quant is None and X.needs_quant(graph):
-            quant = X.calibrate(graph, seed, weights)
-        if inputs is None:
-            inputs = (X.quant_inputs(graph, quant, seed) if quant is not None
-                      else X.random_inputs(graph, seed))
+            if quant is None and X.needs_quant(graph):
+                quant = X.calibrate(graph, seed, weights)
+            if inputs is None:
+                inputs = (X.quant_inputs(graph, quant, seed)
+                          if quant is not None
+                          else X.random_inputs(graph, seed))
+            wflat = self._weight_list(plan, weights, quant)
+
+        with TraceAnnotation("dmo.legalise", call=call):
+            bplan = self._legalised(plan)
+            specs = self._specs(plan, bplan, quant)
+            budget = self._resolve_budget()
+            if bplan is not None:
+                self._check_vmem(bplan, graph, specs, wflat, budget)
+            interpret = self.interpret
+            if not interpret and jax.default_backend() != "tpu":
+                raise RuntimeError(
+                    f"{self.mode} Pallas kernels need a TPU, and JAX runs "
+                    f"on {jax.default_backend()!r}: pass interpret=True (or "
+                    "leave the mode to the platform) to interpret them")
+
+        with TraceAnnotation("dmo.seed_arena", call=call):
+            if bplan is not None:
+                arena = self._seed_block_arena(bplan, graph, inputs)
+            else:
+                arena = np.zeros(plan.peak_bytes, np.uint8)
+                for t in graph.tensors:
+                    if t.kind == "input":
+                        s, off = t.storage(), plan.offsets[t.storage()]
+                        v = np.asarray(inputs[t.name], X.arena_dtype(
+                            s.dtype_bytes)).reshape(-1)
+                        arena[off:off + s.nbytes] = v.view(np.uint8)
+
+        # interpreted kernels run on the host CPU, never on a TPU
+        place = (jax.default_device(runtime.interpret_device()) if interpret
+                 else contextlib.nullcontext())
+        with warnings.catch_warnings(), place:
+            # CPU jit can't honour the donation and warns; the in-kernel
+            # aliasing is what carries the single-buffer semantics there
+            warnings.filterwarnings("ignore", message=".*donated.*")
+            distinct = list({id(w): w for w in wflat}.values())
+            h2d = arena.nbytes + sum(w.nbytes for w in distinct)
+            with TraceAnnotation("dmo.upload", call=call, bytes=h2d):
+                on_device = {id(w): jnp.asarray(w) for w in distinct}
+                arena_in = jnp.asarray(arena)
+            st["h2d_bytes"] += h2d
+            st["uploads"] += len(on_device) + 1
+            with TraceAnnotation("dmo.launch", call=call):
+                fn = arena_ops.lower_program(specs, interpret,
+                                             None if interpret else budget)
+                built = fn not in self._programs
+                out = fn(arena_in, *(on_device[id(w)] for w in wflat))
+            with TraceAnnotation("dmo.fetch", call=call, bytes=out.nbytes):
+                out_arena = np.asarray(out)
+                # free the call's device buffers inside the span, not
+                # unnamed after the last one as the frame unwinds
+                del out, arena_in, on_device
+            st["d2h_bytes"] += out_arena.nbytes
+
+        with TraceAnnotation("dmo.gather", call=call):
+            if bplan is not None:
+                outs = self._gather_block_outputs(bplan, graph, out_arena)
+            else:
+                outs = {}
+                for t in graph.tensors:
+                    if t.kind == "output":
+                        s, off = t.storage(), plan.offsets[t.storage()]
+                        outs[t.name] = out_arena[off:off + s.nbytes].view(
+                            X.arena_dtype(s.dtype_bytes)).reshape(
+                                X.tensor_shape(t))
+        st["images"] += graph.batch
+        if built:
+            self._programs.add(fn)
+            st["programs_built"] += 1
+            st["first_call_s"] += time.perf_counter() - t0
+        return outs
+
+    @staticmethod
+    def _weight_list(plan: Plan, weights, quant) -> List[np.ndarray]:
+        """The program's weight arguments, in order. The order mirrors the
+        per-image spec/stage expansion exactly: a batched op repeats its
+        filter per image (one device buffer, no copies); a batched fused
+        chain's stages run op-major so each weighted member's filter
+        repeats per image consecutively."""
+        from repro.kernels import arena_ops
 
         def w_of(op):
             if quant is not None and id(op) in quant.weights_q:
                 return np.asarray(quant.weights_q[id(op)]["filter"], np.int8)
             return np.asarray(weights[id(op)]["filter"], np.float32)
 
-        # weight order mirrors the per-image spec/stage expansion exactly:
-        # a batched op repeats its filter per image (one device buffer, no
-        # copies); a batched fused chain's stages run op-major so each
-        # weighted member's filter repeats per image consecutively
-        wflat = []
+        wflat: List[np.ndarray] = []
         wchains = _fused_chains(plan.order)
         wemitted: set = set()
         for op in plan.order:
@@ -647,97 +758,63 @@ class PallasExecutor:
             if op.kind in arena_ops.WEIGHTED_KINDS:
                 wflat.extend(w_of(op)
                              for _ in range(op.output.storage().batch))
+        return wflat
 
-        bplan = self._legalised(plan)
+    def _specs(self, plan: Plan, bplan: Optional[BlockPlan], quant) -> Tuple:
+        """The lowered spec sequence of this call's route, from the
+        per-executor cache when the same plan and quantisation ran
+        before."""
         route = (("stream" if self.mode == "streaming" else "blocks")
                  if bplan is not None else "flat")
         key = (id(plan), route, id(quant) if quant is not None else None)
         cached = self._lowered.get(key)
         if cached is not None and cached[0] is plan and cached[1] is quant:
-            specs = cached[2]
-            self._cache_hits += 1
+            self._stats["lowering_hits"] += 1
+            return cached[2]
+        self._stats["lowering_misses"] += 1
+        if route == "stream":
+            specs = self.lower_stream(bplan, quant)
+        elif route == "blocks":
+            specs = self.lower_blocks(bplan, quant)
         else:
-            self._cache_misses += 1
-            if route == "stream":
-                specs = self.lower_stream(bplan, quant)
-            elif route == "blocks":
-                specs = self.lower_blocks(bplan, quant)
-            else:
-                specs = self.lower(plan, quant)
-            self._lowered[key] = (plan, quant, specs)
-            while len(self._lowered) > 32:
-                self._lowered.popitem(last=False)
+            specs = self.lower(plan, quant)
+        self._lowered[key] = (plan, quant, specs)
+        while len(self._lowered) > 32:
+            self._lowered.popitem(last=False)
+        return specs
 
-        budget = self._resolve_budget()
-        if bplan is not None:
-            if self.mode == "streaming":
-                ws = bplan.window_schedule()
-                if ws.max_resident_bytes > budget:
-                    raise ValueError(
-                        f"streaming window of {graph.name!r} does not fit "
-                        f"VMEM: peak resident {ws.max_resident_bytes} bytes "
-                        f"({ws.max_window_rows} live rows) exceeds the "
-                        f"{budget}-byte budget")
-            elif self.mode == "compiled":
-                # each launch holds the whole arena in VMEM, plus a fused
-                # chain's scratch and the weights the call stages there
-                need, rows, wbytes = 0, 0, 0
-                i = 0
-                for s in specs:
-                    nw = arena_ops.spec_weight_count(s)
-                    w = sum(int(x.nbytes) for x in wflat[i:i + nw])
-                    i += nw
-                    r = bplan.total_rows + s.scratch_rows
-                    if r * bplan.row_bytes + w > need:
-                        need, rows, wbytes = r * bplan.row_bytes + w, r, w
-                if need > budget:
-                    raise ValueError(
-                        f"arena of {graph.name!r} does not fit VMEM: a "
-                        f"launch needs {need} bytes ({rows} arena + scratch "
-                        f"rows, {wbytes} weight bytes), over the "
-                        f"{budget}-byte budget — mode='streaming' keeps "
-                        "only the live window resident")
-            arena = self._seed_block_arena(bplan, graph, inputs)
-        else:
-            arena = np.zeros(plan.peak_bytes, np.uint8)
-            for t in graph.tensors:
-                if t.kind == "input":
-                    s, off = t.storage(), plan.offsets[t.storage()]
-                    v = np.asarray(inputs[t.name],
-                                   X.arena_dtype(s.dtype_bytes)).reshape(-1)
-                    arena[off:off + s.nbytes] = v.view(np.uint8)
-
-        interpret = self.interpret
-        if not interpret and jax.default_backend() != "tpu":
-            raise RuntimeError(
-                f"{self.mode} Pallas kernels need a TPU, and JAX runs on "
-                f"{jax.default_backend()!r}: pass interpret=True (or leave "
-                "the mode to the platform) to interpret them")
-        fn = arena_ops.lower_program(specs, interpret,
-                                     None if interpret else budget)
-        # interpreted kernels run on the host CPU, never on a TPU
-        place = (jax.default_device(runtime.interpret_device()) if interpret
-                 else contextlib.nullcontext())
-        with warnings.catch_warnings(), place:
-            # CPU jit can't honour the donation and warns; the in-kernel
-            # aliasing is what carries the single-buffer semantics there
-            warnings.filterwarnings("ignore", message=".*donated.*")
-            on_device: Dict[int, object] = {}
-            for w in wflat:
-                if id(w) not in on_device:
-                    on_device[id(w)] = jnp.asarray(w)
-            out_arena = np.asarray(fn(jnp.asarray(arena),
-                                      *(on_device[id(w)] for w in wflat)))
-
-        if bplan is not None:
-            return self._gather_block_outputs(bplan, graph, out_arena)
-        outs: Dict[str, np.ndarray] = {}
-        for t in graph.tensors:
-            if t.kind == "output":
-                s, off = t.storage(), plan.offsets[t.storage()]
-                outs[t.name] = out_arena[off:off + s.nbytes].view(
-                    X.arena_dtype(s.dtype_bytes)).reshape(X.tensor_shape(t))
-        return outs
+    def _check_vmem(self, bplan: BlockPlan, graph, specs, wflat,
+                    budget: int) -> None:
+        """Refuse a row-blocked program whose VMEM residency exceeds
+        ``budget``: the live window in streaming mode; in compiled mode
+        the whole arena, plus a fused chain's scratch and the weights the
+        launch stages there."""
+        from repro.kernels import arena_ops
+        if self.mode == "streaming":
+            ws = bplan.window_schedule()
+            if ws.max_resident_bytes > budget:
+                raise ValueError(
+                    f"streaming window of {graph.name!r} does not fit "
+                    f"VMEM: peak resident {ws.max_resident_bytes} bytes "
+                    f"({ws.max_window_rows} live rows) exceeds the "
+                    f"{budget}-byte budget")
+        elif self.mode == "compiled":
+            need, rows, wbytes = 0, 0, 0
+            i = 0
+            for s in specs:
+                nw = arena_ops.spec_weight_count(s)
+                w = sum(int(x.nbytes) for x in wflat[i:i + nw])
+                i += nw
+                r = bplan.total_rows + s.scratch_rows
+                if r * bplan.row_bytes + w > need:
+                    need, rows, wbytes = r * bplan.row_bytes + w, r, w
+            if need > budget:
+                raise ValueError(
+                    f"arena of {graph.name!r} does not fit VMEM: a "
+                    f"launch needs {need} bytes ({rows} arena + scratch "
+                    f"rows, {wbytes} weight bytes), over the "
+                    f"{budget}-byte budget — mode='streaming' keeps "
+                    "only the live window resident")
 
     @staticmethod
     def _seed_block_arena(bplan: BlockPlan, graph, inputs) -> np.ndarray:

@@ -314,6 +314,18 @@ def test_export_trace_pallas_routes():
         et.trace_pallas_events(cp_nosplit, "fused")
 
 
+def test_export_trace_cli_runs_a_pallas_route(tmp_path):
+    """The command line reaches the pallas routes, not only numpy/serve."""
+    import json
+    et = _load_script("export_trace")
+    out = tmp_path / "trace.json"
+    et.main(["--route", "blocked", "--out", str(out)])
+    doc = json.loads(out.read_text())
+    assert doc["otherData"]["route"] == "blocked"
+    assert any(e["ph"] == "X" and e["args"]["route"] == "blocked"
+               for e in doc["traceEvents"])
+
+
 def test_lowering_cache_hits_across_executes():
     """Satellite: lowered specs are cached per (plan, route, quant)
     signature — a second execute() of the same plan reuses them."""
@@ -321,11 +333,10 @@ def test_lowering_cache_hits_across_executes():
     cp = pipeline.compile(zoo.mobilenet_v1(0.25, 32, 1), cache=False)
     be = PallasExecutor(layout="blocks", interpret=True)
     a = be.execute(cp)
-    info1 = be.lowering_cache_info()
+    info1 = be.stats()
     b = be.execute(cp)
-    info2 = be.lowering_cache_info()
-    assert info1["misses"] == 1 and info1["hits"] == 0
-    assert info2["misses"] == 1 and info2["hits"] == 1
-    assert info2["size"] >= 1
+    info2 = be.stats()
+    assert info1["lowering_misses"] == 1 and info1["lowering_hits"] == 0
+    assert info2["lowering_misses"] == 1 and info2["lowering_hits"] == 1
     for k in a:
         np.testing.assert_array_equal(a[k], b[k])
