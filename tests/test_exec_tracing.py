@@ -25,15 +25,25 @@ def _filter_bytes(op, dtype_bytes: int) -> int:
     return kh * kw * ic * oc * dtype_bytes
 
 
-def _h2d_per_call(cp) -> int:
-    """The bytes a call uploads, from the plan: each distinct filter once
-    (split bands share their source layer's), plus the typed arena."""
+def _filter_bytes_of(cp) -> int:
+    """The bytes of a plan's filters, from its shapes: each distinct filter
+    once (split bands share their source layer's)."""
     weighted = ("conv2d", "depthwise_conv2d", "fully_connected")
     db = cp.graph.tensors[0].dtype_bytes
     filters = {op.params.get("split_src", op.name): _filter_bytes(op, db)
                for op in cp.plan.order if op.kind in weighted}
+    return sum(filters.values())
+
+
+def _arena_bytes(cp) -> int:
     bp = cp.legalised()
-    return sum(filters.values()) + bp.total_rows * bp.row_bytes
+    return bp.total_rows * bp.row_bytes
+
+
+def _h2d_per_call(cp) -> int:
+    """The bytes a call with parameters new to the executor uploads: each
+    distinct filter once, plus the typed arena."""
+    return _filter_bytes_of(cp) + _arena_bytes(cp)
 
 
 @pytest.fixture(scope="module")
@@ -95,12 +105,14 @@ def test_execute_emits_the_seven_phase_spans_in_order(profiled):
 
 
 def test_upload_and_fetch_spans_carry_their_bytes(profiled):
+    """Call 0 uploads the filters and the arena; call 1, with the same
+    parameters, finds the filters resident and uploads the arena alone."""
     be, cp, events, _ = profiled
-    bp = cp.legalised()
-    for spans in _phases_of(events):
+    uploaded = [_h2d_per_call(cp), _arena_bytes(cp)]
+    for n, spans in enumerate(_phases_of(events)):
         by = {e[0]: e[3] for e in spans}
-        assert by["dmo.upload"]["bytes"] == _h2d_per_call(cp)
-        assert by["dmo.fetch"]["bytes"] == bp.total_rows * bp.row_bytes
+        assert by["dmo.upload"]["bytes"] == uploaded[n]
+        assert by["dmo.fetch"]["bytes"] == _arena_bytes(cp)
 
 
 def test_stats_count_calls_bytes_and_programs(profiled):
@@ -112,9 +124,10 @@ def test_stats_count_calls_bytes_and_programs(profiled):
                      if op.kind in ("conv2d", "depthwise_conv2d",
                                     "fully_connected")})
     assert st["calls"] == 2 and st["images"] == 2
-    assert st["h2d_bytes"] == 2 * _h2d_per_call(cp)
+    assert st["h2d_bytes"] == _filter_bytes_of(cp) + 2 * _arena_bytes(cp)
     assert st["d2h_bytes"] == 2 * bp.total_rows * bp.row_bytes
-    assert st["uploads"] == 2 * (n_filters + 1)
+    assert st["uploads"] == n_filters + 2
+    assert (st["weight_misses"], st["weight_hits"]) == (1, 1)
     assert (st["lowering_misses"], st["lowering_hits"]) == (1, 1)
     assert st["programs_built"] == 1 and st["first_call_s"] > 0
     for k in results[0]:
@@ -139,6 +152,7 @@ def test_stats_count_images_and_shared_filters_of_a_batch():
 def test_stats_start_at_zero():
     st = PallasExecutor(interpret=True).stats()
     assert set(st) == {"calls", "images", "h2d_bytes", "d2h_bytes",
-                       "uploads", "lowering_hits", "lowering_misses",
+                       "uploads", "weight_hits", "weight_misses",
+                       "lowering_hits", "lowering_misses",
                        "programs_built", "first_call_s"}
     assert not any(st.values())
