@@ -253,9 +253,11 @@ class PallasExecutor:
         distinct filter on a miss); ``lowering_hits`` and
         ``lowering_misses`` of the lowered-spec cache; ``programs_built``,
         the calls that ran a program this executor had not run before,
-        and ``first_call_s``, their host seconds. Read it before and after
-        a window: a ``programs_built`` or ``weight_misses`` that moved
-        names a recompile or a re-upload."""
+        and ``first_call_s``, their host seconds; ``launches.<kind>``, the
+        kernels launched by op kind (``launches.conv2d``,
+        ``launches.concat``, ``launches.fused`` for a band chain), over
+        all calls. Read it before and after a window: a ``programs_built``
+        or ``weight_misses`` that moved names a recompile or a re-upload."""
         return dict(self._stats)
 
     @property
@@ -333,7 +335,8 @@ class PallasExecutor:
                     out_shape=out.shape,
                     dtype="i8" if out.dtype_bytes == 1 else "f32",
                     meta=_canon_meta(op),
-                    qmeta=_canon_qmeta(op, q)))
+                    qmeta=_canon_qmeta(op, q),
+                    name=op.name))
         return tuple(specs)
 
     def _fused_flat_spec(self, plan: Plan, members: List[Op],
@@ -396,7 +399,8 @@ class PallasExecutor:
             dtype="i8" if out_lay.dtype_bytes == 1 else "f32",
             meta=(cat.params["fuse_chain"],),
             stages=tuple(stages),
-            scratch_rows=total)          # bytes in the flat program
+            scratch_rows=total,          # bytes in the flat program
+            name=cat.params["fuse_chain"])
 
     @staticmethod
     def _chain_ext_inputs(members: List[Op], internal) -> List:
@@ -468,6 +472,7 @@ class PallasExecutor:
                     rowlen=bplan.arena_rowlen,
                     in_rows=tuple((l.image_rows, l.rowlen) for l in lays),
                     out_rows=(out.image_rows, out.rowlen),
+                    name=op.name,
                     **extra))
         return tuple(specs)
 
@@ -579,7 +584,8 @@ class PallasExecutor:
                            bplan.layout_of(t).rowlen) for t in ext),
             out_rows=(out_lay.rows, out_lay.rowlen),
             stages=tuple(stages),
-            scratch_rows=total)
+            scratch_rows=total,
+            name=cat.params["fuse_chain"])
         if streaming:
             import dataclasses
             assert window.resident_rows == total, \
@@ -700,7 +706,7 @@ class PallasExecutor:
 
         with TraceAnnotation("dmo.legalise", call=call):
             bplan = self._legalised(plan)
-            specs = self._specs(plan, bplan, quant)
+            specs, launches = self._specs(plan, bplan, quant)
             budget = self._resolve_budget()
             if bplan is not None:
                 self._check_vmem(bplan, graph, specs, wflat, budget)
@@ -747,6 +753,8 @@ class PallasExecutor:
                                              None if interpret else budget)
                 built = fn not in self._programs
                 out = fn(arena_in, *wflat)
+                for k, n in launches:
+                    st[k] = st.get(k, 0) + n
             with TraceAnnotation("dmo.fetch", call=call, bytes=out.nbytes):
                 out_arena = np.asarray(out)
                 # free the call's device buffers inside the span, not
@@ -809,16 +817,16 @@ class PallasExecutor:
         return wflat
 
     def _specs(self, plan: Plan, bplan: Optional[BlockPlan], quant) -> Tuple:
-        """The lowered spec sequence of this call's route, from the
-        per-executor cache when the same plan and quantisation ran
-        before."""
+        """The lowered spec sequence of this call's route and its launches
+        by kind (``(("launches.<kind>", n), ...)``), from the per-executor
+        cache when the same plan and quantisation ran before."""
         route = (("stream" if self.mode == "streaming" else "blocks")
                  if bplan is not None else "flat")
         key = (id(plan), route, id(quant) if quant is not None else None)
         cached = self._lowered.get(key)
         if cached is not None and cached[0] is plan and cached[1] is quant:
             self._stats["lowering_hits"] += 1
-            return cached[2]
+            return cached[2], cached[3]
         self._stats["lowering_misses"] += 1
         if route == "stream":
             specs = self.lower_stream(bplan, quant)
@@ -826,10 +834,12 @@ class PallasExecutor:
             specs = self.lower_blocks(bplan, quant)
         else:
             specs = self.lower(plan, quant)
-        self._lowered[key] = (plan, quant, specs)
+        launches = tuple(collections.Counter(
+            "launches." + s.kind for s in specs).items())
+        self._lowered[key] = (plan, quant, specs, launches)
         while len(self._lowered) > 32:
             self._lowered.popitem(last=False)
-        return specs
+        return specs, launches
 
     def _check_vmem(self, bplan: BlockPlan, graph, specs, wflat,
                     budget: int) -> None:

@@ -289,9 +289,14 @@ def inception_v4(res: int = 299, dtype_bytes: int = 4) -> Graph:
     return b.head(x)
 
 
-def inception_resnet_v2(res: int = 299, dtype_bytes: int = 4) -> Graph:
-    # Keras Applications variant: *sequential* stem (conv/conv/conv/pool/
-    # conv/conv/pool), which is where the paper's 34.4 % saving lives.
+def inception_resnet_v2(res: int = 299, dtype_bytes: int = 4,
+                        repeats: Tuple[int, int, int] = (10, 20, 10)) -> Graph:
+    """Keras Applications variant: *sequential* stem (conv/conv/conv/pool/
+    conv/conv/pool), which is where the paper's 34.4 % saving lives.
+    ``repeats``: how many Inception-ResNet-A, -B and -C blocks (the
+    published 10, 20, 10; fewer build a small copy with every block kind,
+    at the published widths)."""
+    n_a, n_b, n_c = repeats
     b = _B("inception_resnet_v2", dtype_bytes)
     x = b.input(res, res, 3)
     x = b.conv(x, 32, 3, 2, "valid", name="stem_c1")          # 149
@@ -320,13 +325,13 @@ def inception_resnet_v2(res: int = 299, dtype_bytes: int = 4) -> Graph:
                     1, 1, name=f"m35_{i}_up")
         return b.add(x, up, name=f"m35_{i}_add")
 
-    for i in range(10):
+    for i in range(n_a):
         x = block35(x, i)
     r1 = b.conv(x, 384, 3, 2, "valid", name="ra_1")
     r2 = b.conv(b.conv(b.conv(x, 256, 1, 1, name="ra_2a"), 256, 3, 1,
                        name="ra_2b"), 384, 3, 2, "valid", name="ra_2c")
     r3 = b.pool(x, 3, 2, "valid", "max", name="ra_p")
-    x = b.concat([r1, r2, r3], name="ra_cat")                  # 17x1152
+    x = b.concat([r1, r2, r3], name="ra_cat")                  # 17x1088
 
     def block17(x, i):
         b1 = b.conv(x, 192, 1, 1, name=f"m17_{i}_b1")
@@ -337,14 +342,14 @@ def inception_resnet_v2(res: int = 299, dtype_bytes: int = 4) -> Graph:
                     name=f"m17_{i}_up")
         return b.add(x, up, name=f"m17_{i}_add")
 
-    for i in range(20):
+    for i in range(n_b):
         x = block17(x, i)
     r1 = b.conv(b.conv(x, 256, 1, 1, name="rb_1a"), 384, 3, 2, "valid", name="rb_1b")
     r2 = b.conv(b.conv(x, 256, 1, 1, name="rb_2a"), 288, 3, 2, "valid", name="rb_2b")
     r3 = b.conv(b.conv(b.conv(x, 256, 1, 1, name="rb_3a"), 288, 3, 1,
                        name="rb_3b"), 320, 3, 2, "valid", name="rb_3c")
     r4 = b.pool(x, 3, 2, "valid", "max", name="rb_p")
-    x = b.concat([r1, r2, r3, r4], name="rb_cat")              # 8x2144
+    x = b.concat([r1, r2, r3, r4], name="rb_cat")              # 8x2080
 
     def block8(x, i):
         b1 = b.conv(x, 192, 1, 1, name=f"m8_{i}_b1")
@@ -355,7 +360,7 @@ def inception_resnet_v2(res: int = 299, dtype_bytes: int = 4) -> Graph:
                     name=f"m8_{i}_up")
         return b.add(x, up, name=f"m8_{i}_add")
 
-    for i in range(10):
+    for i in range(n_c):
         x = block8(x, i)
     x = b.conv(x, 1536, 1, 1, name="conv_final")
     return b.head(x)

@@ -60,7 +60,9 @@ output ref, and conv/pool walk output rows in ascending index order inside a
 sequential ``fori_loop``. Reads for output row ``i`` therefore happen after
 the row ``i-1`` store — exactly the element order the safe overlap ``O_s``
 was derived against, which is why a planner-approved layout cannot clobber a
-live value. In the blocked program a row store clobbers the *whole* arena
+live value. Residual adds and channel concats of image tensors walk rows the
+same way where their placement is safe in that order (:func:`_row_streamable`
+checks it from the spec), and otherwise read every input before writing. In the blocked program a row store clobbers the *whole* arena
 row (tiling padding included), which is why the legaliser re-derives each
 diagonal distance at row granularity. A parallel grid over rows would break
 the guarantee, precisely the paper's multi-threading caveat (§III.F) — keep
@@ -153,6 +155,8 @@ class OpSpec:
     out_scratch: int = 0               # stage flag: output writes the scratch ref
     in_slots: Tuple[int, ...] = ()     # fused streaming: ext-input scratch slots
     out_slot: int = 0                  # fused streaming: terminal-output slot
+    #: The graph op (or fused chain) the spec lowers: names its kernel.
+    name: str = ""
 
 
 def _elems(shape: Tuple[int, ...]) -> int:
@@ -182,6 +186,14 @@ def _addr_in(spec: OpSpec, i: int) -> Tuple[int, int, int]:
 
 def _addr_out(spec: OpSpec) -> Tuple[int, int, int]:
     return spec.out_addr if spec.out_addr else (1, 1, 0)
+
+
+def kernel_name(spec: OpSpec) -> str:
+    """The ``pallas_call`` name of a spec's kernel: ``dmo_<kind>_<op>``
+    (``dmo_concat_m35_3_cat``), so that a device trace tells the kernels
+    of joins, pools and convs apart."""
+    op = "".join(ch if ch.isalnum() else "_" for ch in spec.name)
+    return f"dmo_{spec.kind}_{op}" if op else f"dmo_{spec.kind}"
 
 
 def _tile_geom(spec: OpSpec) -> Tuple[int, int]:
@@ -448,6 +460,14 @@ class _FlatMem:
     def out_is_image(self) -> bool:
         return len(self.spec.out_shape) >= 3
 
+    def row_span(self, i: Optional[int], y: int) -> Tuple[int, int]:
+        """The bytes ``[lo, hi)`` image row ``y`` of input ``i`` (the
+        output for ``None``) occupies."""
+        shape = self.spec.out_shape if i is None else self.spec.in_shape[i]
+        off = self.spec.out_off if i is None else self.spec.in_off[i]
+        row = _elems(shape[-2:]) * self.isz
+        return off + y * row, off + (y + 1) * row
+
     def read_t(self, i: int):
         """Input ``i`` as its (N, C) matrix."""
         shape = self.spec.in_shape[i]
@@ -510,6 +530,17 @@ class _BlockMem:
         rows, used = self.spec.out_rows
         return _is_image(self.spec.out_shape, rows, used,
                          _addr_out(self.spec))
+
+    def row_span(self, i: Optional[int], y: int) -> Tuple[int, int]:
+        """The arena rows ``[lo, hi)`` image row ``y`` of input ``i`` (the
+        output for ``None``) touches: a packed row's whole arena row, a
+        spanning row's ``row_span`` rows."""
+        off = self._out_off() if i is None else self._in_off(i)
+        c, k, _ = _addr_out(self.spec) if i is None else _addr_in(
+            self.spec, i)
+        if c > 1:
+            return off + y // c, off + y // c + 1
+        return off + y * k, off + (y + 1) * k
 
     def read_t(self, i: int):
         rows, used = self.spec.in_rows[i]
@@ -769,19 +800,55 @@ def _pool_kernel(mem, *, spec: OpSpec):
     mem.fori_rows(oh, body)
 
 
+def _row_streamable(mem, spec: OpSpec) -> bool:
+    """Can a join run one output row at a time: reading row ``y`` of every
+    input, then writing output row ``y``, for ``y`` ascending? Every
+    operand must keep image rows, and in place writing row ``y`` must
+    leave every input row after ``y`` intact: the span it clobbers lies
+    below the next input row or above the input's last. Where it does not,
+    the whole-tensor body (every read before any write) runs instead."""
+    n_in = len(spec.in_shape)
+    if not (len(spec.out_shape) >= 3 and mem.out_is_image()
+            and all(mem.is_image(i) for i in range(n_in))):
+        return False
+    oh = spec.out_shape[-3]
+    for i in range(n_in):
+        end = mem.row_span(i, spec.in_shape[i][-3] - 1)[1]
+        for y in range(oh - 1):
+            lo, hi = mem.row_span(None, y)
+            if hi > mem.row_span(i, y + 1)[0] and lo < end:
+                return False
+    return True
+
+
 def _elementwise_kernel(mem, *, spec: OpSpec):
     fn = _ELEMENTWISE[spec.meta[0]]
-    xs = [mem.read_t(i) for i in range(len(spec.in_shape))]
-    if spec.dtype == "i8":
-        in_q, (ys, yzp) = spec.qmeta
-        xs = [_dequant(x, s, zp) for x, (s, zp) in zip(xs, in_q)]
+    n_in = len(spec.in_shape)
+
+    def compute(xs):
+        if spec.dtype == "i8":
+            in_q, (ys, yzp) = spec.qmeta
+            xs = [_dequant(x, s, zp) for x, (s, zp) in zip(xs, in_q)]
+        v = fn(*xs).astype(jnp.float32)
+        return _quant(v, ys, yzp) if spec.dtype == "i8" else v
+
+    if all(s == spec.out_shape for s in spec.in_shape) and _row_streamable(
+            mem, spec):
+        # same-shape image operands (a residual add): one row per step
+        def body(oy, _):
+            mem.write_row(oy, compute([mem.read_row(i, oy)
+                                       for i in range(n_in)]))
+            return 0
+
+        mem.fori_rows(spec.out_shape[-3], body)
+        return
+    xs = [mem.read_t(i) for i in range(n_in)]
     if len(xs) == 2 and xs[1].shape != xs[0].shape:
         # trailing-axis broadcast: the smaller operand's rows repeat
         reps = xs[0].shape[0] // xs[1].shape[0]
         xs[1] = (jnp.broadcast_to(xs[1], xs[0].shape) if xs[1].shape[0] == 1
                  else jnp.tile(xs[1], (reps, 1)))
-    v = fn(*xs).astype(jnp.float32)
-    mem.write(_quant(v, ys, yzp) if spec.dtype == "i8" else v)
+    mem.write(compute(xs))
 
 
 def _softmax_kernel(mem, *, spec: OpSpec):
@@ -841,6 +908,21 @@ def _concat_kernel(mem, *, spec: OpSpec):
             for y in range(spec.in_shape[i][-3]):
                 mem.write_row(y0 + y, fix[i](mem.read_row(i, y)))
             y0 += spec.in_shape[i][-3]
+        return
+    if rank >= 3 and axis == rank - 1 and _row_streamable(mem, spec):
+        # channel concat of image tensors: each output row interleaves the
+        # inputs' pixels, one row per step
+        w = spec.out_shape[-2]
+        chans = [s[-1] for s in spec.in_shape]
+
+        def body(oy, _):
+            rows = [fix[i](mem.read_row(i, oy)) for i in range(n_in)]
+            mem.write_row(oy, _cat([r[:, x * c:(x + 1) * c]
+                                    for x in range(w)
+                                    for r, c in zip(rows, chans)], 1))
+            return 0
+
+        mem.fori_rows(spec.out_shape[-3], body)
         return
     xs = [fix[i](mem.read_t(i)) for i in range(n_in)]
     if axis in (0, rank - 1):
@@ -1149,6 +1231,7 @@ def _apply_stream(arena: jax.Array, spec: OpSpec,
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
+        name=kernel_name(spec),
         **params,
     )
     sems = [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(())]
@@ -1212,6 +1295,7 @@ def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
             input_output_aliases={0: 0},        # the arena is donated through
             scratch_shapes=scratch,
             interpret=interpret,
+            name=kernel_name(spec),
             **params,
         )
         return fn(arena, *weights)
@@ -1222,6 +1306,7 @@ def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
         scratch_shapes=([pltpu.VMEM((spec.scratch_rows,), jnp.uint8)]
                         if fused else []),
         interpret=interpret,
+        name=kernel_name(spec),
     )
     return fn(arena, *weights)
 

@@ -134,6 +134,42 @@ def test_stats_count_calls_bytes_and_programs(profiled):
         np.testing.assert_array_equal(results[0][k], results[1][k])
 
 
+def test_stats_count_launches_by_kind(profiled):
+    """``launches.<kind>`` counts each call's kernels by op kind, over all
+    calls: 30 launches a call at 32 px (14 conv2d, 13 depthwise, mean,
+    fully connected and softmax), twice."""
+    be, _, _, _ = profiled
+    launches = {k: v for k, v in be.stats().items()
+                if k.startswith("launches.")}
+    assert launches == {"launches.conv2d": 28,
+                        "launches.depthwise_conv2d": 26,
+                        "launches.mean": 2,
+                        "launches.fully_connected": 2,
+                        "launches.softmax": 2}
+
+
+def test_every_kernel_is_named_by_its_kind_and_op():
+    """Each ``pallas_call`` carries ``dmo_<kind>_<op>`` as its name, so a
+    device trace read by hand tells the kernels apart."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import arena_ops
+    cp = pipeline.compile(zoo.mobilenet_v1(0.25, 32, 4), cache=False)
+    bp = cp.legalised()
+    specs = PallasExecutor(interpret=True).lower_blocks(bp)
+    names = [arena_ops.kernel_name(s) for s in specs]
+    assert names[:3] == ["dmo_conv2d_conv1", "dmo_depthwise_conv2d_dw1",
+                         "dmo_conv2d_pw1"]
+    assert names[-1] == "dmo_softmax_prob"
+    assert all(n.startswith(f"dmo_{s.kind}_") for n, s in zip(names, specs))
+    arena = jnp.zeros((bp.total_rows, bp.arena_rowlen), jnp.float32)
+    w = jnp.zeros((3, 3, 3, 8), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda a: arena_ops.apply_op(
+        a, specs[0], (w,), interpret=True))(arena)
+    assert "name=dmo_conv2d_conv1" in str(jaxpr)
+
+
 def test_stats_count_images_and_shared_filters_of_a_batch():
     """At batch 2 every filter is passed once per image but uploaded once,
     and a call completes two images. (Float, where the split bands of one

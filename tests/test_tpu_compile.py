@@ -55,9 +55,10 @@ def _weight_shapes(spec):
     return []
 
 
-def _lowered(name: str, route: str):
-    """(specs, arena shape, arena dtype) of a zoo model's arena program."""
-    cp = compile_graph(zoo.TABLE3_MODELS[name][0](), verify="off")
+def _lowered(name: str, route: str, graph=None):
+    """(specs, arena shape, arena dtype) of a zoo model's arena program
+    (``graph``, where given, in place of the zoo's own)."""
+    cp = compile_graph(graph or zoo.TABLE3_MODELS[name][0](), verify="off")
     bp = cp.legalised()
     quant = None
     if X.needs_quant(cp.graph):
@@ -100,6 +101,57 @@ def test_full_width_f32_kernel_compiles_for_v5e(one_chip, kind):
                key=lambda s: arena_ops._elems(s.out_shape))
     fn = jax.jit(lambda arena, *w: arena_ops.apply_op(
         arena, spec, w, interpret=False,
+        vmem_limit=runtime.vmem_limit(_V5E)))
+    compiled = _compile(fn, shape, dt, [spec], one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_small_inception_resnet_v2_program_compiles_for_v5e(one_chip):
+    """Every launch of Inception-ResNet-v2 at 75 px with one block of each
+    kind, at the published widths (f32: convs of 1x1 to 5x5 and 1x7 / 7x1,
+    max and average pools, residual adds, channel concats), compiles
+    through Mosaic: one TPU kernel per launch."""
+    specs, shape, dt = _lowered(
+        "inception_resnet_v2", "blocks",
+        zoo.inception_resnet_v2(75, 4, (1, 1, 1)))
+    assert len(specs) == 58
+    fn = arena_ops.lower_program(specs, interpret=False,
+                                 vmem_limit=runtime.vmem_limit(_V5E))
+    compiled = _compile(fn, shape, dt, specs, one_chip)
+    assert compiled.as_text().count("tpu_custom_call") >= len(specs)
+
+
+@pytest.fixture(scope="module")
+def irv2_published():
+    """(specs, arena shape, arena dtype, block plan) of the published
+    Inception-ResNet-v2 program at 299 px, planned once for the module."""
+    cp = compile_graph(zoo.inception_resnet_v2(299, 4), verify="off")
+    bp = cp.legalised()
+    specs = PallasExecutor(mode="compiled").lower_blocks(bp)
+    return specs, (bp.total_rows, bp.arena_rowlen), jnp.float32, bp
+
+
+def test_published_inception_resnet_v2_joins_stream_rows(irv2_published):
+    """The published plan passes the row-granular no-clobber check, and
+    every residual add and channel concat of it runs one row at a time."""
+    specs, _, _, bp = irv2_published
+    bp.validate()
+    joins = [s for s in specs if s.kind in ("elementwise", "concat")]
+    assert len(joins) == 83
+    assert all(arena_ops._row_streamable(arena_ops._BlockMem(None, s), s)
+               for s in joins)
+
+
+@pytest.mark.parametrize("name", ["m35_0_add", "m5b_cat"])
+def test_published_join_kernel_compiles_for_v5e(one_chip, irv2_published,
+                                                name):
+    """The published 35x35x320 residual add and the 4-way concat to
+    35x35x320 compile on their own."""
+    specs, shape, dt, _ = irv2_published
+    spec = next(s for s in specs if s.name == name)
+    assert spec.out_shape == (35, 35, 320)
+    fn = jax.jit(lambda arena: arena_ops.apply_op(
+        arena, spec, (), interpret=False,
         vmem_limit=runtime.vmem_limit(_V5E)))
     compiled = _compile(fn, shape, dt, [spec], one_chip)
     assert "tpu_custom_call" in compiled.as_text()

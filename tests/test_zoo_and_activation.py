@@ -1,4 +1,6 @@
 """Zoo graphs build + arch activation-arena DMO plans."""
+import collections
+
 import pytest
 
 from repro.configs import registry
@@ -21,6 +23,42 @@ def test_mobilenet_originals_match_paper():
                  "mobilenet_v1_0.25_128_8bit"):
         build, orig_kb, _ = zoo.TABLE3_MODELS[name]
         assert plan_original(build()).peak_bytes == orig_kb * 1024, name
+
+
+def _params_and_macs(g):
+    params = macs = 0
+    for op in g.ops:
+        if op.kind == "conv2d":
+            kh, kw = op.params["kernel"]
+            w = kh * kw * op.inputs[0].shape[-1] * op.output.shape[-1]
+            params += w
+            macs += w * op.output.shape[-3] * op.output.shape[-2]
+        elif op.kind == "fully_connected":
+            w = op.inputs[0].shape[-1] * op.output.shape[-1]
+            params += w
+            macs += w
+    return params, macs
+
+
+@pytest.mark.parametrize("repeats,ops,params,macs", [
+    ((10, 20, 10), 335, 55_736_160, 13_155_794_016),
+    ((1, 1, 1), 58, None, None),
+])
+def test_inception_resnet_v2_published_graph(repeats, ops, params, macs):
+    """The published graph (Keras InceptionResNetV2, 299 px): 335 ops, of
+    them 244 convs, 43 concats, 40 adds and 5 pools; 55.7 M weights and
+    13.16 G multiply-adds. ``repeats`` cuts only the block counts."""
+    g = zoo.inception_resnet_v2(299, 4, repeats)
+    kinds = collections.Counter(op.kind for op in g.ops)
+    assert len(g.ops) == ops
+    n_blocks = sum(repeats)
+    assert kinds["elementwise"] == n_blocks
+    assert kinds["concat"] == n_blocks + 3
+    assert kinds["pool"] == 5
+    assert [t.shape for t in g.tensors if t.kind == "output"] == [(1000,)]
+    if params is not None:
+        assert _params_and_macs(g) == (params, macs)
+        assert kinds["conv2d"] == 244
 
 
 @pytest.mark.parametrize("arch", list(registry()))
