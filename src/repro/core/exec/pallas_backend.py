@@ -9,7 +9,8 @@ Three arena programs share the backend (see :mod:`repro.kernels.arena_ops`):
   Kernels address whole arena rows via ``pl.dslice`` — no byte bitcasts —
   so the same program lowers under ``interpret=False``: this is the
   compiled-mode path, the TPU-VMEM realisation of the paper's SRAM arena.
-  The whole arena is VMEM-resident, so VMEM caps ``total_rows``.
+  Each launch holds an arena-sized VMEM image, so VMEM caps
+  ``total_rows``, but copies only its operands' rows between it and HBM.
 - **streaming** (``mode="streaming"``): the same row-blocked layouts, but
   the arena lives in ``pl.ANY`` (HBM) and each op DMAs only its *live
   window* (:meth:`repro.core.planner.BlockPlan.window_schedule`) into VMEM
@@ -236,7 +237,7 @@ class PallasExecutor:
         self._stats: Dict[str, float] = dict.fromkeys(
             ("calls", "images", "h2d_bytes", "d2h_bytes", "uploads",
              "weight_hits", "weight_misses", "lowering_hits",
-             "lowering_misses", "programs_built"), 0)
+             "lowering_misses", "programs_built", "stage_bytes"), 0)
         self._stats["first_call_s"] = 0.0
         self._check_mode_layout()
 
@@ -256,7 +257,10 @@ class PallasExecutor:
         and ``first_call_s``, their host seconds; ``launches.<kind>``, the
         kernels launched by op kind (``launches.conv2d``,
         ``launches.concat``, ``launches.fused`` for a band chain), over
-        all calls. Read it before and after a window: a ``programs_built``
+        all calls; ``stage_bytes``, the arena bytes the row-blocked
+        launches copied between HBM and VMEM, both directions (the
+        streaming route's window copies and the flat program add
+        nothing). Read it before and after a window: a ``programs_built``
         or ``weight_misses`` that moved names a recompile or a re-upload."""
         return dict(self._stats)
 
@@ -706,7 +710,7 @@ class PallasExecutor:
 
         with TraceAnnotation("dmo.legalise", call=call):
             bplan = self._legalised(plan)
-            specs, launches = self._specs(plan, bplan, quant)
+            specs, launches, stage_bytes = self._specs(plan, bplan, quant)
             budget = self._resolve_budget()
             if bplan is not None:
                 self._check_vmem(bplan, graph, specs, wflat, budget)
@@ -755,6 +759,7 @@ class PallasExecutor:
                 out = fn(arena_in, *wflat)
                 for k, n in launches:
                     st[k] = st.get(k, 0) + n
+                st["stage_bytes"] += stage_bytes
             with TraceAnnotation("dmo.fetch", call=call, bytes=out.nbytes):
                 out_arena = np.asarray(out)
                 # free the call's device buffers inside the span, not
@@ -817,16 +822,19 @@ class PallasExecutor:
         return wflat
 
     def _specs(self, plan: Plan, bplan: Optional[BlockPlan], quant) -> Tuple:
-        """The lowered spec sequence of this call's route and its launches
-        by kind (``(("launches.<kind>", n), ...)``), from the per-executor
-        cache when the same plan and quantisation ran before."""
+        """The lowered spec sequence of this call's route, its launches by
+        kind (``(("launches.<kind>", n), ...)``) and the arena bytes its
+        row-blocked launches stage between HBM and VMEM, from the
+        per-executor cache when the same plan and quantisation ran
+        before."""
+        from repro.kernels import arena_ops
         route = (("stream" if self.mode == "streaming" else "blocks")
                  if bplan is not None else "flat")
         key = (id(plan), route, id(quant) if quant is not None else None)
         cached = self._lowered.get(key)
         if cached is not None and cached[0] is plan and cached[1] is quant:
             self._stats["lowering_hits"] += 1
-            return cached[2], cached[3]
+            return cached[2:]
         self._stats["lowering_misses"] += 1
         if route == "stream":
             specs = self.lower_stream(bplan, quant)
@@ -836,10 +844,12 @@ class PallasExecutor:
             specs = self.lower(plan, quant)
         launches = tuple(collections.Counter(
             "launches." + s.kind for s in specs).items())
-        self._lowered[key] = (plan, quant, specs, launches)
+        stage_bytes = (sum(arena_ops.block_stage_bytes(s, bplan.total_rows)
+                           for s in specs) if route == "blocks" else 0)
+        self._lowered[key] = (plan, quant, specs, launches, stage_bytes)
         while len(self._lowered) > 32:
             self._lowered.popitem(last=False)
-        return specs, launches
+        return specs, launches, stage_bytes
 
     def _check_vmem(self, bplan: BlockPlan, graph, specs, wflat,
                     budget: int) -> None:

@@ -31,8 +31,11 @@ that the streaming one):
   triples) put ``cols_per_row`` narrow image rows in each arena row —
   reads pick the lane phase from static slices, writes merge it into the
   loaded row — or span one wide image row over ``row_span`` consecutive
-  arena rows. Each launch copies the arena from HBM into its VMEM block,
-  so the VMEM capacity caps ``total_rows``.
+  arena rows. The arena stays in HBM; each launch holds a VMEM image of
+  it at the same row indices, copies in only the DMA-aligned rows of its
+  operand blocks (:func:`block_stage_spans`) and copies back only its
+  output block's. The image is arena-sized, so the VMEM capacity caps
+  ``total_rows``.
 - **streaming** (:class:`_StreamRollMem` / :class:`_StreamStageMem`) — the
   arena stays in ``pl.ANY`` (HBM) and each op DMAs only its *live
   window* (:class:`repro.core.planner.WindowSchedule`) into VMEM scratch
@@ -1020,24 +1023,73 @@ def _flat_kernel(*refs, spec: OpSpec):
         _BODIES[spec.kind](_FlatMem(o_ref, spec), *w_refs, spec=spec)
 
 
+def block_stage_spans(spec: OpSpec, total_rows: int
+                      ) -> Tuple[Tuple[Tuple[int, int], ...], Tuple[int, int]]:
+    """The HBM <-> VMEM copies of one row-blocked launch over an arena of
+    ``total_rows`` rows, as ``(start row, rows)`` spans: ``(spans in, span
+    out)``. In: the DMA-aligned span (:func:`repro.core.planner.dma_span`,
+    cut at the arena's end) of every input block and of the output block,
+    sorted, with overlapping or touching spans merged, so a diagonal
+    in/out overlap is copied once. Out: the output block's aligned span.
+    Whole 8-row groups travel both ways, so the rows of a group outside
+    the block (which an int8 row store rewrites unchanged) go back with
+    the values they came in with. A fused chain's spec names its external
+    inputs and its terminal output, the only arena rows its stages
+    touch."""
+    def span(off: int, rows: int) -> Tuple[int, int]:
+        lo, n = dma_span(off, rows)
+        return lo, min(n, total_rows - lo)
+
+    blocks = [span(off, rows) for off, (rows, _) in zip(spec.in_off,
+                                                         spec.in_rows)]
+    out = span(spec.out_off, spec.out_rows[0])
+    merged = []
+    for lo, n in sorted(blocks + [out]):
+        if merged and lo <= merged[-1][0] + merged[-1][1]:
+            plo, pn = merged[-1]
+            merged[-1] = (plo, max(pn, lo + n - plo))
+        else:
+            merged.append((lo, n))
+    return tuple(merged), out
+
+
+def block_stage_bytes(spec: OpSpec, total_rows: int) -> int:
+    """Arena bytes one row-blocked launch copies between HBM and VMEM, both
+    directions (:func:`block_stage_spans`)."""
+    spans, (_, out_rows) = block_stage_spans(spec, total_rows)
+    rows = sum(n for _, n in spans) + out_rows
+    return rows * spec.rowlen * _isz(spec.dtype)
+
+
 def _block_kernel(a_ref, *rest, spec: OpSpec):
-    """Row-blocked VMEM-resident program: refs are (arena in HBM,
-    *weights, arena block in VMEM[, chain scratch], DMA semaphore). A
-    kernel's VMEM output block starts undefined, so the arena is first
-    copied into it; the body then reads and writes it in place, and the
-    whole block goes back to the aliased HBM arena."""
+    """Row-blocked VMEM-resident program: refs are (arena in HBM, *weights,
+    the aliased arena in HBM, the arena's VMEM image[, chain scratch], DMA
+    semaphores). The VMEM image holds every arena row at its own index, so
+    the bodies keep their absolute row addressing, but only the rows the
+    op touches are copied in (:func:`block_stage_spans`; every copy starts
+    before the first wait) and only the output block's rows go back to
+    HBM. The rest of the image is never read."""
     nw = spec_weight_count(spec)
-    w_refs, o_ref = rest[:nw], rest[nw]
-    sem = rest[-1]
-    cp = pltpu.make_async_copy(a_ref, o_ref, sem)
-    cp.start()
-    cp.wait()
+    w_refs, o_ref, buf = rest[:nw], rest[nw], rest[nw + 1]
+    sems = rest[-1]
+    spans, (out_lo, out_n) = block_stage_spans(spec, buf.shape[0])
+    cps = [pltpu.make_async_copy(o_ref.at[pl.ds(lo, n), :],
+                                 buf.at[pl.ds(lo, n), :], sems.at[i])
+           for i, (lo, n) in enumerate(spans)]
+    for cp in cps:
+        cp.start()
+    for cp in cps:
+        cp.wait()
     if spec.kind == "fused":
-        scratch = rest[nw + 1]
-        _run_stages(spec, lambda st: _RoutedBlockMem(o_ref, scratch, st),
+        scratch = rest[nw + 2]
+        _run_stages(spec, lambda st: _RoutedBlockMem(buf, scratch, st),
                     w_refs)
     else:
-        _BODIES[spec.kind](_BlockMem(o_ref, spec), *w_refs, spec=spec)
+        _BODIES[spec.kind](_BlockMem(buf, spec), *w_refs, spec=spec)
+    cp = pltpu.make_async_copy(buf.at[pl.ds(out_lo, out_n), :],
+                               o_ref.at[pl.ds(out_lo, out_n), :], sems.at[0])
+    cp.start()
+    cp.wait()
 
 
 # ---------------------------------------------------------------------------
@@ -1281,16 +1333,20 @@ def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
         return _apply_stream(arena, spec, weights, interpret, params)
     fused = spec.kind == "fused"
     if spec.rowlen:
-        # one launch for the whole chain; intermediates live in the VMEM
-        # scratch (typed rows)
+        # the arena stays in HBM; its VMEM image takes the staged rows. One
+        # launch for a whole chain, intermediates in the chain scratch
+        # (typed rows)
         dt = _jnp_dtype(spec.dtype)
-        scratch = ([pltpu.VMEM((spec.scratch_rows, spec.rowlen), dt)]
-                   if fused else []) + [pltpu.SemaphoreType.DMA(())]
+        scratch = ([pltpu.VMEM(arena.shape, dt)]
+                   + ([pltpu.VMEM((spec.scratch_rows, spec.rowlen), dt)]
+                      if fused else [])
+                   + [pltpu.SemaphoreType.DMA(
+                       (len(block_stage_spans(spec, arena.shape[0])[0]),))])
         fn = pl.pallas_call(
             functools.partial(_block_kernel, spec=spec),
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
             + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(weights),
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
             input_output_aliases={0: 0},        # the arena is donated through
             scratch_shapes=scratch,

@@ -148,6 +148,31 @@ def test_stats_count_launches_by_kind(profiled):
                         "launches.softmax": 2}
 
 
+def test_stats_count_the_rows_each_launch_stages(profiled):
+    """``stage_bytes`` counts, per call, each launch's DMA-aligned input
+    and output rows once (the union of their 8-row groups) on the way in
+    and the output's groups on the way back. A diagonal overlap shares
+    rows between a launch's input and output, and the total stays far
+    below the whole arena in and out per launch."""
+    be, cp, _, _ = profiled
+    bp = cp.legalised()
+    specs = PallasExecutor(layout="blocks", interpret=True).lower_blocks(bp)
+
+    def groups(off, rows):
+        return set(range(off // 8, -(-(off + rows) // 8)))
+
+    per_call, overlaps = 0, 0
+    for s in specs:
+        ins = set().union(*(groups(o, r) for o, (r, _)
+                            in zip(s.in_off, s.in_rows)))
+        out = groups(s.out_off, s.out_rows[0])
+        overlaps += bool(ins & out)
+        per_call += 8 * (len(ins | out) + len(out)) * bp.row_bytes
+    assert overlaps
+    assert be.stats()["stage_bytes"] == 2 * per_call
+    assert per_call < 2 * len(specs) * bp.total_rows * bp.row_bytes
+
+
 def test_every_kernel_is_named_by_its_kind_and_op():
     """Each ``pallas_call`` carries ``dmo_<kind>_<op>`` as its name, so a
     device trace read by hand tells the kernels apart."""
@@ -190,5 +215,5 @@ def test_stats_start_at_zero():
     assert set(st) == {"calls", "images", "h2d_bytes", "d2h_bytes",
                        "uploads", "weight_hits", "weight_misses",
                        "lowering_hits", "lowering_misses",
-                       "programs_built", "first_call_s"}
+                       "programs_built", "first_call_s", "stage_bytes"}
     assert not any(st.values())

@@ -155,3 +155,20 @@ def test_published_join_kernel_compiles_for_v5e(one_chip, irv2_published,
         vmem_limit=runtime.vmem_limit(_V5E)))
     compiled = _compile(fn, shape, dt, [spec], one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["stem_c1", "m17_0_b2c"])
+def test_published_conv_kernel_compiles_for_v5e(one_chip, irv2_published,
+                                                name):
+    """The published stem's first conv (3 input channels, 299 px) and a
+    17x17 block-B 7x1 conv compile on their own, each staging only its
+    operands' rows of the 7.4 MB arena."""
+    specs, shape, dt, _ = irv2_published
+    spec = next(s for s in specs if s.name == name)
+    spans, out = arena_ops.block_stage_spans(spec, shape[0])
+    assert sum(n for _, n in spans) < shape[0] and out[1] < shape[0]
+    fn = jax.jit(lambda arena, *w: arena_ops.apply_op(
+        arena, spec, w, interpret=False,
+        vmem_limit=runtime.vmem_limit(_V5E)))
+    compiled = _compile(fn, shape, dt, [spec], one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
