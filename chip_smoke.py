@@ -11,9 +11,9 @@ weights (f32 to the shared fp32 tolerance, int8 to <= 1 LSB).
 Phases (all in this one process):
 
 - ``mobilenet_v1_0.25_128_8bit`` at batch 1 and batch 8, compiled route
-  (each launch stages its operands' rows into an arena-sized VMEM image);
-- the same model at batch 1 on the streaming route (arena in HBM, live
-  windows DMA'd into VMEM);
+  (each launch stages its operands' rows into a packed VMEM buffer);
+- the same model at batch 1 on the streaming route (its convs, depthwise
+  convs and pools on the roll grid, every other launch staged alike);
 - ``mobilenet_v1_1.0_224`` f32 at batch 1, compiled route.
 
 Each phase that passes prints one JSON line; a failing phase prints its

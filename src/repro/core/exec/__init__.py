@@ -11,21 +11,19 @@ into a pluggable runtime layer:
   (bit-exact ground truth, used by ``verify_plan``);
 - ``pallas`` — :mod:`.pallas_backend`: lowers the plan to a sequence of
   Pallas kernels over one donated arena buffer (``input_output_aliases``
-  threads the arena through the op sequence). Three programs: the
+  threads the arena through the op sequence). Two arenas: the
   **row-blocked** 2-D arena (plans legalised onto per-dtype VMEM tiles by
   :func:`repro.core.planner.legalise_for_blocks` — the compiled-mode path,
-  and the default whenever the plan legalises), the **streaming** grid
-  program (``mode="streaming"``: arena in HBM, each op's live window
-  DMA'd into VMEM scratch per the planner's
-  :meth:`~repro.core.planner.BlockPlan.window_schedule`, VMEM-gated on
-  the window instead of the whole arena), and the **flat** byte arena
-  (interpret-only fallback for mixed-dtype plans, and the cross-check
-  reference). ``mode="interpret"`` runs any of them in the Pallas
-  interpreter on the host CPU; ``mode="compiled"`` lowers the blocked
-  program through Mosaic on a TPU — the TPU analogue of the paper's SRAM
-  arena being VMEM. Unpinned, the mode follows the platform. Select per
-  instance via
-  ``get_backend("pallas", mode=..., layout=...)``.
+  and the default whenever the plan legalises; kept in HBM, each launch
+  staging its operands' rows in VMEM, and with ``mode="streaming"`` its
+  convs, depthwise convs and pools on a rolling-window grid per the
+  planner's :meth:`~repro.core.planner.BlockPlan.window_schedule`), and
+  the **flat** byte arena (interpret-only fallback for mixed-dtype plans,
+  and the cross-check reference). ``mode="interpret"`` runs any of them
+  in the Pallas interpreter on the host CPU; ``mode="compiled"`` lowers
+  the blocked program through Mosaic on a TPU — the TPU analogue of the
+  paper's SRAM arena being VMEM. Unpinned, the mode follows the platform.
+  Select per instance via ``get_backend("pallas", mode=..., layout=...)``.
 
 Every backend implements the :class:`ArenaExecutor` protocol::
 

@@ -1,22 +1,20 @@
 """Pallas arena executor: lower a plan to kernels over ONE donated buffer.
 
-Three arena programs share the backend (see :mod:`repro.kernels.arena_ops`):
+Two arena addressings share the backend (see :mod:`repro.kernels.arena_ops`):
 
 - **row-blocked** (the default whenever the plan legalises): the plan is
   passed through :func:`repro.core.planner.legalise_for_blocks`, giving
   every tensor a ``(rows, rowlen)`` block at a sublane-tile-aligned row
   offset over one typed 2-D arena ((8, 128) f32 / (32, 128) int8 tiles).
   Kernels address whole arena rows via ``pl.dslice`` — no byte bitcasts —
-  so the same program lowers under ``interpret=False``: this is the
-  compiled-mode path, the TPU-VMEM realisation of the paper's SRAM arena.
-  Each launch holds an arena-sized VMEM image, so VMEM caps
-  ``total_rows``, but copies only its operands' rows between it and HBM.
-- **streaming** (``mode="streaming"``): the same row-blocked layouts, but
-  the arena lives in ``pl.ANY`` (HBM) and each op DMAs only its *live
-  window* (:meth:`repro.core.planner.BlockPlan.window_schedule`) into VMEM
-  scratch with double-buffered ``make_async_copy``. The VMEM gate becomes
-  the schedule's ``max_resident_bytes`` instead of the whole arena — the
-  refactor that turns the ~16 MB arena ceiling into a window ceiling.
+  so the program lowers under ``interpret=False``: the TPU-VMEM
+  realisation of the paper's SRAM arena. The arena stays in HBM, and each
+  launch copies only its operands' rows into one packed VMEM buffer
+  (:func:`repro.core.planner.stage_rows`) and its output's rows back. Two
+  routes run it: the compiled route stages every launch so;
+  ``mode="streaming"`` stages every launch but the conv / depthwise /
+  pool ones, which run a row-tile grid over a rolling input window
+  (:meth:`repro.core.planner.BlockPlan.window_schedule`).
 - **flat** (fallback, and the cross-check reference): the byte-granular
   program over a 1-D uint8 arena of exactly ``plan.peak_bytes``; kernels
   bitcast their windows to the tier each layout declares, so mixed-dtype
@@ -27,10 +25,11 @@ Execution mode is ``mode="interpret"`` (the Pallas interpreter, run on
 the host CPU), ``mode="compiled"`` (Mosaic lowering on a TPU; requires
 row-blocked layouts), or ``mode="streaming"`` (interpreted or compiled).
 Unpinned, the mode follows the platform (:mod:`repro.kernels.runtime`):
-interpret on the CPU backend, compiled on a TPU. The VMEM budget the
-compiled and streaming gates check against — and the scoped-VMEM limit
-handed to Mosaic — is ``vmem_budget`` bytes, by default the device kind's
-row of :data:`repro.kernels.runtime.VMEM_LIMIT_BYTES`.
+interpret on the CPU backend, compiled on a TPU. One VMEM gate holds on
+both routes: the largest launch's VMEM rows and filters must fit
+``vmem_budget`` bytes, by default the device kind's row of
+:data:`repro.kernels.runtime.VMEM_LIMIT_BYTES`, which is also the
+scoped-VMEM limit handed to Mosaic.
 
 Split row bands lower like any conv/pool: ``_canon_meta`` takes the op's
 geometry from the band-aware :func:`repro.core.exec.ops.pads`, so a band's
@@ -179,7 +178,8 @@ class PallasExecutor:
 
     ``mode``: ``"interpret"`` (the Pallas interpreter on the host CPU),
     ``"compiled"`` (Mosaic lowering on a TPU), or ``"streaming"``
-    (ANY-space arena, live windows DMA'd into VMEM scratch; runs
+    (conv / depthwise / pool launches on the roll grid, every other launch
+    staged as on the compiled route; runs
     interpreted or compiled — pass ``interpret=`` to pin it, else the
     platform decides). ``None`` follows the platform: interpret on the CPU
     backend, compiled on a TPU. ``layout``:
@@ -191,10 +191,11 @@ class PallasExecutor:
     row layout whenever packing fails to reduce the padded peak.
     Compiled and streaming modes require the row-blocked program — a flat
     byte arena cannot meet the VMEM tilings. ``vmem_budget`` (bytes, by
-    default the device kind's VMEM limit) gates execution: compiled mode
-    refuses a launch whose arena, chain scratch and weights exceed it,
-    streaming mode refuses only schedules whose ``max_resident_bytes``
-    exceeds it."""
+    default the device kind's VMEM limit) gates every row-blocked
+    program, in every mode: it is refused where one launch's VMEM arena
+    rows (a staged launch's buffer plus a fused chain's scratch, a
+    roll-grid launch's window) and its filters exceed the budget. The
+    arena itself may exceed it."""
 
     name = "pallas"
 
@@ -257,9 +258,9 @@ class PallasExecutor:
         and ``first_call_s``, their host seconds; ``launches.<kind>``, the
         kernels launched by op kind (``launches.conv2d``,
         ``launches.concat``, ``launches.fused`` for a band chain), over
-        all calls; ``stage_bytes``, the arena bytes the row-blocked
-        launches copied between HBM and VMEM, both directions (the
-        streaming route's window copies and the flat program add
+        all calls; ``stage_bytes``, the arena bytes the staged launches
+        of either route copied between HBM and VMEM, both directions (the
+        streaming route's roll-grid copies and the flat program add
         nothing). Read it before and after a window: a ``programs_built``
         or ``weight_misses`` that moved names a recompile or a re-upload."""
         return dict(self._stats)
@@ -408,8 +409,7 @@ class PallasExecutor:
 
     @staticmethod
     def _chain_ext_inputs(members: List[Op], internal) -> List:
-        """The chain's external data inputs, deduped in first-read order —
-        the DMA order of the streaming fused kernel."""
+        """The chain's external data inputs, deduped in first-read order."""
         ext, seen = [], set()
         for op in members:
             for t in op.inputs:
@@ -481,17 +481,14 @@ class PallasExecutor:
         return tuple(specs)
 
     def _fused_block_spec(self, bplan: BlockPlan, members: List[Op],
-                          quant: Optional[X.QuantSpec], window=None):
-        """One row-blocked spec for a fused band chain — or, given the
-        chain's staged :class:`~repro.core.planner.OpWindow`, the streaming
-        variant, whose stages run entirely inside the VMEM scratch buffer
-        (every operand gets an ``include_io`` scratch slot; external inputs
-        are DMA'd in up front, the terminal output DMA'd back once).
-        Scratch slots are sized over the *batched* rows (per-image rows ×
-        batch — per-image sub-blocks pack back to back inside a slot);
-        stages expand op-major (member-major, image-minor) so the chain
-        executes in the exact order the planner's liveness model assumes,
-        each stage addressing its image's sub-block."""
+                          quant: Optional[X.QuantSpec]):
+        """One row-blocked spec for a fused band chain, the same on both
+        routes: external operands at their arena rows, chain-internal ones
+        in scratch slots. Scratch slots are sized over the *batched* rows
+        (per-image rows × batch — per-image sub-blocks pack back to back
+        inside a slot); stages expand op-major (member-major, image-minor)
+        so the chain executes in the exact order the planner's liveness
+        model assumes, each stage addressing its image's sub-block."""
         from repro.kernels.arena_ops import OpSpec
         dtype = "i8" if bplan.dtype_bytes == 1 else "f32"
         L = bplan.arena_rowlen
@@ -499,7 +496,6 @@ class PallasExecutor:
         cat = members[-1]
         B = cat.output.storage().batch
         internal = {op.output.storage() for op in members[:-1]}
-        streaming = window is not None
         packed = bplan.packing == "packed"
         irows_of = chain_image_rows_of(bplan)
 
@@ -525,8 +521,7 @@ class PallasExecutor:
             c, k, rl = triple_of(s)
             return L if k > 1 else c * rl
 
-        slots, total = fused_slots(members, rows_of, round_to=sub,
-                                   include_io=streaming)
+        slots, total = fused_slots(members, rows_of, round_to=sub)
         for s in internal:
             assert used_of(s) <= L, \
                 f"scratch row of {s.name} wider than the arena row"
@@ -537,7 +532,7 @@ class PallasExecutor:
             sub-block inside the batched slot, arena-resident ones the
             image's padded arena sub-block."""
             s = t.storage()
-            if s in internal or streaming:
+            if s in internal:
                 bb = b if s.batch > 1 else 0
                 return (slots[s] + bb * irows_of(s),
                         (irows_of(s), used_of(s)), 1)
@@ -573,9 +568,9 @@ class PallasExecutor:
         ext = self._chain_ext_inputs(members, internal)
         out_lay = bplan.layout_of(cat.output)
         # top-level I/O covers the WHOLE batched block of each external
-        # operand (per-image sub-blocks are contiguous), so the streaming
-        # up-front/write-back DMAs stay one entry per tensor
-        spec = OpSpec(
+        # operand (per-image sub-blocks are contiguous), so the launch
+        # stages each tensor as one block
+        return OpSpec(
             kind="fused",
             in_off=tuple(bplan.layout_of(t).row_offset for t in ext),
             in_shape=tuple(tuple(t.shape) for t in ext),
@@ -590,41 +585,25 @@ class PallasExecutor:
             stages=tuple(stages),
             scratch_rows=total,
             name=cat.params["fuse_chain"])
-        if streaming:
-            import dataclasses
-            assert window.resident_rows == total, \
-                f"fused window/slot mismatch: {window.resident_rows} vs " \
-                f"{total}"
-            spec = dataclasses.replace(
-                spec, win_lo=window.lo, win_rows=window.win_rows,
-                in_slots=tuple(slots[t.storage()] for t in ext),
-                out_slot=slots[cat.output.storage()])
-        return spec
 
     def lower_stream(self, bplan: BlockPlan,
                      quant: Optional[X.QuantSpec] = None) -> Tuple:
-        """BlockPlan -> streaming OpSpec sequence: the row-blocked specs
-        with each op's live-window statics grafted on from the planner's
-        :class:`~repro.core.planner.WindowSchedule` (1:1 — both skip
-        reshape views and both emit one entry per fused chain), so
-        ``win_rows > 0`` selects the streaming grid program in
-        :mod:`repro.kernels.arena_ops`. Fused chains are re-lowered in
-        their streaming form (all stage operands scratch-resident)."""
+        """BlockPlan -> streaming OpSpec sequence: the row-blocked specs,
+        with each conv / depthwise / pool's rolling-window statics grafted
+        on from the planner's :class:`~repro.core.planner.WindowSchedule`
+        (1:1 — both skip reshape views and both emit one entry per fused
+        chain), so those run the roll grid of
+        :mod:`repro.kernels.arena_ops`. Every other spec is the compiled
+        route's own, staged alike."""
         import dataclasses
         specs = self.lower_blocks(bplan, quant)
         ws = bplan.window_schedule()
-        chains = _fused_chains(bplan.order)
         assert len(specs) == len(ws.windows), \
             f"spec/window mismatch: {len(specs)} vs {len(ws.windows)}"
-        out: List = []
-        for s, w in zip(specs, ws.windows):
-            if s.kind == "fused":
-                out.append(self._fused_block_spec(
-                    bplan, chains[w.op_name], quant, window=w))
-            else:
-                out.append(dataclasses.replace(
-                    s, win_lo=w.lo, win_rows=w.win_rows, win_starts=w.starts))
-        return tuple(out)
+        return tuple(dataclasses.replace(s, win_rows=w.win_rows,
+                                         win_starts=w.starts)
+                     if w.rolling else s
+                     for s, w in zip(specs, ws.windows))
 
     # -- execution ----------------------------------------------------------
 
@@ -710,10 +689,16 @@ class PallasExecutor:
 
         with TraceAnnotation("dmo.legalise", call=call):
             bplan = self._legalised(plan)
-            specs, launches, stage_bytes = self._specs(plan, bplan, quant)
+            specs, launches, stage_bytes, vmem = self._specs(plan, bplan,
+                                                             quant)
             budget = self._resolve_budget()
-            if bplan is not None:
-                self._check_vmem(bplan, graph, specs, wflat, budget)
+            if vmem[0] > budget:
+                need, name, rows, fbytes = vmem
+                raise ValueError(
+                    f"arena program of {graph.name!r} does not fit VMEM: "
+                    f"launch {name} needs {need} bytes ({rows} arena rows "
+                    f"in VMEM, {fbytes} filter bytes), over the "
+                    f"{budget}-byte budget")
             if not interpret and jax.default_backend() != "tpu":
                 raise RuntimeError(
                     f"{self.mode} Pallas kernels need a TPU, and JAX runs "
@@ -823,10 +808,11 @@ class PallasExecutor:
 
     def _specs(self, plan: Plan, bplan: Optional[BlockPlan], quant) -> Tuple:
         """The lowered spec sequence of this call's route, its launches by
-        kind (``(("launches.<kind>", n), ...)``) and the arena bytes its
-        row-blocked launches stage between HBM and VMEM, from the
-        per-executor cache when the same plan and quantisation ran
-        before."""
+        kind (``(("launches.<kind>", n), ...)``), the arena bytes its
+        staged launches copy between HBM and VMEM, and the VMEM its largest
+        launch needs, ``(bytes, kernel name, arena rows, filter bytes)``
+        (``(0, ...)`` on the flat route) — from the per-executor cache when
+        the same plan and quantisation ran before."""
         from repro.kernels import arena_ops
         route = (("stream" if self.mode == "streaming" else "blocks")
                  if bplan is not None else "flat")
@@ -844,45 +830,22 @@ class PallasExecutor:
             specs = self.lower(plan, quant)
         launches = tuple(collections.Counter(
             "launches." + s.kind for s in specs).items())
-        stage_bytes = (sum(arena_ops.block_stage_bytes(s, bplan.total_rows)
-                           for s in specs) if route == "blocks" else 0)
-        self._lowered[key] = (plan, quant, specs, launches, stage_bytes)
+        stage_bytes, vmem = 0, (0, "", 0, 0)
+        if bplan is not None:
+            T = bplan.total_rows
+            stage_bytes = sum(arena_ops.block_stage_bytes(s, T)
+                              for s in specs if not s.win_starts)
+            for s in specs:
+                rows = arena_ops.launch_vmem_rows(s, T)
+                fbytes = arena_ops.spec_filter_bytes(s)
+                need = rows * bplan.row_bytes + fbytes
+                if need > vmem[0]:
+                    vmem = (need, arena_ops.kernel_name(s), rows, fbytes)
+        self._lowered[key] = (plan, quant, specs, launches, stage_bytes,
+                              vmem)
         while len(self._lowered) > 32:
             self._lowered.popitem(last=False)
-        return specs, launches, stage_bytes
-
-    def _check_vmem(self, bplan: BlockPlan, graph, specs, wflat,
-                    budget: int) -> None:
-        """Refuse a row-blocked program whose VMEM residency exceeds
-        ``budget``: the live window in streaming mode; in compiled mode
-        the whole arena, plus a fused chain's scratch and the weights the
-        launch stages there."""
-        from repro.kernels import arena_ops
-        if self.mode == "streaming":
-            ws = bplan.window_schedule()
-            if ws.max_resident_bytes > budget:
-                raise ValueError(
-                    f"streaming window of {graph.name!r} does not fit "
-                    f"VMEM: peak resident {ws.max_resident_bytes} bytes "
-                    f"({ws.max_window_rows} live rows) exceeds the "
-                    f"{budget}-byte budget")
-        elif self.mode == "compiled":
-            need, rows, wbytes = 0, 0, 0
-            i = 0
-            for s in specs:
-                nw = arena_ops.spec_weight_count(s)
-                w = sum(int(x.nbytes) for x in wflat[i:i + nw])
-                i += nw
-                r = bplan.total_rows + s.scratch_rows
-                if r * bplan.row_bytes + w > need:
-                    need, rows, wbytes = r * bplan.row_bytes + w, r, w
-            if need > budget:
-                raise ValueError(
-                    f"arena of {graph.name!r} does not fit VMEM: a "
-                    f"launch needs {need} bytes ({rows} arena + scratch "
-                    f"rows, {wbytes} weight bytes), over the "
-                    f"{budget}-byte budget — mode='streaming' keeps "
-                    "only the live window resident")
+        return specs, launches, stage_bytes, vmem
 
     @staticmethod
     def _seed_block_arena(bplan: BlockPlan, graph, inputs) -> np.ndarray:

@@ -226,8 +226,9 @@ TPU_TILES: Dict[int, Tuple[int, int]] = {4: (8, 128), 2: (16, 128),
 
 #: Row granularity of an HBM <-> VMEM copy of a (rows, lanes) arena: Mosaic
 #: takes DMA row slices whose start and length are multiples of 8 (f32 and
-#: int8 alike). Streaming slots keep each block at its arena row modulo 8,
-#: so one aligned copy moves it.
+#: int8 alike). A staged launch's buffer keeps each span at its arena row
+#: modulo the sublane tile (:func:`stage_rows`), so one aligned copy moves
+#: it.
 DMA_ROWS = 8
 
 
@@ -236,6 +237,64 @@ def dma_span(off: int, rows: int) -> Tuple[int, int]:
     ``[off, off + rows)``."""
     lo = off // DMA_ROWS * DMA_ROWS
     return lo, _round_up(off + rows, DMA_ROWS) - lo
+
+
+@dataclasses.dataclass(frozen=True)
+class Staging:
+    """The HBM <-> VMEM copies of one launch that stages whole operand
+    blocks, and the packed VMEM buffer they land in (:func:`stage_rows`).
+    ``spans`` are the ``(arena row, rows)`` spans copied in, ``bases`` the
+    buffer row each lands at, ``rows`` the buffer's rows; ``out`` is the
+    output block's span, copied back."""
+
+    spans: Tuple[Tuple[int, int], ...]
+    out: Tuple[int, int]
+    bases: Tuple[int, ...]
+    rows: int
+
+    def row(self, r: int) -> int:
+        """The buffer row holding arena row ``r``."""
+        for (lo, n), base in zip(self.spans, self.bases):
+            if lo <= r < lo + n:
+                return r - lo + base
+        raise ValueError(f"arena row {r} is not staged")
+
+
+def stage_rows(in_blocks: Sequence[Tuple[int, int]],
+               out_block: Tuple[int, int], total_rows: int,
+               sub: int) -> Staging:
+    """The one staging rule of every launch that stages whole operand
+    blocks, from the ``(arena row, rows)`` of each input block and of the
+    output block in an arena of ``total_rows`` rows.
+
+    In: the DMA-aligned span (:func:`dma_span`, cut at the arena's end) of
+    every input block and of the output block, sorted, with overlapping or
+    touching spans merged, so a diagonal in/out overlap is copied once and
+    operands that share a span keep their relative rows. Out: the output
+    block's aligned span. Whole 8-row groups travel both ways, so the rows
+    of a group outside the block (which an int8 row store rewrites
+    unchanged) go back with the values they came in with. The spans pack
+    back to back into one buffer, each at the first row congruent to its
+    arena row modulo the sublane tile ``sub``, so every operand keeps its
+    rows' alignment; the buffer's rows are rounded up to ``sub``."""
+    def span(off: int, rows: int) -> Tuple[int, int]:
+        lo, n = dma_span(off, rows)
+        return lo, min(n, total_rows - lo)
+
+    out = span(*out_block)
+    merged: List[Tuple[int, int]] = []
+    for lo, n in sorted([span(o, r) for o, r in in_blocks] + [out]):
+        if merged and lo <= merged[-1][0] + merged[-1][1]:
+            plo, pn = merged[-1]
+            merged[-1] = (plo, max(pn, lo + n - plo))
+        else:
+            merged.append((lo, n))
+    bases, cur = [], 0
+    for lo, n in merged:
+        cur += (lo - cur) % sub
+        bases.append(cur)
+        cur += n
+    return Staging(tuple(merged), out, tuple(bases), _round_up(cur, sub))
 
 #: Op kinds whose kernels stream output rows (and therefore read/write the
 #: arena one whole row at a time — the shapes the row-granular O_s covers).
@@ -787,31 +846,9 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def staged_slots(in_blocks: Sequence[Tuple[int, int]],
-                 out_block: Tuple[int, int], sub: int,
-                 ) -> Tuple[Tuple[int, ...], int, int]:
-    """Scratch packing for a staged (whole-tensor) streaming op, from the
-    ``(arena row offset, rows)`` of each input block and of the output
-    block: each block gets a slot holding its :func:`dma_span`, slots
-    packed back-to-back (output last), total rounded up to the sublane
-    tile. Returns ``(input block row offsets, output block row offset,
-    total scratch rows)`` — each block sits at its arena row modulo
-    :data:`DMA_ROWS` inside its slot. A staged op costs the sum of its
-    block spans, not the span between scattered placements. The kernel
-    layer and the planner both derive the packing from this one function,
-    so the scratch a kernel allocates always matches the resident rows the
-    schedule reports."""
-    offs: List[int] = []
-    cur = 0
-    for off, rows in list(in_blocks) + [out_block]:
-        offs.append(cur + off % DMA_ROWS)
-        cur += dma_span(off, rows)[1]
-    return tuple(offs[:-1]), offs[-1], _round_up(cur, sub)
-
-
 def fused_slots(members: Sequence[Op], size_of, align: int = 1,
                 round_to: int = 1, include_io: bool = False,
-                dma_io: bool = True) -> Tuple[Dict[Tensor, int], int]:
+                ) -> Tuple[Dict[Tensor, int], int]:
     """Scratch-slot packing for one fused band chain.
 
     The chain's internal tensors (every member output except the last
@@ -822,19 +859,15 @@ def fused_slots(members: Sequence[Op], size_of, align: int = 1,
     for the flat one), so a mid band's slot is reused as soon as its
     consumer band has read it, while the per-band outputs accumulate until
     the concat. ``include_io=True`` additionally packs the chain's external
-    inputs and its terminal output into the scratch (the streaming program
-    stages *everything* in VMEM: inputs are DMA'd up front, the output is
-    DMA'd back at the end) — and since an external input dies at its last
-    in-chain read, the output slot can reuse its space.
+    inputs (live from the chain's start) and its terminal output, all
+    tight, and since an external input dies at its last in-chain read, the
+    output can reuse its space: the chain's live rows, which the window
+    schedule reports as the chain's ``win_rows``.
 
-    Internal slots pack tight; with ``include_io`` the staged I/O blocks
-    (whole tensors at DMA-aligned arena rows) take :data:`DMA_ROWS`-aligned
-    slots of whole DMA rows, so one aligned copy moves each
-    (``dma_io=False`` packs them tight too: the chain's live rows, without
-    the copy slack). Only the total is rounded up to ``round_to``. Returns
-    ``(slot offset per tensor, total scratch units)``. The kernel layer,
-    the window schedule and the FusePass budget estimate all derive the
-    packing from this one function."""
+    Only the total is rounded up to ``round_to``. Returns ``(slot offset
+    per tensor, total scratch units)``. The kernel layer, the window
+    schedule and the FusePass budget estimate all derive the packing from
+    this one function."""
     n = len(members)
     internal = {op.output.storage() for op in members[:-1]}
     first: Dict[Tensor, int] = {}
@@ -855,23 +888,20 @@ def fused_slots(members: Sequence[Op], size_of, align: int = 1,
             if s in internal:
                 touch(s, i)
             elif include_io:
-                touch(s, 0)        # resident from the up-front DMA
+                touch(s, 0)        # live from the chain's start
                 last[s] = max(last[s], i)
         s = op.output.storage()
         if s in internal:
             touch(s, i)
         elif include_io:
             touch(s, i)
-            last[s] = n - 1        # held until the write-back DMA
+            last[s] = n - 1        # live to the chain's end
     scopes = {s: (first[s], last[s]) for s in tensors}
-    io = {s for s in tensors if s not in internal} if dma_io else set()
-    sizes = {s: (_round_up(int(size_of(s)), DMA_ROWS) if s in io
-                 else int(size_of(s))) for s in tensors}
+    sizes = {s: int(size_of(s)) for s in tensors}
     placed: Dict[Tensor, int] = {}
     for s in tensors:              # first-touch (production) order
         placed[s] = _lowest_feasible(
-            s, placed, scopes, list(members), {}, sizes=sizes,
-            align=max(align, DMA_ROWS) if s in io else align)
+            s, placed, scopes, list(members), {}, sizes=sizes, align=align)
     total = max((placed[s] + sizes[s] for s in tensors), default=0)
     return placed, _round_up(total, max(1, round_to))
 
@@ -985,10 +1015,11 @@ def tile_writeback(out_off: int, oh: int, co: int, ko: int, sub: int,
 class OpWindow:
     """One op's live window in the streaming schedule: the contiguous
     arena-row extent ``[lo, hi)`` it may touch, the live-window rows
-    (``win_rows``) and the scratch rows its streaming program allocates
-    (``resident_rows`` — the rolling input window is double-buffered, and
-    every slot holds whole :data:`DMA_ROWS` groups, so resident exceeds
-    the live window).
+    (``win_rows``) and the VMEM arena rows its launch allocates
+    (``resident_rows``: a rolling launch's double-buffered input window
+    and output slot; a staged launch's :func:`stage_rows` buffer, plus a
+    fused chain's scratch — whole :data:`DMA_ROWS` groups, so resident
+    exceeds the live window).
     ``starts`` is the per-output-tile fetch start table for rolling
     (conv / depthwise / pool) ops; empty for staged whole-tensor ops."""
 
@@ -1009,9 +1040,9 @@ class OpWindow:
 class WindowSchedule:
     """The live-window row schedule of a :class:`BlockPlan`: per executed op
     (reshapes excluded, same order the backends lower), the arena rows it
-    may touch and the rows its streaming program keeps resident in VMEM,
-    plus the whole-program bound ``max_window_rows`` — the quantity that
-    replaces ``total_rows`` as the streaming executor's VMEM ceiling."""
+    may touch and the rows its launch keeps resident in VMEM, plus the
+    whole-program bound ``max_window_rows``, which the legaliser weighs
+    when it picks a layout."""
 
     windows: Tuple[OpWindow, ...]
     total_rows: int
@@ -1028,8 +1059,8 @@ class WindowSchedule:
 
     @property
     def max_resident_bytes(self) -> int:
-        """Peak scratch footprint of any one streaming op (all slots,
-        double-buffering included)."""
+        """Peak VMEM arena footprint of any one launch (all slots,
+        double-buffering included; filters not counted)."""
         return max((w.resident_rows * self.row_bytes
                     for w in self.windows), default=0)
 
@@ -1108,40 +1139,42 @@ def chain_image_rows_of(bplan: BlockPlan):
 
 def _fused_window(bplan: BlockPlan, members: Sequence[Op],
                   sub: int) -> OpWindow:
-    """One staged window for a fused band chain. The streaming fused
-    kernel DMAs every external-input block into VMEM up front, runs all
-    chain stages inside the scratch buffer and writes only the terminal
-    block back — so the resident rows are the ``include_io``
-    :func:`fused_slots` packing (chain scratch plus the staged I/O blocks),
-    and the row extent spans the external operands' arena placements.
-    Chain-internal tensors have no layouts; their scratch rows come from
-    the shared :func:`chain_image_rows_of` rule (one arena row per image
-    row on legacy layouts, packed geometry on packed ones). A batched
-    chain stages ALL images at once (its stages run op-major inside the
-    one kernel, so every image of a member's output is live before the
-    next member runs) — the VMEM window scales with the batch and the
-    budget gate polices that honestly."""
+    """One staged window for a fused band chain. Its launch stages every
+    external-input block and the terminal output block (:func:`stage_rows`)
+    and holds the chain's internal tensors in a scratch buffer
+    (:func:`fused_slots`): those are its resident rows. Its live rows are
+    the ``include_io`` packing, every operand tight, and the row extent
+    spans the external operands' arena placements. Chain-internal tensors
+    have no layouts; their scratch rows come from the shared
+    :func:`chain_image_rows_of` rule (one arena row per image row on legacy
+    layouts, packed geometry on packed ones). A batched chain stages ALL
+    images at once (its stages run op-major inside the one kernel, so every
+    image of a member's output is live before the next member runs) — the
+    VMEM window scales with the batch and the budget gate polices that
+    honestly."""
     internal = {op.output.storage() for op in members[:-1]}
     irows_of = chain_image_rows_of(bplan)
 
     def rows_of(s: Tensor) -> int:
         return irows_of(s) * (s.batch if s.batch > 1 else 1)
 
-    _, live = fused_slots(members, rows_of, round_to=sub, include_io=True,
-                          dma_io=False)
-    _, total = fused_slots(members, rows_of, round_to=sub, include_io=True)
+    _, live = fused_slots(members, rows_of, round_to=sub, include_io=True)
+    _, scratch = fused_slots(members, rows_of, round_to=sub)
     ext: List[BlockLayout] = []
     for op in members:
         for t in op.inputs:
             s = t.storage()
             if s.kind != "weight" and s not in internal:
                 ext.append(bplan.layouts[s])
-    ext.append(bplan.layouts[members[-1].output.storage()])
+    out = bplan.layouts[members[-1].output.storage()]
+    staged = stage_rows([(l.row_offset, l.rows) for l in ext],
+                        (out.row_offset, out.rows), bplan.total_rows, sub)
+    ext.append(out)
     lo = min(l.row_offset for l in ext)
     hi = max(l.row_offset + l.rows for l in ext)
     return OpWindow(members[-1].params["fuse_chain"], "fused",
                     (lo // sub) * sub, _round_up(hi, sub),
-                    win_rows=live, resident_rows=total)
+                    win_rows=live, resident_rows=staged.rows + scratch)
 
 
 def window_schedule(bplan: BlockPlan) -> "WindowSchedule":
@@ -1149,9 +1182,9 @@ def window_schedule(bplan: BlockPlan) -> "WindowSchedule":
 
     Row-streaming ops (conv / depthwise / pool) get a rolling input window
     plus a one-tile output slot via :func:`rolling_starts`; every other
-    kind stages whole operand blocks via :func:`staged_slots` (each block
+    kind stages whole operand blocks via :func:`stage_rows` (each block
     is contiguous, so a scattered multi-operand extent — e.g. a
-    band-reassembling concat — costs only the sum of its block heights,
+    band-reassembling concat — costs only the sum of its block spans,
     not the span between them). A fused band chain contributes ONE staged
     window (at the first member's position, named after the chain) sized by
     :func:`_fused_window`."""
@@ -1205,14 +1238,14 @@ def window_schedule(bplan: BlockPlan) -> "WindowSchedule":
                     win_rows=win_in + tile_arena_rows(*out_addr, sub),
                     resident_rows=2 * win_in + slot_rows, starts=starts))
             else:
-                _, _, total = staged_slots(
+                staged = stage_rows(
                     [(o, l.image_rows) for o, l in zip(offs, lays)],
-                    (out_off, out.image_rows), sub)
+                    (out_off, out.image_rows), bplan.total_rows, sub)
                 live = sum(l.image_rows for l in lays) + out.image_rows
                 windows.append(OpWindow(
                     op.name, op.kind, (lo_e // sub) * sub,
                     _round_up(hi_e, sub), win_rows=_round_up(live, sub),
-                    resident_rows=total))
+                    resident_rows=staged.rows))
     return WindowSchedule(tuple(windows), bplan.total_rows,
                           bplan.arena_rowlen, bplan.dtype_bytes)
 

@@ -1,5 +1,5 @@
 """Generalised DMO arena kernels: every supported op as a Pallas call over
-ONE shared arena buffer, in one of three arena programs.
+ONE shared arena buffer.
 
 This generalises :mod:`repro.kernels.dmo_arena_dwconv` (a single hard-coded
 depthwise conv) to the full op set a :class:`~repro.core.planner.Plan` can
@@ -10,20 +10,20 @@ fully_connected / matmul / concat / pad / mean. Each op becomes one
 in-place through the op sequence — the TPU-VMEM analogue of the paper's SRAM
 tensor arena.
 
-Three arena addressings share the same kernel bodies through a small memory
-access layer (an :class:`OpSpec` with ``rowlen == 0`` selects the flat
-program, ``rowlen > 0`` the blocked one, and ``win_rows > 0`` on top of
-that the streaming one):
+The kernel bodies are written once against a small memory access layer,
+and three kernels run them (an :class:`OpSpec` with ``rowlen == 0``
+selects the flat program, ``rowlen > 0`` the row-blocked launches, and
+``win_starts`` on top of that the roll grid):
 
-- **flat** (:class:`_FlatMem`) — the arena is a 1-D *byte* buffer; operands
-  live at byte offsets and kernels bitcast their windows to the tier the
-  spec declares (f32 windows / int8 bytes, the quantised tier running int32
-  accumulation plus the float32 requantisation of
-  :mod:`repro.core.exec.ops`). Mixed-dtype plans execute in one buffer, but
-  byte-granular dynamic slices fight the TPU's (8, 128)/(32, 128) VMEM
-  tilings — this program is interpret-mode only.
-- **row-blocked** (:class:`_BlockMem`) — the arena is a 2-D
-  ``(rows, rowlen)`` buffer *typed* to the plan's dtype, laid out by
+- **flat** (:class:`_FlatMem`, :func:`_flat_kernel`) — the arena is a 1-D
+  *byte* buffer; operands live at byte offsets and kernels bitcast their
+  windows to the tier the spec declares (f32 windows / int8 bytes, the
+  quantised tier running int32 accumulation plus the float32 requantisation
+  of :mod:`repro.core.exec.ops`). Mixed-dtype plans execute in one buffer,
+  but byte-granular dynamic slices fight the TPU's (8, 128)/(32, 128) VMEM
+  tilings — this program is interpret-only.
+- **staged** (:class:`_BlockMem`, :func:`_block_kernel`) — the arena is a
+  2-D ``(rows, rowlen)`` buffer *typed* to the plan's dtype, laid out by
   :func:`repro.core.planner.legalise_for_blocks`: operands occupy whole
   arena rows at row-aligned offsets, conv/pool walk image rows via
   ``pl.ds`` on the row axis, and no bitcasts are needed — the program
@@ -31,22 +31,19 @@ that the streaming one):
   triples) put ``cols_per_row`` narrow image rows in each arena row —
   reads pick the lane phase from static slices, writes merge it into the
   loaded row — or span one wide image row over ``row_span`` consecutive
-  arena rows. The arena stays in HBM; each launch holds a VMEM image of
-  it at the same row indices, copies in only the DMA-aligned rows of its
-  operand blocks (:func:`block_stage_spans`) and copies back only its
-  output block's. The image is arena-sized, so the VMEM capacity caps
-  ``total_rows``.
-- **streaming** (:class:`_StreamRollMem` / :class:`_StreamStageMem`) — the
-  arena stays in ``pl.ANY`` (HBM) and each op DMAs only its *live
-  window* (:class:`repro.core.planner.WindowSchedule`) into VMEM scratch
-  with ``pltpu.make_async_copy``. Row-streaming ops (conv / depthwise /
-  pool) run a row-tile grid: a double-buffered rolling input window (the
-  tile-``t+1`` fetch is issued before the tile-``t`` wait) plus an output
-  slot mirroring the tile's DMA-aligned arena span. Every other kind
-  stages whole operand blocks into packed scratch slots
-  (:func:`repro.core.planner.staged_slots`, fetches pipelined over two
-  rotating DMA semaphores), computes, and copies the output block back.
-  The VMEM ceiling becomes ``max_window_rows``, not ``total_rows``.
+  arena rows. The arena stays in HBM (``pl.ANY``); each launch copies the
+  DMA-aligned spans of its operand blocks into one packed VMEM buffer
+  (:func:`repro.core.planner.stage_rows`, every copy started before the
+  first wait), runs the body, and copies back only its output block's
+  span. A fused band chain is one such launch, its internal tensors in a
+  second VMEM scratch buffer. Every launch of both routes but the roll
+  grid's runs here.
+- **roll grid** (:class:`_StreamRollMem`, :func:`_stream_roll_kernel`) —
+  on the streaming route, conv / depthwise / pool run a row-tile grid: a
+  double-buffered rolling input window (the tile-``t+1`` fetch is issued
+  before the tile-``t`` wait; :class:`repro.core.planner.WindowSchedule`)
+  plus an output slot mirroring the tile's DMA-aligned arena span. It is
+  the one launch that holds less than a whole operand in VMEM.
 
 Split row bands (§II.A) need no kernels of their own: a banded conv/pool's
 spec carries its band shapes and its explicit band-local pads (a producer
@@ -82,7 +79,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.planner import (DMA_ROWS, dma_span, staged_slots,
+from repro.core.planner import (DMA_ROWS, Staging, stage_rows,
                                 tile_writeback)
 
 #: jnp mirrors of repro.core.exec.ops.ELEMENTWISE (same names, same maths).
@@ -110,16 +107,14 @@ class OpSpec:
 
     ``rowlen == 0`` selects the flat byte program: ``in_off``/``out_off``
     are *byte* offsets into a 1-D uint8 arena. ``rowlen > 0`` selects the
-    row-blocked program over a typed ``(rows, rowlen)`` arena: offsets are
+    row-blocked launches over a typed ``(rows, rowlen)`` arena: offsets are
     arena *row* indices and ``in_rows``/``out_rows`` carry each operand's
     ``(rows, used-elements-per-row)`` block shape from its
-    :class:`~repro.core.planner.BlockLayout`. ``win_rows > 0`` (on top of
-    ``rowlen > 0``) selects the streaming grid program: the arena lives in
-    ``pl.ANY`` and only the op's live window (``win_rows`` rows, plus
-    double-buffering and DMA row-group slack) is VMEM-resident —
-    ``win_starts`` is the planner's per-output-tile fetch start table for
-    rolling conv/pool windows (empty = staged whole-block op), ``win_lo``
-    the low edge of the op's live-window extent (reporting only)."""
+    :class:`~repro.core.planner.BlockLayout`. A non-empty ``win_starts``
+    (on top of ``rowlen > 0``) selects the roll grid of a conv / depthwise
+    / pool: the planner's per-output-tile fetch start table, with
+    ``win_rows`` the op's live-window rows (rolling input window plus one
+    output tile)."""
 
     kind: str
     in_off: Tuple[int, ...]            # byte (flat) | arena-row (blocked)
@@ -132,8 +127,7 @@ class OpSpec:
     rowlen: int = 0                    # arena row elements (0 = flat program)
     in_rows: Tuple[Tuple[int, int], ...] = ()  # (rows, used) per input
     out_rows: Tuple[int, int] = ()             # (rows, used) of the output
-    win_lo: int = 0                    # live-window extent low edge (rows)
-    win_rows: int = 0                  # live-window rows (0 = non-streaming)
+    win_rows: int = 0                  # roll-grid live-window rows
     win_starts: Tuple[int, ...] = ()   # rolling-window fetch starts per tile
     #: Packed row addressing (blocked/streaming programs only): per-operand
     #: ``(cols_per_row, row_span, image_rowlen)`` triples from the packed
@@ -156,8 +150,6 @@ class OpSpec:
     scratch_rows: int = 0              # chain scratch: rows (blocked) | bytes (flat)
     in_scratch: Tuple[int, ...] = ()   # stage flag: input i reads the scratch ref
     out_scratch: int = 0               # stage flag: output writes the scratch ref
-    in_slots: Tuple[int, ...] = ()     # fused streaming: ext-input scratch slots
-    out_slot: int = 0                  # fused streaming: terminal-output slot
     #: The graph op (or fused chain) the spec lowers: names its kernel.
     name: str = ""
 
@@ -505,12 +497,15 @@ class _FlatMem:
 class _BlockMem:
     """Row-blocked accessor over a typed (R, L) ref: image rows by arena
     row (static or traced) and lane phase, whole blocks as matrices — no
-    bitcasts, compiled-mode lowerable."""
+    bitcasts, compiled-mode lowerable. ``at`` maps an operand's arena row
+    to its row of ``ref`` (a staged launch's
+    :meth:`repro.core.planner.Staging.row`; the identity by default)."""
 
-    def __init__(self, ref, spec: OpSpec):
+    def __init__(self, ref, spec: OpSpec, at=None):
         self.ref, self.spec = ref, spec
         self.cdt = _cdt(spec.dtype)
         self.L = spec.rowlen
+        self._at = at or (lambda r: r)
 
     def _in_ref(self, i: int):
         return self.ref
@@ -519,10 +514,10 @@ class _BlockMem:
         return self.ref
 
     def _in_off(self, i: int):
-        return self.spec.in_off[i]
+        return self._at(self.spec.in_off[i])
 
     def _out_off(self):
-        return self.spec.out_off
+        return self._at(self.spec.out_off)
 
     def is_image(self, i: int) -> bool:
         rows, used = self.spec.in_rows[i]
@@ -579,8 +574,8 @@ class _RoutedMem:
     so the written-once bodies run unmodified while chain-internal values
     stay VMEM-resident."""
 
-    def __init__(self, arena_ref, scratch_ref, spec: OpSpec):
-        super().__init__(arena_ref, spec)
+    def __init__(self, arena_ref, scratch_ref, spec: OpSpec, *args):
+        super().__init__(arena_ref, spec, *args)
         self.scratch_ref = scratch_ref
 
     def _in_ref(self, i: int):
@@ -596,7 +591,19 @@ class _RoutedFlatMem(_RoutedMem, _FlatMem):
 
 
 class _RoutedBlockMem(_RoutedMem, _BlockMem):
-    pass
+    """Scratch-flagged operands keep their scratch rows; arena-flagged ones
+    map through ``at`` like any staged operand."""
+
+    def _in_off(self, i: int):
+        flags = self.spec.in_scratch
+        if flags and flags[i]:
+            return self.spec.in_off[i]
+        return super()._in_off(i)
+
+    def _out_off(self):
+        if self.spec.out_scratch:
+            return self.spec.out_off
+        return super()._out_off()
 
 
 class _StreamRollMem(_BlockMem):
@@ -640,27 +647,6 @@ class _StreamRollMem(_BlockMem):
         jax.lax.fori_loop(self.row_lo, self.row_hi, body, 0)
 
 
-class _StreamStageMem(_BlockMem):
-    """Streaming accessor for a staged whole-block op: operand blocks were
-    DMA'd into packed scratch slots before the body runs (read-all before
-    write-all — exactly the blocked kernels' order); the output block is
-    staged in its slot, which the kernel copies back after the body."""
-
-    def __init__(self, ref, spec: OpSpec, offs: Tuple[int, ...],
-                 out_slot: int):
-        super().__init__(ref, spec)
-        self.offs, self.out_slot = offs, out_slot
-
-    def _in_off(self, i: int):
-        return self.offs[i]
-
-    def _out_off(self):
-        return self.out_slot
-
-    def out_is_image(self) -> bool:
-        return False    # the output block is staged whole, then DMA'd
-
-
 def _requant(acc, mult: float, zp: int):
     """jnp mirror of repro.core.exec.ops.requantise (same f32 arithmetic);
     the int8-range result stays in the int32 compute dtype."""
@@ -701,10 +687,10 @@ def _qdot(a, b, a_zp: int, b_zp: int):
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies — all state lives in the aliased arena (or its staged
-# scratch window); the input operand only seeds the initial contents via
-# the alias. Bodies are addressing-agnostic: every arena touch goes through
-# the mem layer, so the flat, blocked and streaming programs share them.
+# Kernel bodies — all state lives in the aliased arena (or its staged VMEM
+# rows); the input operand only seeds the initial contents via the alias.
+# Bodies are addressing-agnostic: every arena touch goes through the mem
+# layer, so the flat, staged and roll-grid kernels share them.
 # ---------------------------------------------------------------------------
 
 
@@ -1023,79 +1009,97 @@ def _flat_kernel(*refs, spec: OpSpec):
         _BODIES[spec.kind](_FlatMem(o_ref, spec), *w_refs, spec=spec)
 
 
-def block_stage_spans(spec: OpSpec, total_rows: int
-                      ) -> Tuple[Tuple[Tuple[int, int], ...], Tuple[int, int]]:
-    """The HBM <-> VMEM copies of one row-blocked launch over an arena of
-    ``total_rows`` rows, as ``(start row, rows)`` spans: ``(spans in, span
-    out)``. In: the DMA-aligned span (:func:`repro.core.planner.dma_span`,
-    cut at the arena's end) of every input block and of the output block,
-    sorted, with overlapping or touching spans merged, so a diagonal
-    in/out overlap is copied once. Out: the output block's aligned span.
-    Whole 8-row groups travel both ways, so the rows of a group outside
-    the block (which an int8 row store rewrites unchanged) go back with
-    the values they came in with. A fused chain's spec names its external
+def block_stage_spans(spec: OpSpec, total_rows: int) -> Staging:
+    """The staging of one row-blocked launch over an arena of
+    ``total_rows`` rows (:func:`repro.core.planner.stage_rows` over its
+    input and output blocks). A fused chain's spec names its external
     inputs and its terminal output, the only arena rows its stages
     touch."""
-    def span(off: int, rows: int) -> Tuple[int, int]:
-        lo, n = dma_span(off, rows)
-        return lo, min(n, total_rows - lo)
-
-    blocks = [span(off, rows) for off, (rows, _) in zip(spec.in_off,
-                                                         spec.in_rows)]
-    out = span(spec.out_off, spec.out_rows[0])
-    merged = []
-    for lo, n in sorted(blocks + [out]):
-        if merged and lo <= merged[-1][0] + merged[-1][1]:
-            plo, pn = merged[-1]
-            merged[-1] = (plo, max(pn, lo + n - plo))
-        else:
-            merged.append((lo, n))
-    return tuple(merged), out
+    return stage_rows([(off, rows) for off, (rows, _) in zip(spec.in_off,
+                                                              spec.in_rows)],
+                      (spec.out_off, spec.out_rows[0]), total_rows,
+                      _sub(spec.dtype))
 
 
 def block_stage_bytes(spec: OpSpec, total_rows: int) -> int:
-    """Arena bytes one row-blocked launch copies between HBM and VMEM, both
+    """Arena bytes one staged launch copies between HBM and VMEM, both
     directions (:func:`block_stage_spans`)."""
-    spans, (_, out_rows) = block_stage_spans(spec, total_rows)
-    rows = sum(n for _, n in spans) + out_rows
+    st = block_stage_spans(spec, total_rows)
+    rows = sum(n for _, n in st.spans) + st.out[1]
     return rows * spec.rowlen * _isz(spec.dtype)
 
 
-def _block_kernel(a_ref, *rest, spec: OpSpec):
-    """Row-blocked VMEM-resident program: refs are (arena in HBM, *weights,
-    the aliased arena in HBM, the arena's VMEM image[, chain scratch], DMA
-    semaphores). The VMEM image holds every arena row at its own index, so
-    the bodies keep their absolute row addressing, but only the rows the
-    op touches are copied in (:func:`block_stage_spans`; every copy starts
-    before the first wait) and only the output block's rows go back to
-    HBM. The rest of the image is never read."""
+def _roll_rows(spec: OpSpec, total_rows: int) -> Tuple[int, int]:
+    """Rows of a roll-grid launch's input window (one of its two buffers)
+    and of its output slot."""
+    c, k, _ = _addr_out(spec)
+    slot = tile_writeback(spec.out_off, spec.out_shape[-3], c, k,
+                          _sub(spec.dtype), total_rows)[1]
+    return spec.win_rows - _tile_geom(spec)[1], slot
+
+
+def launch_vmem_rows(spec: OpSpec, total_rows: int) -> int:
+    """Arena rows one row-blocked launch allocates in VMEM: a roll-grid
+    launch its double-buffered input window and its output slot, any other
+    its staged buffer plus a fused chain's scratch."""
+    if spec.win_starts:
+        win_in, slot = _roll_rows(spec, total_rows)
+        return 2 * win_in + slot
+    return block_stage_spans(spec, total_rows).rows + spec.scratch_rows
+
+
+def spec_filter_bytes(spec: OpSpec) -> int:
+    """Bytes of the filters a launch holds in VMEM: the graph's filter
+    shapes (:func:`repro.core.exec.ops.weights_for`) in the arena's tier."""
+    if spec.kind == "fused":
+        return sum(spec_filter_bytes(st) for st in spec.stages)
+    if spec.kind in ("conv2d", "depthwise_conv2d"):
+        kh, kw = spec.meta[:2]
+        last = (spec.meta[8] if spec.kind == "depthwise_conv2d"
+                else spec.out_shape[-1])
+        n = kh * kw * spec.in_shape[0][-1] * last
+    elif spec.kind == "fully_connected":
+        n = spec.in_shape[0][-1] * spec.out_shape[-1]
+    else:
+        return 0
+    return n * _isz(spec.dtype)
+
+
+def _block_kernel(a_ref, *rest, spec: OpSpec, staging: Staging):
+    """Staged launch: refs are (arena in HBM, *weights, the aliased arena
+    in HBM, the packed VMEM buffer[, chain scratch], DMA semaphores). The
+    DMA-aligned spans of the op's blocks are copied into the buffer
+    (``staging``; every copy starts before the first wait), each operand is
+    addressed at its arena row shifted by its span's static offset, and
+    only the output block's span goes back to HBM."""
     nw = spec_weight_count(spec)
     w_refs, o_ref, buf = rest[:nw], rest[nw], rest[nw + 1]
     sems = rest[-1]
-    spans, (out_lo, out_n) = block_stage_spans(spec, buf.shape[0])
     cps = [pltpu.make_async_copy(o_ref.at[pl.ds(lo, n), :],
-                                 buf.at[pl.ds(lo, n), :], sems.at[i])
-           for i, (lo, n) in enumerate(spans)]
+                                 buf.at[pl.ds(base, n), :], sems.at[i])
+           for i, ((lo, n), base) in enumerate(zip(staging.spans,
+                                                   staging.bases))]
     for cp in cps:
         cp.start()
     for cp in cps:
         cp.wait()
     if spec.kind == "fused":
         scratch = rest[nw + 2]
-        _run_stages(spec, lambda st: _RoutedBlockMem(buf, scratch, st),
-                    w_refs)
+        _run_stages(spec, lambda st: _RoutedBlockMem(buf, scratch, st,
+                                                     staging.row), w_refs)
     else:
-        _BODIES[spec.kind](_BlockMem(buf, spec), *w_refs, spec=spec)
-    cp = pltpu.make_async_copy(buf.at[pl.ds(out_lo, out_n), :],
+        _BODIES[spec.kind](_BlockMem(buf, spec, staging.row), *w_refs,
+                           spec=spec)
+    out_lo, out_n = staging.out
+    cp = pltpu.make_async_copy(buf.at[pl.ds(staging.row(out_lo), out_n), :],
                                o_ref.at[pl.ds(out_lo, out_n), :], sems.at[0])
     cp.start()
     cp.wait()
 
 
 # ---------------------------------------------------------------------------
-# Streaming grid programs: arena in pl.ANY (HBM), live window in VMEM.
-# Every HBM <-> VMEM copy moves whole DMA row groups (planner.DMA_ROWS):
-# scratch slots hold each block at its arena row modulo DMA_ROWS.
+# The roll grid: arena in pl.ANY (HBM), a rolling input window in VMEM.
+# Every HBM <-> VMEM copy moves whole DMA row groups (planner.DMA_ROWS).
 # ---------------------------------------------------------------------------
 
 
@@ -1107,57 +1111,6 @@ def _table(vals: Tuple[int, ...], t):
     for i in range(1, len(vals)):
         s = jnp.where(t >= i, jnp.int32(vals[i]), s)
     return pl.multiple_of(s, DMA_ROWS)
-
-
-def _fetch_blocks(arena_ref, buf, blocks, sems) -> None:
-    """DMA each ``(arena row, rows, slot row)`` block's aligned span into
-    the scratch slot (the slot row sits at the arena row modulo DMA_ROWS),
-    pipelined over two rotating semaphores; returns once all landed."""
-    cps = []
-    for i, (off, rows, slot) in enumerate(blocks):
-        lo, n = dma_span(off, rows)
-        cps.append(pltpu.make_async_copy(
-            arena_ref.at[pl.dslice(lo, n), :],
-            buf.at[pl.dslice(slot - (off - lo), n), :],
-            sems.at[i % 2]))
-    for cp in cps[:2]:
-        cp.start()
-    for i, cp in enumerate(cps):
-        cp.wait()
-        if i + 2 < len(cps):
-            cps[i + 2].start()
-
-
-def _copy_back(buf, slot: int, arena_ref, off: int, rows: int, edge,
-               sem) -> None:
-    """Copy the block staged at scratch row ``slot`` back to arena rows
-    ``[off, off + rows)`` (``slot`` is ``off`` modulo DMA_ROWS). Whole DMA
-    row groups go straight back; a group the block only partly covers is
-    merged into the arena's current rows in the ``edge`` buffer first, so
-    rows outside the block keep their values."""
-    D = DMA_ROWS
-    hi = off + rows
-    a, b = -(-off // D) * D, hi // D * D       # wholly covered groups
-    if a < b:
-        cp = pltpu.make_async_copy(buf.at[pl.dslice(slot + a - off, b - a), :],
-                                   arena_ref.at[pl.dslice(a, b - a), :], sem)
-        cp.start()
-        cp.wait()
-    for g in sorted({off // D * D, (hi - 1) // D * D} - set(range(a, b, D))):
-        cp = pltpu.make_async_copy(arena_ref.at[pl.dslice(g, D), :], edge,
-                                   sem)
-        cp.start()
-        cp.wait()
-        cdt = jnp.int32 if edge.dtype == jnp.int8 else edge.dtype
-        ours = buf[pl.dslice(slot + g - off, D), :].astype(cdt)
-        row = jax.lax.broadcasted_iota(jnp.int32, ours.shape, 0) + g
-        keep = (row >= off) & (row < hi)
-        edge[...] = jnp.where(keep, ours, edge[...].astype(cdt)
-                              ).astype(edge.dtype)
-        cp = pltpu.make_async_copy(edge, arena_ref.at[pl.dslice(g, D), :],
-                                   sem)
-        cp.start()
-        cp.wait()
 
 
 def _stream_roll_kernel(a_ref, *rest, spec: OpSpec):
@@ -1220,44 +1173,6 @@ def _stream_roll_kernel(a_ref, *rest, spec: OpSpec):
     cp.wait()
 
 
-def _stream_stage_kernel(a_ref, *rest, spec: OpSpec, offs, out_slot):
-    """Staged whole-block op: DMA every operand block from the ANY arena
-    into its packed VMEM slot, run the written-once body against the
-    staged window, then copy the output block back. Read-all-before-
-    write-all — the exact element order of the blocked program, so
-    in-place overlaps are handled identically."""
-    nw = spec_weight_count(spec)
-    w_refs, o_ref = rest[:nw], rest[nw]
-    buf, edge, in_sems, out_sem = rest[nw + 1:]
-    _fetch_blocks(o_ref, buf, [(off, rows, slot) for off, (rows, _), slot
-                               in zip(spec.in_off, spec.in_rows, offs)],
-                  in_sems)
-    _BODIES[spec.kind](_StreamStageMem(buf, spec, offs, out_slot), *w_refs,
-                       spec=spec)
-    _copy_back(buf, out_slot, o_ref, spec.out_off, spec.out_rows[0], edge,
-               out_sem)
-
-
-def _stream_fused_kernel(a_ref, *rest, spec: OpSpec):
-    """Streaming fused band chain: stage every *external* input block from
-    the ANY arena into its packed VMEM slot (exactly the staged program),
-    run ALL chain stages entirely inside the scratch buffer (stage specs
-    carry scratch-local slot offsets for every operand — internals,
-    externals and the terminal output alike), then copy the terminal output
-    block back. The chain's VMEM residency is the
-    :func:`repro.core.planner.fused_slots` ``include_io`` packing = the
-    window schedule's ``win_rows``."""
-    nw = spec_weight_count(spec)
-    w_refs, o_ref = rest[:nw], rest[nw]
-    buf, edge, in_sems, out_sem = rest[nw + 1:]
-    _fetch_blocks(o_ref, buf, [(off, rows, slot) for off, (rows, _), slot
-                               in zip(spec.in_off, spec.in_rows,
-                                      spec.in_slots)], in_sems)
-    _run_stages(spec, lambda st: _BlockMem(buf, st), w_refs)
-    _copy_back(buf, spec.out_slot, o_ref, spec.out_off, spec.out_rows[0],
-               edge, out_sem)
-
-
 def _kernel_weights(spec: OpSpec, weights: Tuple[jax.Array, ...]):
     """Weights as the kernel bodies index them: a depthwise filter
     ``(kh, kw, ic, mult)`` becomes ``(kh, kw, 1, ic*mult)`` so each tap's
@@ -1270,99 +1185,58 @@ def _kernel_weights(spec: OpSpec, weights: Tuple[jax.Array, ...]):
         for kind, w in zip(kinds, weights))
 
 
-def _apply_stream(arena: jax.Array, spec: OpSpec,
-                  weights: Tuple[jax.Array, ...], interpret: bool,
-                  params: dict):
+def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
+             interpret: bool, vmem_limit: Optional[int] = None) -> jax.Array:
+    """Run one op in-place on the shared arena (flat 1-D byte buffer, or a
+    row-blocked 2-D typed buffer in HBM that the launch stages or rolls
+    through VMEM, per the spec); returns the (aliased) arena.
+    ``vmem_limit`` (bytes) is handed to Mosaic as the kernel's scoped-VMEM
+    limit when compiling."""
+    weights = _kernel_weights(spec, weights)
+    fused = spec.kind == "fused"
+    if not spec.rowlen:
+        fn = pl.pallas_call(
+            functools.partial(_flat_kernel, spec=spec),
+            out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
+            input_output_aliases={0: 0},
+            scratch_shapes=([pltpu.VMEM((spec.scratch_rows,), jnp.uint8)]
+                            if fused else []),
+            interpret=interpret,
+            name=kernel_name(spec),
+        )
+        return fn(arena, *weights)
     dt = _jnp_dtype(spec.dtype)
     L = spec.rowlen
-    sub = _sub(spec.dtype)
-    io_specs = dict(
+    if spec.win_starts:                        # roll grid: conv/dw/pool
+        kernel = functools.partial(_stream_roll_kernel, spec=spec)
+        grid = (len(spec.win_starts),)
+        win_in, slot = _roll_rows(spec, arena.shape[0])
+        scratch = [
+            pltpu.VMEM((2, win_in, L), dt), pltpu.VMEM((slot, L), dt),
+            pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(())]
+    else:       # staged: one launch for a whole chain, its intermediates
+        # in the chain scratch
+        staging = block_stage_spans(spec, arena.shape[0])
+        kernel = functools.partial(_block_kernel, spec=spec, staging=staging)
+        grid = ()
+        scratch = ([pltpu.VMEM((staging.rows, L), dt)]
+                   + ([pltpu.VMEM((spec.scratch_rows, L), dt)]
+                      if fused else [])
+                   + [pltpu.SemaphoreType.DMA((len(staging.spans),))])
+    params = ({} if interpret or vmem_limit is None else dict(
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)))
+    fn = pl.pallas_call(
+        kernel,
+        grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
         + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(weights),
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={0: 0},
+        input_output_aliases={0: 0},            # the arena is donated through
+        scratch_shapes=scratch,
         interpret=interpret,
         name=kernel_name(spec),
         **params,
-    )
-    sems = [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(())]
-    edge = pltpu.VMEM((DMA_ROWS, L), dt)
-    if spec.kind == "fused":                   # band-chain super-kernel
-        fn = pl.pallas_call(
-            functools.partial(_stream_fused_kernel, spec=spec),
-            scratch_shapes=[pltpu.VMEM((spec.scratch_rows, L), dt),
-                            edge] + sems,
-            **io_specs,
-        )
-    elif spec.win_starts:                      # rolling conv/dw/pool window
-        c, k, _ = _addr_out(spec)
-        _, slot_rows = tile_writeback(spec.out_off, spec.out_shape[-3], c, k,
-                                      sub, arena.shape[0])
-        fn = pl.pallas_call(
-            functools.partial(_stream_roll_kernel, spec=spec),
-            grid=(len(spec.win_starts),),
-            scratch_shapes=[
-                pltpu.VMEM((2, spec.win_rows - _tile_geom(spec)[1], L), dt),
-                pltpu.VMEM((slot_rows, L), dt)] + sems,
-            **io_specs,
-        )
-    else:                                      # staged whole-block op
-        offs, out_slot, total = staged_slots(
-            [(o, r) for o, (r, _) in zip(spec.in_off, spec.in_rows)],
-            (spec.out_off, spec.out_rows[0]), sub)
-        fn = pl.pallas_call(
-            functools.partial(_stream_stage_kernel, spec=spec, offs=offs,
-                              out_slot=out_slot),
-            scratch_shapes=[pltpu.VMEM((total, L), dt), edge] + sems,
-            **io_specs,
-        )
-    return fn(arena, *weights)
-
-
-def apply_op(arena: jax.Array, spec: OpSpec, weights: Tuple[jax.Array, ...],
-             interpret: bool, vmem_limit: Optional[int] = None) -> jax.Array:
-    """Run one op in-place on the shared arena (flat 1-D byte buffer,
-    row-blocked 2-D typed buffer, or ANY-space streamed buffer, per the
-    spec); returns the (aliased) arena. ``vmem_limit`` (bytes) is handed to
-    Mosaic as the kernel's scoped-VMEM limit when compiling."""
-    weights = _kernel_weights(spec, weights)
-    params = ({} if interpret or vmem_limit is None else dict(
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)))
-    if spec.win_rows:
-        return _apply_stream(arena, spec, weights, interpret, params)
-    fused = spec.kind == "fused"
-    if spec.rowlen:
-        # the arena stays in HBM; its VMEM image takes the staged rows. One
-        # launch for a whole chain, intermediates in the chain scratch
-        # (typed rows)
-        dt = _jnp_dtype(spec.dtype)
-        scratch = ([pltpu.VMEM(arena.shape, dt)]
-                   + ([pltpu.VMEM((spec.scratch_rows, spec.rowlen), dt)]
-                      if fused else [])
-                   + [pltpu.SemaphoreType.DMA(
-                       (len(block_stage_spans(spec, arena.shape[0])[0]),))])
-        fn = pl.pallas_call(
-            functools.partial(_block_kernel, spec=spec),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
-            + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(weights),
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-            input_output_aliases={0: 0},        # the arena is donated through
-            scratch_shapes=scratch,
-            interpret=interpret,
-            name=kernel_name(spec),
-            **params,
-        )
-        return fn(arena, *weights)
-    fn = pl.pallas_call(
-        functools.partial(_flat_kernel, spec=spec),
-        out_shape=jax.ShapeDtypeStruct(arena.shape, arena.dtype),
-        input_output_aliases={0: 0},
-        scratch_shapes=([pltpu.VMEM((spec.scratch_rows,), jnp.uint8)]
-                        if fused else []),
-        interpret=interpret,
-        name=kernel_name(spec),
     )
     return fn(arena, *weights)
 

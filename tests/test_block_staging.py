@@ -1,20 +1,25 @@
 """Row-blocked launches stage only their operands' rows.
 
-Each launch of the row-blocked (compiled) program copies into its VMEM
-image of the arena the DMA-aligned spans of its input blocks and of its
-output block, and copies back only the output block's span. Under test:
+Each staged launch, on the compiled route and on the streaming route
+alike, copies the DMA-aligned spans of its input blocks and of its output
+block into one packed VMEM buffer, and copies back only the output block's
+span. Under test:
 
 - the spans themselves (:func:`repro.kernels.arena_ops.block_stage_spans`):
-  merged where they overlap or touch, aligned to whole DMA row groups;
-- that no kernel body reads a row no copy filled: every row-blocked
-  kernel runs in the TPU interpreter with its VMEM starting as NaN (the
-  largest integer for an int8 arena), and the outputs still match the
-  NumPy backend, while a staging that leaves the inputs out does not;
+  merged where they overlap or touch, aligned to whole DMA row groups, and
+  packed into the buffer at rows congruent to their arena rows modulo the
+  sublane tile;
+- that no kernel body reads a row no copy filled: every staged kernel
+  runs in the TPU interpreter with its VMEM starting as NaN (the largest
+  integer for an int8 arena), and the outputs still match the NumPy
+  backend, while a staging that leaves the inputs out does not;
 - that the arithmetic is unchanged: at batch 2 the row-blocked outputs
   equal the streaming route's exactly (both in one interpreter, since the
   two interpreters may round a dot product differently).
 """
 from __future__ import annotations
+
+import types
 
 import numpy as np
 import pytest
@@ -43,7 +48,7 @@ CASES = {
 
 @pytest.fixture
 def nan_vmem(monkeypatch):
-    """Interpret every row-blocked kernel in the TPU interpreter with its
+    """Interpret every staged kernel in the TPU interpreter with its
     VMEM starting as NaN, so that a read of a row no copy filled poisons
     the result. Programs lowered before or under the patch are dropped on
     both sides."""
@@ -61,43 +66,56 @@ def nan_vmem(monkeypatch):
     arena_ops._lower_program_cached.cache_clear()
 
 
-def _spec(in_blocks, out_block):
+def _spec(in_blocks, out_block, dtype="f32"):
     """A row-blocked spec with the given ``(row offset, rows)`` blocks."""
     return arena_ops.OpSpec(
         kind="elementwise", in_off=tuple(o for o, _ in in_blocks),
         in_shape=((1,),) * len(in_blocks), out_off=out_block[0],
-        out_shape=(1,), rowlen=128,
+        out_shape=(1,), dtype=dtype, rowlen=128,
         in_rows=tuple((r, 128) for _, r in in_blocks),
         out_rows=(out_block[1], 128))
 
 
-@pytest.mark.parametrize("ins,out,total,spans,back", [
+@pytest.mark.parametrize("ins,out,total,spans,back,bases,rows,dtype", [
     # a diagonal overlap: the output block starts inside the input's
     # span, so the two are copied as one
-    ([(0, 20)], (12, 20), 32, ((0, 32),), (8, 24)),
+    ([(0, 20)], (12, 20), 32, ((0, 32),), (8, 24), (0,), 32, "f32"),
     # blocks in one 8-row group, and blocks that only touch, merge too
-    ([(3, 2)], (5, 1), 8, ((0, 8),), (0, 8)),
-    ([(0, 8), (8, 8)], (16, 8), 24, ((0, 24),), (16, 8)),
-    # disjoint blocks stay apart, sorted by row
-    ([(40, 4), (0, 9)], (24, 3), 48, ((0, 16), (24, 8), (40, 8)), (24, 8)),
+    ([(3, 2)], (5, 1), 8, ((0, 8),), (0, 8), (0,), 8, "f32"),
+    ([(0, 8), (8, 8)], (16, 8), 24, ((0, 24),), (16, 8), (0,), 24, "f32"),
+    # disjoint blocks stay apart, sorted by row, and pack back to back
+    ([(40, 4), (0, 9)], (24, 3), 48, ((0, 16), (24, 8), (40, 8)), (24, 8),
+     (0, 16, 24), 32, "f32"),
     # an arena that ends inside a group: the last span stops at its end
-    ([(8, 10)], (0, 3), 18, ((0, 18),), (0, 8)),
+    ([(8, 10)], (0, 3), 18, ((0, 18),), (0, 8), (0,), 24, "f32"),
+    # int8: each span lands on its arena row modulo the 32-row tile
+    ([(48, 4)], (0, 3), 64, ((0, 8), (48, 8)), (0, 8), (0, 16), 32, "i8"),
 ])
-def test_stage_spans_merge_aligned_blocks(ins, out, total, spans, back):
-    spec = _spec(ins, out)
-    assert arena_ops.block_stage_spans(spec, total) == (spans, back)
+def test_stage_spans_merge_aligned_blocks(ins, out, total, spans, back,
+                                          bases, rows, dtype):
+    spec = _spec(ins, out, dtype)
+    st = arena_ops.block_stage_spans(spec, total)
+    assert (st.spans, st.out, st.bases, st.rows) == (spans, back, bases, rows)
+    sub = 32 if dtype == "i8" else 8
+    for (lo, n), base in zip(st.spans, st.bases):
+        assert base % sub == lo % sub and st.row(lo + n - 1) == base + n - 1
     assert arena_ops.block_stage_bytes(spec, total) == (
-        sum(n for _, n in spans) + back[1]) * 128 * 4
+        sum(n for _, n in spans) + back[1]) * 128 * (1 if dtype == "i8"
+                                                      else 4)
 
 
-@pytest.mark.parametrize("name", list(CASES))
+@pytest.mark.parametrize("name", list(CASES) + ["stream_allops_b2.streaming"])
 def test_blocked_route_reads_only_staged_rows(nan_vmem, name):
-    """With every VMEM buffer of the row-blocked kernels starting as NaN,
-    the program's outputs match the NumPy backend (f32 tolerance, int8 <=
-    1 LSB)."""
-    build, batch = CASES[name]
+    """With every VMEM buffer of the staged kernels starting as NaN, the
+    program's outputs match the NumPy backend (f32 tolerance, int8 <= 1
+    LSB): on the compiled route, and (``.streaming``) on the streaming
+    route, whose staged launches are the same kernel."""
+    case = name.removesuffix(".streaming")
+    build, batch = CASES[case]
     cp = compile_graph(build(), batch=batch, cache=False)
-    got = X.get_backend("pallas", layout="blocks").execute(cp)
+    opts = (dict(mode="streaming", interpret=True) if case != name
+            else dict(layout="blocks"))
+    got = X.get_backend("pallas", **opts).execute(cp)
     X.compare_outputs(X.get_backend("numpy").execute(cp), got, exact=False,
                       label=f"{name} blocked vs numpy")
     if name.startswith("mobilenet_v1_0.25_128"):
@@ -116,13 +134,15 @@ def test_blocked_route_equals_streaming_at_batch_2():
 
 
 def test_nan_vmem_catches_an_unstaged_input(nan_vmem, monkeypatch):
-    """The check above bites: staging only the output block's span leaves
-    the inputs' rows NaN, and the outputs with them."""
+    """The check above bites: copying in only the output block's span
+    leaves the inputs' rows NaN, and the outputs with them."""
     real = arena_ops.block_stage_spans
 
     def output_only(spec, total_rows):
-        out = real(spec, total_rows)[1]
-        return (out,), out
+        st = real(spec, total_rows)
+        return types.SimpleNamespace(
+            spans=(st.out,), bases=(st.row(st.out[0]),), out=st.out,
+            rows=st.rows, row=st.row)
 
     monkeypatch.setattr(arena_ops, "block_stage_spans", output_only)
     cp = compile_graph(zoo.mobilenet_v1(0.25, 32, 4), cache=False)

@@ -141,9 +141,11 @@ def test_fused_parity_zoo(name):
 
 
 def test_fused_streaming_window_containment():
-    """The fused streaming window stages exactly the include_io slot total
-    (ext inputs + chain scratch + terminal output) and stays inside the
-    arena extents of its external operands."""
+    """The fused chain's window reports as resident what its launch
+    allocates, the staged spans of its external inputs and terminal output
+    plus the chain scratch; its live rows stay the tight include_io
+    packing, and it stays inside the arena extents of its external
+    operands."""
     cp = pipeline.compile(_flagship(), cache=False)
     assert cp.winner == "fuse"
     bp = cp.legalised()
@@ -157,12 +159,22 @@ def test_fused_streaming_window_containment():
 
     # chain_rows_of applies the packed (cols_per_row, row_span) geometry to
     # chain-scratch tensors exactly as the planner's _fused_window does
-    _, total = P.fused_slots(members, P.chain_rows_of(bp),
-                             round_to=bp.tiling[0], include_io=True)
-    _, live = P.fused_slots(members, P.chain_rows_of(bp),
-                            round_to=bp.tiling[0], include_io=True,
-                            dma_io=False)
-    assert w.resident_rows == total and w.win_rows == live <= total
+    sub = bp.tiling[0]
+    _, scratch = P.fused_slots(members, P.chain_rows_of(bp), round_to=sub)
+    _, live = P.fused_slots(members, P.chain_rows_of(bp), round_to=sub,
+                            include_io=True)
+    ext = {t.storage() for op in members for t in op.inputs
+           if t.storage().kind != "weight" and t.storage() not in internal}
+    out = bp.layout_of(members[-1].output)
+    staged = P.stage_rows(
+        [(bp.layouts[s].row_offset, bp.layouts[s].rows) for s in ext],
+        (out.row_offset, out.rows), bp.total_rows, sub)
+    assert w.resident_rows == staged.rows + scratch
+    assert w.win_rows == live
+    from repro.core.exec.pallas_backend import PallasExecutor
+    spec, = [s for s in PallasExecutor(layout="blocks").lower_blocks(bp)
+             if s.kind == "fused"]
+    assert spec.scratch_rows == scratch
     for op in members:
         for t in list(op.inputs) + [op.output]:
             s = t.storage()

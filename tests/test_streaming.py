@@ -13,8 +13,8 @@ Three layers under test:
   row-blocked program (same kernel bodies, f32 AND int8) and vs the numpy
   backend (f32 tolerance / int8 <= 1 LSB);
 - plumbing: mode validation, the flat-layout refusal, the interpret pin,
-  and the VMEM-budget refusals (streaming gates on the window, compiled
-  mode on the whole arena).
+  and the one VMEM gate (each launch's VMEM rows and filters, on both
+  routes).
 """
 from __future__ import annotations
 
@@ -168,70 +168,71 @@ def test_window_containment(name):
 
 @pytest.mark.parametrize("name", list(_MODELS))
 def test_staged_slots_match_schedule(name):
-    """Staged ops: the packed scratch slots are disjoint, ordered, each
-    block sits at its arena row modulo DMA_ROWS, and the total the kernel
-    allocates equals the schedule's resident rows; the live rows are the
-    blocks' own rows."""
+    """Every launch allocates in VMEM the rows its window reports as
+    resident (:func:`repro.kernels.arena_ops.launch_vmem_rows` of the
+    streaming route's spec): the rolling input window and output slot of
+    a roll-grid launch; the packed staging buffer of any other, plus a
+    fused chain's scratch. A staged launch's spans are disjoint and
+    ordered, land in the buffer in order without overlap, and every
+    operand keeps its arena rows' residue modulo the sublane tile; the
+    live rows are the blocks' own rows (a fused chain's: its tight
+    ``include_io`` packing)."""
+    from repro.core.exec.pallas_backend import PallasExecutor
+    from repro.kernels import arena_ops
     _, bp = _bplan(_MODELS[name])
     ws = bp.window_schedule()
+    specs = PallasExecutor(mode="streaming",
+                           interpret=True).lower_stream(bp)
     by_name = {op.name: op for op in bp.order}
-    sub = bp.tiling[0]
+    sub, T = bp.tiling[0], bp.total_rows
     chains = {}
     for op in bp.order:
         cname = op.params.get("fuse_chain")
         if cname is not None:
             chains.setdefault(cname, []).append(op)
-    for w in ws.windows:
+    assert len(specs) == len(ws.windows)
+    for spec, w in zip(specs, ws.windows):
+        assert arena_ops.launch_vmem_rows(spec, T) == w.resident_rows
         if w.rolling:
             op = by_name[w.op_name]
             out = bp.layout_of(op.output)
             _, slot_rows = P.tile_writeback(
                 out.row_offset, int(op.output.shape[-3]), out.cols_per_row,
-                out.row_span, sub, bp.total_rows)
+                out.row_span, sub, T)
             tile_ar = P.tile_arena_rows(out.cols_per_row, out.row_span, sub)
             assert slot_rows >= tile_ar
             assert w.resident_rows == 2 * (w.win_rows - tile_ar) + slot_rows
             continue
+        st = arena_ops.block_stage_spans(spec, T)
+        assert w.resident_rows == st.rows + spec.scratch_rows
+        for (lo, n), (lo2, _) in zip(st.spans, st.spans[1:]):
+            assert lo + n < lo2
+        for (lo, n), base, nxt in zip(st.spans, st.bases,
+                                      st.bases[1:] + (st.rows,)):
+            assert base % sub == lo % sub and base + n <= nxt
+        assert st.rows % sub == 0
+        blocks = list(zip(spec.in_off, (r for r, _ in spec.in_rows)))
+        blocks.append((spec.out_off, spec.out_rows[0]))
+        for off, r in blocks:
+            assert st.row(off) % sub == off % sub
+            assert st.row(off + r - 1) == st.row(off) + r - 1
         if w.kind == "fused":
-            # fused chains stage the ext inputs + terminal output alongside
-            # the chain scratch: the window is the include_io slot total
-            # (chain_rows_of applies the packed geometry to scratch tensors
-            # exactly as the planner's own _fused_window does)
             members = chains[w.op_name]
-            _, total = P.fused_slots(members, P.chain_rows_of(bp),
-                                     round_to=sub, include_io=True)
             _, live = P.fused_slots(members, P.chain_rows_of(bp),
-                                    round_to=sub, include_io=True,
-                                    dma_io=False)
-            assert total == w.resident_rows and live == w.win_rows <= total
+                                    round_to=sub, include_io=True)
+            assert live == w.win_rows
             continue
-        op = by_name[w.op_name]
-        ins = [t for t in op.inputs if t.storage().kind != "weight"]
-        blocks = [(bp.layout_of(t).row_offset, bp.layout_of(t).rows)
-                  for t in ins]
-        out_lay = bp.layout_of(op.output)
-        out_block = (out_lay.row_offset, out_lay.rows)
-        offs, out_slot, total = P.staged_slots(blocks, out_block, sub)
-        assert total == w.resident_rows
-        assert w.win_rows == -(-sum(r for _, r in blocks + [out_block])
-                               // sub) * sub <= total
-        cur = 0
-        for o, (off, r) in zip(list(offs) + [out_slot],
-                               blocks + [out_block]):
-            lo, n = P.dma_span(off, r)
-            assert o == cur + off - lo
-            cur += n
-        assert cur <= total
+        assert w.win_rows == -(-sum(r for _, r in blocks) // sub) * sub
 
 
 def test_flagship_window_strictly_below_arena():
     """Acceptance: on the paper's flagship 8-bit rows the streaming VMEM
-    ceiling (max_resident_bytes) is strictly smaller than what the
-    VMEM-resident blocked program needs — the whole arena plus any fused
-    chain scratch. Packing can shrink the arena *below* the rolling
-    double-buffer (the window/arena row comparison loses meaning there),
-    so the strict window-below-arena bound is asserted on the legacy
-    layout and packing is held to never raising the streaming ceiling."""
+    ceiling (max_resident_bytes) is strictly smaller than a whole-arena
+    residency would need — the whole arena plus any fused chain scratch.
+    Packing can shrink the arena *below* the rolling double-buffer (the
+    window/arena row comparison loses meaning there), so the strict
+    window-below-arena bound is asserted on the legacy layout and packing
+    is held to never raising the streaming ceiling."""
     from repro.core.exec.pallas_backend import PallasExecutor
     for name in zoo.TABLE3_8BIT_MODELS:
         _, bp = _bplan(zoo.TABLE3_MODELS[name][0])
@@ -286,14 +287,17 @@ def test_lower_stream_grafts_window_statics():
     specs = be.lower_stream(bp)
     ws = bp.window_schedule()
     assert len(specs) == len(ws.windows)
-    for s, w in zip(specs, ws.windows):
-        assert s.win_rows == w.win_rows > 0
-        assert s.win_lo == w.lo
-        assert s.win_starts == w.starts
+    blocks = be.lower_blocks(bp)
+    for s, b, w in zip(specs, blocks, ws.windows):
         if s.kind in ("conv2d", "depthwise_conv2d", "pool"):
             assert s.win_starts, f"{s.kind} should roll"
+            assert s.win_rows == w.win_rows > 0
+            assert s.win_starts == w.starts
+        else:
+            # every other launch is the compiled route's own spec
+            assert s == b and not s.win_rows and not s.win_starts
     # the blocked lowering stays streaming-free
-    assert all(s.win_rows == 0 for s in be.lower_blocks(bp))
+    assert all(s.win_rows == 0 and not s.win_starts for s in blocks)
 
 
 # ---------------------------------------------------------------------------
@@ -317,34 +321,67 @@ def test_streaming_mode_plumbing(monkeypatch):
 
 
 def test_streaming_refuses_over_budget_window():
-    """The streaming gate is the *window*, not the arena: a budget between
-    the two refuses compiled-style whole-arena residency but admits
-    streaming; a budget below the window refuses streaming too."""
+    """One VMEM gate on both routes: a launch needs its VMEM arena rows
+    (staged buffer plus chain scratch, or its roll-grid window) and its
+    filters. A budget below a route's largest launch refuses that route
+    and names the launch; a budget between the compiled route's largest
+    launch and a whole arena's residency (arena, chain scratch and
+    filters) admits it, and its outputs match the numpy backend."""
     from repro.core.exec.pallas_backend import PallasExecutor
-    # 96px v2 build: big enough that the double-buffered resident scratch
-    # is strictly below the compiled-mode need — the packed layouts shrink
-    # the mobilenet_v1 arenas to the point where the two tie
-    cp, bp = _bplan(lambda: zoo.mobilenet_v2(0.35, 96, 1))
-    ws = bp.window_schedule()
-    # compiled mode must keep the whole arena plus any fused chain scratch
-    # resident; streaming only the largest window
-    specs = PallasExecutor(layout="blocks", interpret=True).lower_blocks(bp)
-    scratch = max((s.scratch_rows for s in specs if s.kind == "fused"),
-                  default=0)
-    compiled_need = (bp.total_rows + scratch) * bp.row_bytes
-    assert ws.max_resident_bytes < compiled_need
-    with pytest.raises(ValueError, match="does not fit VMEM"):
-        PallasExecutor(mode="streaming", interpret=True,
-                       vmem_budget=ws.max_resident_bytes - 1).execute(cp)
-    with pytest.raises(ValueError, match="streaming"):
-        PallasExecutor(mode="compiled",
-                       vmem_budget=compiled_need - 1).execute(cp)
-    # between window and compiled need: streaming executes where compiled
-    # refuses
-    out = PallasExecutor(mode="streaming", interpret=True,
-                         vmem_budget=compiled_need - 1).execute(cp)
-    ref = X.get_backend("numpy").execute(cp)
-    X.compare_outputs(ref, out, exact=False, label="budget-admitted stream")
+    from repro.kernels import arena_ops
+    cp, bp = _bplan(_MODELS["mobilenet_v1_0.25_32_f32"])
+    T, rb = bp.total_rows, bp.row_bytes
+
+    def needs(specs):
+        return {arena_ops.kernel_name(s): arena_ops.launch_vmem_rows(s, T)
+                * rb + arena_ops.spec_filter_bytes(s) for s in specs}
+
+    be = PallasExecutor(mode="streaming", interpret=True)
+    stream, blocks = needs(be.lower_stream(bp)), needs(be.lower_blocks(bp))
+    whole = max((T + s.scratch_rows) * rb + arena_ops.spec_filter_bytes(s)
+                for s in be.lower_blocks(bp))
+    assert max(blocks.values()) < whole
+    for opts, need in [(dict(mode="streaming", interpret=True), stream),
+                       (dict(mode="compiled"), blocks),
+                       (dict(layout="blocks"), blocks)]:
+        largest = max(need, key=need.get)
+        with pytest.raises(ValueError, match="does not fit VMEM") as e:
+            PallasExecutor(vmem_budget=need[largest] - 1,
+                           **opts).execute(cp)
+        assert largest in str(e.value)
+    out = PallasExecutor(layout="blocks", vmem_budget=max(blocks.values())
+                         ).execute(cp)
+    X.compare_outputs(X.get_backend("numpy").execute(cp), out, exact=False,
+                      label="budget-admitted blocks")
+
+
+#: The legalised layout of each build: ``(arena_rowlen, packing,
+#: total_rows, max_window_rows)``. The staging rule must move none of them
+#: (the legaliser weighs ``max_window_rows``, which a launch's VMEM buffer
+#: does not enter).
+_LAYOUTS = {
+    "mobilenet_v1_0.25_32_f32": (256, "packed", 24, 24),
+    "mobilenet_v2_0.35_32_f32": (384, "packed", 24, 32),
+    "mobilenet_v1_0.25_32_8bit": (128, "packed", 64, 64),
+    "mobilenet_v1_0.25_128_8bit": (768, "packed", 96, 128),
+    "stream_allops": (128, "legacy", 32, 48),
+    "mobilenet_v1_0.25_128_int8": (768, "packed", 96, 128),
+    "mobilenet_v1_1.0_224_f32": (7168, "packed", 80, 104),
+}
+
+_LAYOUT_BUILDS = dict(
+    _MODELS,
+    **{"mobilenet_v1_0.25_128_int8": lambda: zoo.mobilenet_v1(0.25, 128, 1),
+       "mobilenet_v1_1.0_224_f32": lambda: zoo.mobilenet_v1(1.0, 224, 4)})
+
+
+@pytest.mark.parametrize("name", list(_LAYOUTS))
+def test_legalised_layouts_pinned(name):
+    """The legalised layouts of the streaming builds and of the
+    benchmark's two MobileNet configurations stay as they were."""
+    _, bp = _bplan(_LAYOUT_BUILDS[name])
+    assert (bp.arena_rowlen, bp.packing, bp.total_rows,
+            bp.window_schedule().max_window_rows) == _LAYOUTS[name]
 
 
 def _fake_tpu(monkeypatch, kind: str):
